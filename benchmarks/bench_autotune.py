@@ -9,7 +9,7 @@ cluster, real ordered traffic):
     with (long flush windows sized for a device none may exist, batch
     caps sized for the wrong host, accumulation off). Autotuner off.
   * ``static-best`` — the repo's hand-benched defaults (the operating
-    point RESULTS.md rows were measured at on this container).
+    point earlier CPU-host rows were measured at).
     Autotuner off: this is the target the controller must reach.
   * ``autotune``   — the SAME cold knobs, autotuner on with a fast
     cadence. The controller must walk the knobs from the cold start
@@ -18,8 +18,7 @@ cluster, real ordered traffic):
 The acceptance gate: ``autotune_over_best >= 0.9`` — from cold
 defaults, the closed loop recovers at least 90% of the hand-benched
 configuration's goodput. (On a noisy shared container the ratio is
-REPORTED per run; RESULTS.md records the measured samples with the
-usual pairing discipline.)
+REPORTED per run, measured in back-to-back pairs.)
 
 Usage: python -m benchmarks.bench_autotune [--secs 12] [--clients 3]
            [--smoke]
@@ -129,7 +128,7 @@ def smoke() -> Dict:
 
 
 def main(argv=None) -> int:
-    from benchmarks.common import setup_cache
+    from tpubft.utils.jaxcache import setup_cache
     setup_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--secs", type=float, default=12.0,

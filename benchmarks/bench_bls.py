@@ -10,7 +10,7 @@ n ∈ {4, 7, 31, 501, 1000} (reference cases stop at 501; 1000 is the
 BASELINE.json north-star scale).
 
 Usage: python -m benchmarks.bench_bls [--cases 4,7,31] [--json]
-Each case prints one JSON line; paste into BASELINE.md.
+Each case prints one JSON line.
 """
 from __future__ import annotations
 

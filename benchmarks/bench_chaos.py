@@ -68,11 +68,10 @@ def main(argv=None) -> int:
                    help="list scenario names and exit")
     args = p.parse_args(argv)
 
-    # force the CPU jax backend before anything imports the ops plane —
-    # chaos campaigns measure recovery, never kernels (benchmarks.common
-    # applies the same config the tests use)
+    # the CPU jax backend, chosen before anything imports the ops plane:
+    # chaos campaigns measure recovery, never kernels
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    from benchmarks.common import setup_cache
+    from tpubft.utils.jaxcache import setup_cache
     setup_cache()
 
     from tpubft.testing import campaign as cmp

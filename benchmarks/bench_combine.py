@@ -24,7 +24,7 @@ the device backend on a CPU/XLA host carry the `degraded` +
 Usage: python -m benchmarks.bench_combine [--sweep] [--crossover]
            [--backend cpu|tpu] [--slots 1,2,4,8,16] [--secs 0.5]
            [--smoke]
-Prints one JSON line per row; paste into benchmarks/RESULTS.md.
+Prints one JSON line per row.
 """
 from __future__ import annotations
 
@@ -33,7 +33,7 @@ import json
 import time
 from typing import List
 
-from benchmarks.common import setup_cache
+from tpubft.utils.jaxcache import setup_cache
 from tpubft.crypto.interfaces import Cryptosystem, IThresholdVerifier
 
 # slow-path quorum 2f+c+1 for c=0, f=(n-1)//3 — the preset --cases

@@ -152,7 +152,7 @@ def _run_flood(rep, flood: List[tuple], distinct: int,
 def run_pair(shape: str, msgs: int, distinct: int, workers: int,
              sample: int) -> List[dict]:
     """One back-to-back A/B pair (fresh replica per mode, same flood
-    content) — the host-noise-pairing convention of RESULTS.md."""
+    content), so host noise hits both legs alike."""
     rows = []
     for mode, w in (("admission", workers), ("inline", 0)):
         rep, keys, first_client = _make_replica(w)
@@ -473,21 +473,13 @@ def device_fault(msgs: int = 360, warmup: int = 64,
       * time-to-restored  — kernel restored → breaker CLOSED via the
         half-open probe batch, device path hot again.
     """
-    import os
-
     from tpubft.ops import ed25519 as ops_ed
     from tpubft.ops.dispatch import device_breaker
 
     # persistent compile cache: the windowed verify kernel is a large
     # XLA program; repeat bench runs should not re-pay the compile
-    try:
-        import jax
-        jax.config.update("jax_compilation_cache_dir", os.path.abspath(
-            os.path.join(os.path.dirname(__file__), "..", ".jax_cache")))
-        jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          2.0)
-    except Exception:  # noqa: BLE001 — cache is an optimization only
-        pass
+    from tpubft.utils.jaxcache import setup_cache
+    setup_cache()
 
     b = device_breaker()
     rep, keys, first_client = _make_replica(

@@ -5,14 +5,18 @@ client sees against a live cluster (reference measurement path:
 tests/simpleKVBC TesterClient + Apollo's bft.py; kvbc add-block
 throughput harness kvbc/benchmark/kvbcbench/main.cpp).
 
-Configs (BASELINE.md):
+Configs (BASELINE.json `configs`):
   1. n=4 (f=1), multisig-ed25519 commit certs   — config 1
   2. n=7 (f=2), threshold-bls commit certs      — config 2
   3. n=31 (f=10), secp256k1 client sigs + threshold-bls commit certs
      (the Apollo 31-replica cluster shape)       — config 3
   5. n=4 (f=1), ECDSA-P256 clients + threshold-bls over TLS, with a
      view-change storm (primary paused every storm-period) — config 5
-Each runs with crypto_backend cpu and (if a device is reachable) tpu.
+Each runs with the backends named by --backends: "tpu" needs the chip
+(or JAX_PLATFORMS=cpu, the XLA-CPU rehearsal, and the row then says
+nothing about a device). --processes with a device backend is refused:
+a chip serves one process (tpubft.crypto.backend.check_process_fanout,
+ROADMAP C6) — the in-process cluster is how n replicas share one chip.
 (Config 4 — the n=1000 synthetic PrePrepare/share flood — is the
 separate benchmarks/bench_flood.py: it measures the crypto plane at a
 scale no single-host cluster can reach.)
@@ -404,7 +408,7 @@ def smoke_optimistic(secs: float = 2.0, clients: int = 2) -> dict:
 
 
 def main() -> None:
-    from benchmarks.common import setup_cache
+    from tpubft.utils.jaxcache import setup_cache
     setup_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--secs", type=float, default=10.0)

@@ -202,7 +202,7 @@ def phase_c_memo_coalesce(n: int, reps: int) -> None:
 
 
 def main() -> None:
-    from benchmarks.common import setup_cache
+    from tpubft.utils.jaxcache import setup_cache
     setup_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--n", type=int, default=1000)

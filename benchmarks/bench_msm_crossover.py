@@ -193,7 +193,7 @@ def main_ecdsa(args) -> None:
 
 
 def main() -> None:
-    from benchmarks.common import setup_cache
+    from tpubft.utils.jaxcache import setup_cache
     setup_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--ks", default="8,32,128,512,667")

@@ -29,7 +29,7 @@ they validate the seam's plumbing and safety, not speed.
 Usage: python -m benchmarks.bench_offload [--ab] [--soundness]
            [--kill] [--lie] [--backend cpu|tpu]
            [--slots 1,4,16] [--secs 0.5] [--smoke]
-Prints one JSON line per row; paste into benchmarks/RESULTS.md.
+Prints one JSON line per row.
 """
 from __future__ import annotations
 
@@ -38,7 +38,7 @@ import json
 import time
 from typing import List
 
-from benchmarks.common import setup_cache
+from tpubft.utils.jaxcache import setup_cache
 from tpubft.crypto.interfaces import Cryptosystem
 
 # the bench IS the external harness the offload-seam baseline speaks

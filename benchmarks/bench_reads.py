@@ -357,7 +357,7 @@ def smoke(secs: float = 2.0) -> dict:
 
 
 def main(argv=None) -> None:
-    from benchmarks.common import setup_cache
+    from tpubft.utils.jaxcache import setup_cache
     setup_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--secs", type=float, default=10.0)

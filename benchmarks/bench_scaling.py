@@ -34,16 +34,26 @@ import sys
 import time
 
 
+def _require_platform(platform: str) -> None:
+    """`--platform native` is a chip measurement: refuse to produce a
+    "native" row on anything but a TPU."""
+    import jax
+    found = jax.devices()[0].platform
+    if platform == "native" and found != "tpu":
+        raise SystemExit(f"--platform native needs a TPU; the default "
+                         f"JAX platform here is {found!r}")
+
+
 def run_width(d: int, batch: int, msm_k: int,
               platform: str = "cpu") -> dict:
-    """One width, current process. Assumes XLA device count already set.
-    platform="cpu" pins the virtual CPU mesh (the 1-host validation
-    mode); "native" leaves the backend alone so a real chip mesh
-    produces the actual scaling slope."""
+    """One width, current process. The sweep's parent set this
+    process's platform and device count through the environment:
+    platform="cpu" is the virtual CPU mesh (the 1-host validation
+    mode); "native" is a real chip mesh, and a child that finds no TPU
+    there fails."""
     import jax
-    if platform == "cpu":
-        jax.config.update("jax_platforms", "cpu")
-    from benchmarks.common import setup_cache
+    _require_platform(platform)
+    from tpubft.utils.jaxcache import setup_cache
     setup_cache()
     import numpy as np
 
@@ -104,12 +114,11 @@ def run_dispatch_ab(d: int, batch: int, platform: str = "cpu") -> dict:
     two verdict vectors must be byte-identical before any rate is
     reported. On a real mesh the acceptance bar is >= 1.6x at 2 shards;
     on the virtual CPU host mesh every shard multiplexes one core, so
-    the row is annotated degraded and only the byte-identity + the
-    bounded sharding overhead are the signal."""
+    only the byte-identity + the bounded sharding overhead are the
+    signal (the row names its platform)."""
     import jax
-    if platform == "cpu":
-        jax.config.update("jax_platforms", "cpu")
-    from benchmarks.common import setup_cache
+    _require_platform(platform)
+    from tpubft.utils.jaxcache import setup_cache
     setup_cache()
     import numpy as np
 
@@ -171,7 +180,7 @@ def run_agg_ab(f: int = 10, fanout: int = 4, writes: int = 10,
         transport, never semantics);
       * goodput — the aggregated leg sustains >= `min_goodput_ratio`
         of baseline write throughput (asserted on real accelerator
-        rows; CPU rows report it and carry the degraded annotation).
+        rows; CPU rows only report it, under their platform's name).
     """
     import jax
 
@@ -284,24 +293,6 @@ def agg_ab_smoke(writes: int = 4) -> dict:
                       min_reduction=1.0, min_goodput_ratio=0.0)
 
 
-def _annotate_degraded(row: dict, probe_error, stderr_tail: str) -> dict:
-    """bench.py's artifact convention (PR 4): a row produced on the CPU
-    backend is not comparable to a real-chip row and must say so in a
-    machine-readable way — `degraded: true` plus a `probe_error`
-    explaining WHY, instead of burying XLA warnings in a raw log tail
-    (the old MULTICHIP_r0*.json failure mode)."""
-    if row.get("platform") != "cpu":
-        return row
-    row["degraded"] = True          # CPU mesh: validates sharding only
-    detail = probe_error or ("virtual CPU host mesh: every 'device' "
-                             "multiplexes the same core, so rates are "
-                             "not a scaling slope")
-    warn = "\n".join(ln for ln in stderr_tail.splitlines()
-                     if "WARNING" in ln or ln.startswith("E"))[-400:]
-    row["probe_error"] = detail + (f"; stderr: {warn}" if warn else "")
-    return row
-
-
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--devices", default="1,2,4,8")
@@ -334,9 +325,10 @@ def main() -> None:
     if args.agg_ab:
         if args.platform == "cpu":
             os.environ.setdefault("JAX_PLATFORMS", "cpu")
+        _require_platform(args.platform)
         row = run_agg_ab(f=args.agg_f, fanout=args.agg_fanout,
                          writes=args.agg_writes, mode=args.agg_mode)
-        print(json.dumps(_annotate_degraded(row, None, "")))
+        print(json.dumps(row))
         return
     if args.one_width:
         if args.dispatch_ab:
@@ -347,15 +339,8 @@ def main() -> None:
                                        args.msm_k,
                                        platform=args.platform)))
         return
-    probe_error = None
-    if args.platform == "native":
-        # same probe bench.py uses: jax silently falls back to CPU when
-        # the accelerator plugin is absent or broken, and a "native" row
-        # that actually ran on the CPU must carry the reason
-        from bench import _device_probe_once
-        ok, probe_error = _device_probe_once()
-        if ok:
-            probe_error = None
+    # the parent stays off JAX: each width is one child, in turn, and a
+    # chip belongs to whichever child is running
     for d in [int(x) for x in args.devices.split(",")]:
         env = dict(os.environ)
         if args.platform == "cpu":
@@ -371,13 +356,9 @@ def main() -> None:
         r = subprocess.run(cmd, env=env, capture_output=True, text=True,
                            timeout=1800)
         if r.returncode != 0:
-            print(json.dumps({"devices": d, "degraded": True,
-                              "probe_error": "width subprocess exited "
-                              f"rc={r.returncode}",
-                              "error": r.stderr[-400:]}))
-            continue
-        row = json.loads(r.stdout.strip().splitlines()[-1])
-        print(json.dumps(_annotate_degraded(row, probe_error, r.stderr)))
+            raise SystemExit(f"width {d} child exited rc={r.returncode}:\n"
+                             f"{r.stderr[-2000:]}")
+        print(r.stdout.strip().splitlines()[-1], flush=True)
 
 
 if __name__ == "__main__":

@@ -15,8 +15,8 @@ protocol handling is serialized under one dispatch lock, emulating each
 node's single consensus dispatcher (and keeping the comparison honest on
 a 1-core host: the pipeline may only overlap LATENCY, not compute).
 
-Rows land in benchmarks/RESULTS.md. `--smoke` runs a small shape for the
-tier-1 wiring test (tests/test_bench_st_smoke.py).
+`--smoke` runs a small shape for the tier-1 wiring test
+(tests/test_bench_st_smoke.py).
 
 Usage:
   python -m benchmarks.bench_st [--blocks 256] [--range 16] [--window 4]

@@ -11,14 +11,15 @@ import subprocess
 import sys
 import time
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
 REPO = os.path.join(os.path.dirname(__file__), "..")
 sys.path.insert(0, REPO)
 
 
 def main() -> None:
     base_port = random.randint(20000, 50000)
-    env = dict(os.environ, PYTHONPATH=REPO, JAX_PLATFORMS="cpu")
+    # replicas run the cpu crypto backend (skvbc_replica's default) and
+    # never start JAX, so n processes need no chip
+    env = dict(os.environ, PYTHONPATH=REPO)
     procs = []
     print(f"spawning 4 replica processes (base port {base_port})...")
     for r in range(4):

@@ -604,29 +604,38 @@ EXPECTED_KNOBS = {
 }
 
 
-def _expected_knobs():
+# the device plane's knobs exist only on a replica whose resolved
+# backend is the device: a cpu-backend replica never starts JAX
+DEVICE_KNOBS = {"ecdsa_crossover_b", "device_min_verify_batch"}
+
+
+def _expected_knobs(backend):
     """crypto_shard_count registers only on multi-chip hosts (the
     tier-1 conftest forces an 8-device CPU mesh, so it is present
     here — but keep the guard honest for single-device runs)."""
+    if backend == "cpu":
+        return EXPECTED_KNOBS - DEVICE_KNOBS
     from tpubft.ops.dispatch import crypto_mesh
     extra = {"crypto_shard_count"} if crypto_mesh().device_count() > 1 \
         else set()
     return EXPECTED_KNOBS | extra
 
 
-def test_replica_tuning_catalog_and_status():
+@pytest.mark.parametrize("backend", ["tpu", "cpu"])
+def test_replica_tuning_catalog_and_status(backend):
     """An in-process cluster with the autotuner on registers the full
     knob catalog, serves `status get tuning`, and the controller's
     degraded rule observes the replica's real health plane."""
     from tpubft.testing.cluster import InProcessCluster
     with InProcessCluster(f=1, cfg_overrides={
+            "crypto_backend": backend,
             "autotune_enabled": True,
             "autotune_interval_ms": 50}) as cluster:
         rep = cluster.replicas[0]
         assert rep.tuning is not None
-        assert set(rep.tuning.registry.names()) == _expected_knobs()
+        assert set(rep.tuning.registry.names()) == _expected_knobs(backend)
         payload = json.loads(rep.tuning.render())
-        assert set(payload["knobs"]) == _expected_knobs()
+        assert set(payload["knobs"]) == _expected_knobs(backend)
         assert payload["active"] is True
         # defaults mirror the config fields the knobs replaced
         assert payload["knobs"]["combine_flush_us"]["value"] \

@@ -4,8 +4,7 @@ static knobs, and the same cold knobs with the controllers live at
 full cadence against the in-process cluster — under TPUBFT_THREADCHECK
 so the tuner-thread ⇄ actuator (batcher/lane/admission) lock orders
 ride the runtime checker. Timing gates (the 0.9x acceptance ratio)
-stay out of tier-1 — host noise; RESULTS.md records the measured
-runs."""
+stay out of tier-1 — host noise."""
 import pytest
 
 

@@ -3,8 +3,7 @@ fused combine plane's microbench must produce well-formed rows whose
 fused and per-slot verdicts are identical (byte-level combined
 signatures included), and the crossover row must carry both schemes'
 costs plus the certificate-size tradeoff. Timing ASSERTIONS stay out of
-tier-1 (host noise); the full sweep's speedups are recorded in
-benchmarks/RESULTS.md."""
+tier-1 (host noise)."""
 import json
 
 from benchmarks.bench_combine import crossover_row, main, sweep_row

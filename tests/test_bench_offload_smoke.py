@@ -3,8 +3,7 @@ offload tier's bench must produce well-formed rows whose leased and
 local verdicts are byte-identical, whose kill drill holds liveness
 without quarantining the crashed (merely sick) helper, and whose lying
 drill catches the Byzantine helper on its first lying lease. Timing
-ASSERTIONS stay out of tier-1 (host noise); the full sweeps are
-recorded in benchmarks/RESULTS.md."""
+ASSERTIONS stay out of tier-1 (host noise)."""
 import json
 
 from benchmarks.bench_offload import main

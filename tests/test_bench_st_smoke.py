@@ -1,9 +1,7 @@
 """Tier-1 wiring for benchmarks/bench_st.py (--smoke shape): the
 pipelined multi-source state transfer must beat stop-and-wait under
-injected per-message latency even on a loaded CI host. The full-shape
->=3x rows (and the device-digest variant) are recorded in
-benchmarks/RESULTS.md; this asserts a conservative floor so the tier-1
-gate doesn't flake on host noise."""
+injected per-message latency even on a loaded CI host. This asserts a
+conservative floor so the tier-1 gate doesn't flake on host noise."""
 from benchmarks.bench_st import compare
 
 

@@ -189,8 +189,19 @@ def test_random_keygen_roundtrips():
         b"m", e.sign(b"m"))
 
 
-def test_ecdsa_verifier_rejects_bad_pubkey():
+def test_ecdsa_verifier_rejects_bad_pubkey(scalar_engine):
+    """The scalar engine admits exactly the wire format: an uncompressed
+    on-curve SEC1 point."""
     with pytest.raises(ValueError):
         cpu.EcdsaVerifier(b"\x04" + b"\x01" * 64, "secp256k1")
     with pytest.raises(ValueError):
         cpu.EcdsaVerifier(b"\x02" + b"\x01" * 32, "secp256k1")
+
+
+def test_ecdsa_verifier_rejects_off_curve_pubkey_openssl():
+    """With `cryptography` installed the constructor goes through
+    OpenSSL, which refuses an off-curve point the same way."""
+    if not cpu.have_openssl():
+        pytest.skip("cryptography not installed")
+    with pytest.raises(ValueError):
+        cpu.EcdsaVerifier(b"\x04" + b"\x01" * 64, "secp256k1")

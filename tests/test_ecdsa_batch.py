@@ -325,7 +325,7 @@ def test_sig_manager_path_equivalence_mixed_schemes():
         del os.environ["TPUBFT_ECDSA_CROSSOVER_B"]
 
 
-def test_breaker_open_rides_batched_host():
+def test_breaker_open_rides_batched_host(scalar_engine):
     """Tier-1 degraded-mode smoke: with the device breaker OPEN, ECDSA
     admission traffic must flow through ecdsa_verify_batch (visible as
     scalar_fallbacks + ecdsa_batched_host), never fail, and keep
@@ -350,7 +350,7 @@ def test_breaker_open_rides_batched_host():
     assert sm._h_ecdsa_host_batch.name == "sigmgr0.ecdsa_host_batch"
 
 
-def test_pubkey_decode_memo_counter_flows():
+def test_pubkey_decode_memo_counter_flows(scalar_engine):
     from tpubft.consensus.sig_manager import SigManager
     cfg, keys = _mixed_cluster()
     corpus, want = _mixed_corpus(cfg, keys)
@@ -368,7 +368,7 @@ def test_pubkey_decode_memo_counter_flows():
     assert sm2.pubkey_memo_hits.value == 0
 
 
-def test_two_replica_concurrent_drain_is_exact():
+def test_two_replica_concurrent_drain_is_exact(scalar_engine):
     """ISSUE 14 satellite: the per-sink drain is atomic. Two replicas'
     SigManagers hammer the shared batched host engine from separate
     threads, each draining its attributed sink per verify call

@@ -72,29 +72,23 @@ def test_import_tree_without_cryptography():
     assert "NO-CRYPTOGRAPHY-OK" in r.stdout
 
 
-def test_crypto_cpu_scalar_path_direct():
+def test_crypto_cpu_scalar_path_direct(scalar_engine):
     """In-process variant (fast): force the feature probe off and check
     the scalar path end to end, including cross-checking that the scalar
     engine's answer agrees with whatever backend is active."""
     from tpubft.crypto import cpu, scalar
-    os.environ["TPUBFT_NO_OPENSSL"] = "1"
-    cpu._openssl.cache_clear()
-    try:
-        assert not cpu.have_openssl()
-        s = cpu.Ed25519Signer.generate(seed=b"probe-off")
-        sig = s.sign(b"payload")
-        assert cpu.Ed25519Verifier(s.public_bytes()).verify(b"payload", sig)
-        assert scalar.ed25519_verify(s.public_bytes(), b"payload", sig)
-        assert not cpu.Ed25519Verifier(s.public_bytes()).verify(b"x", sig)
-        for curve in ("secp256k1", "secp256r1"):
-            e = cpu.EcdsaSigner.generate(curve, seed=b"probe-off")
-            esig = e.sign(b"payload")
-            v = cpu.EcdsaVerifier(e.public_bytes(), curve)
-            assert v.verify(b"payload", esig)
-            assert not v.verify(b"payload!", esig)
-    finally:
-        del os.environ["TPUBFT_NO_OPENSSL"]
-        cpu._openssl.cache_clear()
+    assert not cpu.have_openssl()
+    s = cpu.Ed25519Signer.generate(seed=b"probe-off")
+    sig = s.sign(b"payload")
+    assert cpu.Ed25519Verifier(s.public_bytes()).verify(b"payload", sig)
+    assert scalar.ed25519_verify(s.public_bytes(), b"payload", sig)
+    assert not cpu.Ed25519Verifier(s.public_bytes()).verify(b"x", sig)
+    for curve in ("secp256k1", "secp256r1"):
+        e = cpu.EcdsaSigner.generate(curve, seed=b"probe-off")
+        esig = e.sign(b"payload")
+        v = cpu.EcdsaVerifier(e.public_bytes(), curve)
+        assert v.verify(b"payload", esig)
+        assert not v.verify(b"payload!", esig)
 
 
 def test_collection_has_no_errors_without_cryptography():

@@ -416,3 +416,27 @@ def test_combine_batch_byte_identical_with_offload(thr, strategy,
     assert got == want
     if strategy != "honest":
         assert get_offload_pool().snapshot()["quarantined"] == ["h"]
+
+
+def test_helper_daemon_never_starts_jax():
+    """A chip serves one process: the helper daemon, which runs beside
+    device-backed replicas, computes on the host engines and must not
+    even import JAX (a fresh interpreter, every lease kind served)."""
+    import subprocess
+    import sys
+    code = (
+        "import sys\n"
+        "from tpubft.offload import helper, protocol as proto\n"
+        "from tpubft.crypto import bls12381 as bls, cpu\n"
+        "pt = bls.g1_compress(bls.G1_GEN)\n"
+        "seg = proto.encode_bls_segments([([1, 2], [pt, pt])])\n"
+        "helper.compute(proto.KIND_BLS_COMBINE, seg)\n"
+        "helper.compute(proto.KIND_BLS_SUM, seg)\n"
+        "s = cpu.EcdsaSigner.generate('secp256k1', seed=b'h')\n"
+        "items = [(b'm', s.sign(b'm'), s.public_bytes())]\n"
+        "helper.compute(proto.KIND_ECDSA_RLC,\n"
+        "               proto.encode_ecdsa_items('secp256k1', items))\n"
+        "assert 'jax' not in sys.modules, 'helper imported jax'\n")
+    r = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                       text=True, timeout=120)
+    assert r.returncode == 0, r.stderr[-2000:]

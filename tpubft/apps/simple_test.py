@@ -52,7 +52,8 @@ def add_scheme_args(ap) -> None:
 
 def run_replica(args) -> None:
     cfg = ReplicaConfig(replica_id=args.replica, f_val=args.f,
-                        num_of_client_proxies=args.clients)
+                        num_of_client_proxies=args.clients,
+                        crypto_backend=args.crypto_backend)
     keys = ClusterKeys.generate(cfg, args.clients,
                                 seed=args.seed.encode()).for_node(args.replica)
     eps = endpoint_table(args.base_port, cfg.n_val, args.clients)
@@ -102,6 +103,8 @@ def run_orchestrator(args) -> int:
     cfg = ReplicaConfig(f_val=args.f, num_of_client_proxies=args.clients)
     n = cfg.n_val
     metrics_base = args.metrics_base_port or args.base_port + 100
+    from tpubft.crypto.backend import check_process_fanout
+    check_process_fanout(args.crypto_backend, n)
     procs: List[subprocess.Popen] = []
     try:
         for r in range(n):
@@ -110,8 +113,9 @@ def run_orchestrator(args) -> int:
                  "--replica", str(r), "--f", str(args.f),
                  "--base-port", str(args.base_port),
                  "--clients", str(args.clients), "--seed", args.seed,
+                 "--crypto-backend", args.crypto_backend,
                  "--metrics-port", str(metrics_base + r)]))
-        # 120s: n concurrent cold jax imports contend on this 1-core host
+        # 120s: n concurrent cold process starts contend on a small host
         # (same flake class as the process-cluster boot timeout)
         if not _wait_for_metrics([metrics_base + r for r in range(n)],
                                  timeout_s=120):
@@ -168,6 +172,10 @@ def main() -> int:
     ap.add_argument("--metrics-base-port", type=int, default=0)
     ap.add_argument("--ops", type=int, default=50)
     ap.add_argument("--seed", default="tpubft-simple-test")
+    ap.add_argument("--crypto-backend", default="cpu",
+                    choices=("cpu", "tpu", "auto"),
+                    help="replica processes run the host verifiers unless "
+                         "told otherwise: a chip serves one process")
     args = ap.parse_args()
     if args.replica is not None:
         run_replica(args)

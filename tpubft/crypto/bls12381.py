@@ -4,7 +4,7 @@ Plays the role RELIC plays in the reference (threshsign/src/bls/relic/ —
 SURVEY.md §2.2): field/curve arithmetic, hashing to the curve, BLS signatures,
 threshold (Shamir) key generation, Lagrange interpolation, and pairing-based
 verification. The reference uses BN-P254; we use BLS12-381 (the modern curve,
-and the one BASELINE.md's north star names for the TPU MSM).
+and the one BASELINE.json's north star names for the TPU MSM).
 
 Convention: "min-sig" — signatures/hashes in G1 (cheap shares + G1 MSM on
 TPU), public keys in G2. Verify: e(sig, -g2) * e(H(m), pk) == 1.

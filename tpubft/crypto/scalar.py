@@ -907,8 +907,8 @@ def ecdsa_verify_batch(items: Sequence[Tuple[bytes, bytes, bytes]],
                        curve_name: str) -> List[bool]:
     """Batched ECDSA verify: items are (pubkey, message, raw r||s sig)
     triples (pubkeys may all differ).  Verdict-identical to calling
-    `ecdsa_verify` per item, ~10x faster at batch 256 on the bench
-    container (see benchmarks/RESULTS.md). Batch shape AND wall time
+    `ecdsa_verify` per item, ~10x faster at batch 256 (a CPU-host
+    reading of `bench_msm_crossover --ecdsa`). Batch shape AND wall time
     land in the attributed stats sink (`host_ns`) — the autotuner's
     host-tier cost sensor for the device/host crossover."""
     if not items:

@@ -791,8 +791,8 @@ def register_builtin(type_name: str) -> None:
 # every carried proof, vs 66·k for the vector). The EdDSA-vs-BLS
 # committee measurements (arXiv 2302.00418) put per-share threshold math
 # far above EdDSA cost at committee sizes this small; the default is
-# picked by `python -m benchmarks.bench_combine --crossover`
-# (benchmarks/RESULTS.md) and overridable per cluster via
+# picked by `python -m benchmarks.bench_combine --crossover` (on a CPU
+# host so far) and overridable per cluster via
 # ReplicaConfig.threshold_scheme_crossover_n.
 ADAPTIVE_SCHEME_CROSSOVER_N = 16
 

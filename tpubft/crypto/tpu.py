@@ -36,6 +36,7 @@ from tpubft.crypto.systems import (BlsMultisigVerifier,
                                    BlsThresholdAccumulator,
                                    BlsThresholdVerifier,
                                    MultisigEd25519Verifier)
+from tpubft.ops.dispatch import device_tier
 
 
 def verify_batch_items(items: Sequence[Tuple[bytes, bytes, bytes]]
@@ -99,9 +100,9 @@ def _ecdsa_device_crossover() -> int:
     wins, then TPUBFT_ECDSA_CROSSOVER_B as exported by
     `benchmarks/bench_msm_crossover.py --ecdsa` (env read stays
     per-call: tests flip it at runtime); unset, the default prefers the
-    device on real accelerators and the batched host on the XLA-CPU
-    fallback (where the kernel is ~100x slower than the comb walk —
-    BENCH_r05's 30-34/s cliff)."""
+    device on an accelerator and the batched host on XLA-CPU (where the
+    kernel is ~100x slower than the comb walk — BENCH_r05.json, a
+    CPU-host row: 30-34/s)."""
     import os
     if _crossover_override is not None:
         return _crossover_override
@@ -189,12 +190,13 @@ class TpuEd25519Verifier(IVerifier):
     def verify_batch(self, items: Sequence[Tuple[bytes, bytes]]
                      ) -> List[bool]:
         try:
-            from tpubft.ops import ed25519 as ops
-            return [bool(x) for x in ops.verify_batch(
-                [(d, s, self.public_key_bytes) for d, s in items])]
+            with device_tier("ed25519"):
+                from tpubft.ops import ed25519 as ops
+                return [bool(x) for x in ops.verify_batch(
+                    [(d, s, self.public_key_bytes) for d, s in items])]
         except Exception:  # noqa: BLE001 — device loss (or an OPEN
             # breaker fast-fail) degrades to the host verifier; the
-            # breaker recorded the failure at the kernel seam
+            # breaker recorded it (_device_tier)
             from tpubft.crypto.cpu import make_verifier
             v = make_verifier("ed25519", self.public_key_bytes)
             return [v.verify(d, s) for d, s in items]
@@ -225,7 +227,8 @@ class TpuMultisigEd25519Verifier(MultisigEd25519Verifier):
         if entries is None:
             return False
         try:
-            return all(verify_batch_items(entries))
+            with device_tier("ed25519"):
+                return all(verify_batch_items(entries))
         except Exception:  # noqa: BLE001 — device loss: the host
             # multisig check is byte-identical, just serial
             return super().verify(data, sig)
@@ -245,7 +248,8 @@ class TpuMultisigEd25519Verifier(MultisigEd25519Verifier):
             else:
                 ok_shape.append(False)
         try:
-            verdicts = iter(verify_batch_items(entries))
+            with device_tier("ed25519"):
+                verdicts = iter(verify_batch_items(entries))
         except Exception:  # noqa: BLE001 — degrade to per-share host
             return [self.verify_share(i, d, s) for i, d, s in items]
         return [next(verdicts) if shaped else False for shaped in ok_shape]
@@ -270,7 +274,8 @@ class TpuMultisigEd25519Verifier(MultisigEd25519Verifier):
             # amortize a dispatch: host loop (same doctrine as verify)
             return [self.verify(d, s) for d, s in items]
         try:
-            verdicts = iter(verify_batch_items(entries))
+            with device_tier("ed25519"):
+                verdicts = iter(verify_batch_items(entries))
         except Exception:  # noqa: BLE001 — device loss: serial host check
             return [self.verify(d, s) for d, s in items]
         out = []
@@ -330,7 +335,8 @@ class TpuMultisigEd25519Verifier(MultisigEd25519Verifier):
         if len(entries) < self.min_device_batch:
             return super().combine_batch(jobs)   # host loop (see verify)
         try:
-            flat = verify_batch_items(entries) if entries else []
+            with device_tier("ed25519"):
+                flat = verify_batch_items(entries) if entries else []
         except Exception:  # noqa: BLE001 — device loss: per-job host loop
             return super().combine_batch(jobs)
         ok_by_job: List[Dict[int, bool]] = [{} for _ in jobs]
@@ -369,13 +375,14 @@ class TpuBlsThresholdAccumulator(BlsThresholdAccumulator):
         if len(self._shares) < crossover and k < crossover:
             return super().get_full_signed_data()
         try:
-            from tpubft.ops import bls12_381 as dev
-            ids = sorted(self._shares)[:k]
-            # shares are affine (x, y) int tuples — the device MSM's
-            # native input
-            combined = dev.combine_shares(ids,
-                                          [self._shares[i] for i in ids])
-            return bls.g1_compress(combined)
+            with device_tier("bls_msm"):
+                from tpubft.ops import bls12_381 as dev
+                ids = sorted(self._shares)[:k]
+                # shares are affine (x, y) int tuples — the device MSM's
+                # native input
+                combined = dev.combine_shares(
+                    ids, [self._shares[i] for i in ids])
+                return bls.g1_compress(combined)
         except Exception:  # noqa: BLE001 — device loss: the host
             # Pippenger combine produces the identical signature
             return super().get_full_signed_data()
@@ -416,9 +423,10 @@ class TpuBlsThresholdVerifier(BlsThresholdVerifier):
         if total < crossover or not any(ids for ids, _ in segments):
             return super()._combine_segments(segments)
         try:
-            from tpubft.ops import bls12_381 as dev
-            return dev.combine_shares_batch(
-                [(ids, pts) for ids, pts in segments])
+            with device_tier("bls_msm"):
+                from tpubft.ops import bls12_381 as dev
+                return dev.combine_shares_batch(
+                    [(ids, pts) for ids, pts in segments])
         except Exception:  # noqa: BLE001 — device loss: the host
             # per-segment combine produces identical signatures
             return super()._combine_segments(segments)
@@ -451,10 +459,12 @@ class TpuBlsMultisigVerifier(BlsMultisigVerifier):
         if total < crossover or not any(segments):
             return super()._sum_segments(segments)
         try:
-            from tpubft.ops import bls12_381 as dev
             live = [i for i, pts in enumerate(segments) if pts]
-            sums = dev.msm_batch([(segments[i], [1] * len(segments[i]))
-                                  for i in live])
+            with device_tier("bls_msm"):
+                from tpubft.ops import bls12_381 as dev
+                sums = dev.msm_batch(
+                    [(segments[i], [1] * len(segments[i]))
+                     for i in live])
             out = [None] * len(segments)
             for i, pt in zip(live, sums):
                 out[i] = pt

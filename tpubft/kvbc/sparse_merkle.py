@@ -49,8 +49,10 @@ _DEVICE_THRESHOLD = 192
 def _hash_level(messages: Sequence[bytes], use_device: bool) -> List[bytes]:
     if use_device and len(messages) >= _DEVICE_THRESHOLD:
         try:
+            from tpubft.ops.dispatch import device_tier
             from tpubft.ops.sha256 import sha256_batch
-            return sha256_batch(messages)
+            with device_tier("sha256"):
+                return sha256_batch(messages)
         except Exception:  # noqa: BLE001 — device loss (or an OPEN
             # circuit breaker fast-fail) degrades to hashlib: digests
             # are byte-identical, a Merkle update must never die with

@@ -7,6 +7,11 @@ every answer (tpubft/offload/soundness.py), so a helper binary can be
 anything from this process to rented burst capacity on somebody else's
 accelerator.
 
+This helper computes on the HOST engines (native/pure BLS, the scalar
+ECDSA engine) and never imports JAX: a chip serves one process, so a
+daemon started beside a device-backed replica must not reach for that
+replica's chip (tests/test_offload_plane.py pins this).
+
 Process model mirrors apps/skvbc_replica.py: `python -m
 tpubft.offload.helper --port 7700` runs the TCP daemon (length-prefixed
 frames, one handler thread per connection). `HelperServer` is the

@@ -131,6 +131,19 @@ def device_section(kind: str, batch: int = 0, shards: int = 1) -> _Section:
     return _Section(kind, batch, shards)
 
 
+def device_tier(kind: str):
+    """One breaker attempt around a WHOLE device tier — for callers
+    that answer on the host when the tier raises (crypto/tpu.py,
+    sparse_merkle, state transfer). Such an answer is only safe to read
+    as "the device is fine" if it is on the breaker's books: the kernel
+    seams (`device_section`) count what fails inside them, but host
+    prep, mesh planning and kernel construction run outside. The
+    outermost attempt owns the verdict (nested seams pass through), so
+    a tier that falls to the host for ANY reason is one recorded
+    failure, and an OPEN breaker is one recorded fast-fail."""
+    return _breaker.attempt(kind)
+
+
 # ---------------------------------------------------------------------
 # mesh tier (ISSUE 16): multi-chip routing for the batched kernels
 # ---------------------------------------------------------------------

@@ -304,22 +304,24 @@ def make_rlc_kernel(curve_name: str):
     return jax.jit(rlc_fold_body(get_curve(curve_name)))
 
 
-_RLC_KERNELS = {}
+@functools.lru_cache(maxsize=None)
+def rlc_kernel(curve_name: str):
+    """The process's one jitted single-device RLC kernel for a curve
+    (every launch and every warm-up shares its compile cache)."""
+    return make_rlc_kernel(curve_name)
 
 
 def _rlc_launch(curve_name: str, prep: PreparedRlcBatch,
                 idxs: Sequence[int]) -> bool:
     """One aggregate device launch over a subset of prepared columns,
     padded to a power of two (inactive padding lanes contribute zero)."""
-    if curve_name not in _RLC_KERNELS:
-        _RLC_KERNELS[curve_name] = make_rlc_kernel(curve_name)
     m = _pad_pow2(max(1, len(idxs)))
     sel = list(idxs) + [idxs[0]] * (m - len(idxs))
     active = np.zeros(m, bool)
     active[:len(idxs)] = prep.host_valid[list(idxs)]
     from tpubft.ops.dispatch import device_section
     with device_section("ecdsa", batch=len(idxs)):
-        ok = _RLC_KERNELS[curve_name](
+        ok = rlc_kernel(curve_name)(
             prep.u1_bits[:, sel], prep.u2_bits[:, sel],
             prep.qx[:, sel], prep.qy[:, sel],
             prep.xr_m[:, sel], prep.xrpn_m[:, sel],
@@ -346,8 +348,6 @@ def _rlc_mesh_round(plan, curve_name: str, prep: PreparedRlcBatch,
     if plan is None or plan.mesh is None:
         return [] if _rlc_launch(curve_name, prep, idxs) else [list(idxs)]
     from tpubft.parallel import sharding
-    if curve_name not in _RLC_KERNELS:
-        _RLC_KERNELS[curve_name] = make_rlc_kernel(curve_name)
     d = plan.n
     rows = sharding.shard_rows(len(idxs), d)
     m = rows * d

@@ -47,22 +47,19 @@ AXIS = "shard"
 
 
 def _shard_map(f, mesh: Mesh, in_specs, out_specs):
-    """Version shim: jax >= 0.5 exposes jax.shard_map (replication check
-    flag `check_vma`); 0.4.x has jax.experimental.shard_map.shard_map
-    (flag `check_rep`). The check is disabled either way — the ladder's
-    initial carry is an unvarying constant (identity point) which the
-    varying-manual-axes checker rejects."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(f, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)
+    """`jax.shard_map` with the varying-manual-axes check off: the
+    ladders' initial carry is an unvarying constant (identity point)
+    which the checker rejects."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def make_mesh(n_devices: Optional[int] = None) -> Mesh:
     devs = jax.devices()
     n = n_devices or len(devs)
+    if n > len(devs):
+        raise ValueError(f"mesh of {n} devices asked for, "
+                         f"{len(devs)} present")
     return Mesh(np.array(devs[:n]), (AXIS,))
 
 
@@ -94,36 +91,24 @@ def sharded_msm_kernel(mesh: Mesh):
 
 def sharded_verify_ed25519(mesh: Mesh):
     """Data-parallel batched Ed25519 verify: every input sharded on
-    batch. On TPU platforms each device runs the FUSED Pallas kernel on
-    its shard (the fast single-chip path must not be lost by going
-    multi-chip); elsewhere the XLA formulation."""
+    batch. On a TPU each device runs the FUSED Pallas kernel on its
+    shard (the fast single-chip path must not be lost by going
+    multi-chip) — under shard_map, because the partitioner cannot split
+    a Mosaic kernel; elsewhere the XLA formulation, partitioned by the
+    jit shardings."""
     from tpubft.ops import ed25519 as ops
 
+    batch_last, batch_only = P(None, AXIS), P(AXIS)
+    specs = (batch_last, batch_last, batch_last, batch_only, batch_last,
+             batch_only)
     if ops._use_pallas():
         from tpubft.ops import ed25519_pallas as pk
-        kernel = pk.verify_kernel
-    else:
-        kernel = ops.verify_kernel
-
-    def fn(s_win, h_win, a_y, a_sign, r_y, r_sign):
-        return kernel(s_win, h_win, a_y, a_sign, r_y, r_sign)
-
-    batch_last = NamedSharding(mesh, P(None, AXIS))
-    batch_only = NamedSharding(mesh, P(AXIS))
-    return jax.jit(fn, in_shardings=(batch_last, batch_last, batch_last,
-                                     batch_only, batch_last, batch_only),
-                   out_shardings=batch_only)
-
-
-def verify_pad_multiple(mesh: Mesh) -> int:
-    """Batch-size multiple the sharded verify needs: devices × (the
-    per-device Pallas tile on TPU, 1 on other platforms)."""
-    from tpubft.ops import ed25519 as ops
-    per_dev = 1
-    if ops._use_pallas():
-        from tpubft.ops import ed25519_pallas as pk
-        per_dev = pk.TILE
-    return mesh.devices.size * per_dev
+        return jax.jit(_shard_map(pk.verify_kernel, mesh, in_specs=specs,
+                                  out_specs=batch_only))
+    return jax.jit(ops.verify_kernel,
+                   in_shardings=tuple(NamedSharding(mesh, s)
+                                      for s in specs),
+                   out_shardings=NamedSharding(mesh, batch_only))
 
 
 def sharded_msm(points: Sequence, scalars: Sequence[int],
@@ -354,10 +339,10 @@ class CryptoMesh:
     def _inventory(self) -> Tuple:
         with self._mu:                    # reentrant: plan() re-enters
             if self._devices is None:
-                try:
-                    self._devices = tuple(jax.devices())
-                except Exception:  # noqa: BLE001 — no backend: chip-less
-                    self._devices = ()
+                # no backend is an error, not an empty pool: a process
+                # that asked for the device plane and cannot reach it
+                # must not carry on chip-less
+                self._devices = tuple(jax.devices())
                 for dev in self._devices:
                     if len(self._devices) > 1:
                         self._breakers[dev.id] = get_breaker(

@@ -708,8 +708,10 @@ class StateTransferManager:
         if (self._use_device
                 and len(raws) >= self.cfg.device_digest_threshold):
             try:
+                from tpubft.ops.dispatch import device_tier
                 from tpubft.ops.sha256 import sha256_batch_mixed
-                out = sha256_batch_mixed(raws)
+                with device_tier("sha256"):
+                    out = sha256_batch_mixed(raws)
                 self.m_device_batches.inc()
                 return out
             except Exception:  # noqa: BLE001 — device loss degrades, not fails

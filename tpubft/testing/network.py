@@ -163,6 +163,8 @@ class BftTestNetwork:
         # (~10-20s each when the 1-core host is busy) — 30s and 60s both
         # flaked under background load; boot time is not what any of
         # these scenarios measure
+        from tpubft.crypto.backend import check_process_fanout
+        check_process_fanout(self.crypto_backend, self.n)
         try:
             for r in range(self.n):
                 self.start_replica(r)
@@ -179,14 +181,12 @@ class BftTestNetwork:
                       extra_args: Optional[List[str]] = None,
                       extra_env: Optional[Dict[str, str]] = None) -> None:
         assert r not in self.procs or self.procs[r].poll() is not None
-        # persistent kernel cache: device-backend replicas (crypto tpu)
-        # otherwise pay a cold XLA compile per process — the dominant
-        # source of system-test flakiness
-        env = dict(os.environ, PYTHONPATH=_REPO_ROOT, JAX_PLATFORMS="cpu",
-                   JAX_COMPILATION_CACHE_DIR=os.path.join(_REPO_ROOT,
-                                                          ".jax_cache"),
-                   JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="2",
-                   **(extra_env or {}))
+        # children inherit the parent's JAX environment (platform and
+        # compile-cache placement): the tests export JAX_PLATFORMS=cpu,
+        # so `crypto_backend="tpu"` there is the XLA-CPU rehearsal, and
+        # a replica asked for the device backend is never silently put
+        # on the CPU by its launcher
+        env = dict(os.environ, PYTHONPATH=_REPO_ROOT, **(extra_env or {}))
         args = [sys.executable, "-m", "tpubft.apps.skvbc_replica",
                 "--replica", str(r), "--f", str(self.f), "--c", str(self.c),
                 "--ro", str(self.num_ro),
@@ -238,8 +238,7 @@ class BftTestNetwork:
         follower (reference RO TesterReplica variant). Returns its id."""
         rid = self.n + idx
         assert idx < self.num_ro, "construct the network with num_ro"
-        env = dict(os.environ, PYTHONPATH=_REPO_ROOT, JAX_PLATFORMS="cpu",
-                   **(extra_env or {}))
+        env = dict(os.environ, PYTHONPATH=_REPO_ROOT, **(extra_env or {}))
         args = [sys.executable, "-m", "tpubft.apps.ro_replica",
                 "--replica", str(rid), "--f", str(self.f),
                 "--c", str(self.c), "--ro", str(self.num_ro),

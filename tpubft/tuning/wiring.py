@@ -25,6 +25,10 @@ replica exposes and binds each to its seam:
                                         edges; PIN-ONLY, wire-visible)
   ====================================  ==================================
 
+`ecdsa_crossover_b`, `device_min_verify_batch` and the multi-chip
+`crypto_shard_count` register only on a replica whose resolved crypto
+backend is the device.
+
 Knobs with a policy move from live telemetry; the rest are
 catalog/pin/seed surfaces (and still reset on degradation).
 `combine_batch_max` and `agg_fanout` are additionally WIRE-VISIBLE:
@@ -169,37 +173,47 @@ def build_replica_tuning(replica, cfg) -> TuningController:
         controller.add_policy("admission_high_watermark",
                               admission_watermark_policy())
 
-    # --- ECDSA device/host crossover (ROADMAP 4d): process-wide, like
-    # the device itself — measured `ecdsa` kernel tier vs the batched
-    # host engine's drained per-item cost ---
-    from tpubft.crypto import tpu as tpu_mod
-    K("ecdsa_crossover_b", min(tpu_mod.ecdsa_crossover(), MAX_CROSSOVER),
-      1, MAX_CROSSOVER, tpu_mod.set_ecdsa_crossover,
-      "ecdsa kernel per-item cost vs ecdsa_host_us/items", "sigs")
-    controller.add_policy("ecdsa_crossover_b", ecdsa_crossover_policy())
+    # --- device-plane knobs: only a replica whose RESOLVED backend is
+    # the device has them. Reading their defaults starts JAX (platform
+    # crossover, chip inventory), and a cpu-backend replica process must
+    # never take a chip it will not use — the chip serves one process.
+    if replica.crypto_backend == "tpu":
+        # --- ECDSA device/host crossover (ROADMAP 4d): process-wide,
+        # like the device itself — measured `ecdsa` kernel tier vs the
+        # batched host engine's drained per-item cost ---
+        from tpubft.crypto import tpu as tpu_mod
+        K("ecdsa_crossover_b",
+          min(tpu_mod.ecdsa_crossover(), MAX_CROSSOVER), 1, MAX_CROSSOVER,
+          tpu_mod.set_ecdsa_crossover,
+          "ecdsa kernel per-item cost vs ecdsa_host_us/items", "sigs")
+        controller.add_policy("ecdsa_crossover_b",
+                              ecdsa_crossover_policy())
 
-    # --- multi-chip mesh fan-out (ISSUE 16): cap the crypto plane's
-    # shard count from the measured sharded-launch amortization.
-    # Process-wide like the device and the crossover; default = every
-    # chip, so the degraded-rule reset (any breaker non-CLOSED,
-    # including an evicted chip's `device.chip<N>` child) restores full
-    # width for the post-recovery remeasure ---
-    from tpubft.ops import dispatch as dispatch_mod
-    n_chips = dispatch_mod.crypto_mesh().device_count()
-    if n_chips > 1:
-        K("crypto_shard_count", n_chips, 1, n_chips,
-          dispatch_mod.crypto_mesh().set_shard_count,
-          "ed25519.shard per-item cost vs full-batch trend", "chips")
-        controller.add_policy("crypto_shard_count", crypto_shard_policy())
+        # --- multi-chip mesh fan-out (ISSUE 16): cap the crypto plane's
+        # shard count from the measured sharded-launch amortization.
+        # Process-wide like the device and the crossover; default =
+        # every chip, so the degraded-rule reset (any breaker non-CLOSED,
+        # including an evicted chip's `device.chip<N>` child) restores
+        # full width for the post-recovery remeasure ---
+        from tpubft.ops import dispatch as dispatch_mod
+        n_chips = dispatch_mod.crypto_mesh().device_count()
+        if n_chips > 1:
+            K("crypto_shard_count", n_chips, 1, n_chips,
+              dispatch_mod.crypto_mesh().set_shard_count,
+              "ed25519.shard per-item cost vs full-batch trend", "chips")
+            controller.add_policy("crypto_shard_count",
+                                  crypto_shard_policy())
 
-    # --- device-launch floor (ISSUE 18 satellite): the smallest batch
-    # worth a device ride follows the ed25519 kernel's warm per-item
-    # trend — falling cost lowers the floor, rising cost raises it ---
-    K("device_min_verify_batch", cfg.device_min_verify_batch, 1,
-      MAX_BATCH, lambda v: setattr(replica.sig, "device_min_batch", v),
-      "ed25519 warm per-item cost trend", "sigs")
-    controller.add_policy("device_min_verify_batch",
-                          device_min_batch_policy())
+        # --- device-launch floor (ISSUE 18 satellite): the smallest
+        # batch worth a device ride follows the ed25519 kernel's warm
+        # per-item trend — falling cost lowers the floor, rising cost
+        # raises it ---
+        K("device_min_verify_batch", cfg.device_min_verify_batch, 1,
+          MAX_BATCH,
+          lambda v: setattr(replica.sig, "device_min_batch", v),
+          "ed25519 warm per-item cost trend", "sigs")
+        controller.add_policy("device_min_verify_batch",
+                              device_min_batch_policy())
 
     def apply_st_window(v: int) -> None:
         # late-bound: the kvbc layer attaches state transfer after the
