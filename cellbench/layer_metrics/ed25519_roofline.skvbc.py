@@ -1,0 +1,8 @@
+"""The ed25519 kernel's share of its roofline in the served cell: the
+least time for the signatures the device was given in the traced
+window over the kernel's device time there."""
+from cellbench.roofline import share
+
+
+def read(ctx):
+    return share(ctx, "ed25519")
