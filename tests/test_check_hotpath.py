@@ -95,6 +95,35 @@ def test_lint_catches_telemetry_violations(tmp_path):
     assert tool.find_violations(str(tmp_path)) == []
 
 
+def test_lint_rejects_flight_span_in_a_hot_handler(tmp_path):
+    """`flight.span` is for batch-level work OFF the dispatcher (one
+    object, one profiler annotation per interval): inside a hot handler
+    it is flagged like `start_span`; `flight.record` stays the one
+    sanctioned call, and reading a `.span` attribute is not a call."""
+    tool = _load_tool()
+    del tool.HOT_PATH[("tpubft/consensus/replica.py", "Replica")]
+    mod_dir = tmp_path / "tpubft" / "consensus"
+    mod_dir.mkdir(parents=True)
+    (mod_dir / "incoming.py").write_text(textwrap.dedent("""\
+        class Dispatcher:
+            def _loop_body(self):
+                with flight.span("handler", seq=self.seq):
+                    self.handle()
+    """))
+    violations = tool.find_violations(str(tmp_path))
+    assert len(violations) == 1, violations
+    assert "calls span()" in violations[0][2] \
+        and "flight.record" in violations[0][2]
+    (mod_dir / "incoming.py").write_text(textwrap.dedent("""\
+        class Dispatcher:
+            def _loop_body(self):
+                flight.record(flight.EV_DISPATCH, seq=self.seq)
+                if self.info.span is not None:
+                    self.info.span = None
+    """))
+    assert tool.find_violations(str(tmp_path)) == []
+
+
 def test_hot_path_list_matches_source():
     """Every listed handler exists in the real tree (find_violations
     reports missing ones; an empty result implies full coverage)."""

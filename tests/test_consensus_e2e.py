@@ -45,14 +45,22 @@ def test_read_only_request_fast_path():
 def test_duplicate_request_gets_cached_reply():
     with InProcessCluster(f=1) as cluster:
         cl = cluster.client()
+        def executed(at_least):
+            # a reply quorum is 2f+1 and the replies leave before the
+            # dispatcher books the run: give replica 0 a moment to count
+            deadline = time.monotonic() + 5
+            while cluster.metric(0, "counters", "executed_requests") \
+                    < at_least and time.monotonic() < deadline:
+                time.sleep(0.01)
+            return cluster.metric(0, "counters", "executed_requests")
+
         r1 = cl.send_write(counter.encode_add(7))
         # metrics: executed once per replica; a client retransmission of an
         # executed request must not re-execute (reply cache)
-        executed_before = cluster.metric(0, "counters", "executed_requests")
+        executed_before = executed(1)
         r2 = cl.send_write(counter.encode_add(7))
         assert counter.decode_reply(r2) == 14  # new request executes
-        assert cluster.metric(0, "counters", "executed_requests") \
-            == executed_before + 1
+        assert executed(executed_before + 1) == executed_before + 1
 
 
 def test_two_clients_interleaved():

@@ -5,8 +5,12 @@ tools/tpuprof rendering, and the chaos-campaign red-verdict attachment.
 """
 import json
 import os
+import subprocess
+import sys
 import threading
 import time
+
+import pytest
 
 from tpubft.diagnostics import DiagnosticsServer, Registrar, TimeRecorder
 from tpubft.tools import ctl
@@ -113,7 +117,11 @@ def test_fold_stage_math():
     stages = SlotTracker.fold(slot)
     assert stages == {"adm_wait": 2.0, "dispatch": 1.0, "prepare": 7.0,
                       "commit": 5.0, "exec": 10.0, "reply": 1.0,
-                      "spec_overlap": 0.0, "cert_lag": 0.0}
+                      "spec_overlap": 0.0, "cert_lag": 0.0,
+                      # no lane start / queue stamp / group on record:
+                      # exec reads as all service, the rest as nothing
+                      "order_wait": 0.0, "exec_wait": 0.0,
+                      "exec_run": 10.0, "dur_wait": 0.0}
     # fast path: no prepare quorum — prepare reads 0, commit runs from
     # accept; a primary self-proposal has no admit/handler anchors
     fast = {"accept": t0, "committed": t0 + 4_000_000,
@@ -202,6 +210,278 @@ def test_slot_tracker_live_bound():
     tr.reset()
 
 
+# ---------------- request accounting inside the replica ----------------
+
+MS = 1_000_000
+T0 = 1_000_000_000
+
+
+def _fold_events(events, rid=7, seq=5):
+    """Feed (code, arg, offset_ms) rows for one slot through the live
+    tracker; returns the finalized record."""
+    flight.reset()
+    tr = flight.slot_tracker()
+    for code, arg, at_ms in events:
+        target = arg if code == flight.EV_DUR_GROUP else seq
+        tr.on_event(rid, code, target, 0,
+                    0 if code == flight.EV_DUR_GROUP else arg,
+                    T0 + int(at_ms * MS))
+    return tr.recent(rid=rid)[-1]
+
+
+def test_order_wait_folds_from_pp_create_on_the_primary_only():
+    primary = _fold_events([
+        (flight.EV_PP_CREATE, 3_250_000, 0),      # oldest waited 3.25 s
+        (flight.EV_PP_ACCEPT, 31, 0.1),           # 31 requests
+        (flight.EV_COMMITTED, 0, 5), (flight.EV_EXEC_APPLY, 1, 9),
+        (flight.EV_REPLY, 0, 10)])
+    assert primary["stages_ms"]["order_wait"] == 3250.0
+    assert primary["reqs"] == 31
+    # an overlay: the wait came before the slot existed
+    assert primary["total_ms"] == pytest.approx(
+        sum(primary["stages_ms"][s] for s in flight.PIPELINE_STAGES))
+    backup = _fold_events([
+        (flight.EV_PP_ACCEPT, 31, 0.1), (flight.EV_COMMITTED, 0, 5),
+        (flight.EV_EXEC_APPLY, 1, 9), (flight.EV_REPLY, 0, 10)], rid=8)
+    assert backup["stages_ms"]["order_wait"] == 0.0
+    assert backup["reqs"] == 31
+
+
+@pytest.mark.parametrize("name,events,wait,run", [
+    # committed at 5, the lane reached it at 45, applied at 50
+    ("normal", [(flight.EV_COMMITTED, 0, 5), (flight.EV_EXEC_START, 4, 45),
+                (flight.EV_EXEC_APPLY, 4, 50)], 40.0, 5.0),
+    # speculation ran ahead of the commit: no wait, and the run is what
+    # was left of it after the commit
+    ("speculated_ahead", [(flight.EV_SPEC_ENQ, 0, 1),
+                          (flight.EV_EXEC_START, 1, 2),
+                          (flight.EV_COMMITTED, 0, 5),
+                          (flight.EV_EXEC_APPLY, 1, 6),
+                          (flight.EV_SPEC_SEAL, 1, 6)], 0.0, 1.0),
+    # the staging was discarded: the start that counts is the
+    # re-execution's, after the commit
+    ("aborted_speculation", [(flight.EV_SPEC_ENQ, 0, 1),
+                             (flight.EV_EXEC_START, 1, 2),
+                             (flight.EV_SPEC_ABORT, 0, 3),
+                             (flight.EV_COMMITTED, 0, 5),
+                             (flight.EV_EXEC_START, 2, 25),
+                             (flight.EV_EXEC_APPLY, 2, 30)], 20.0, 5.0),
+    # no lane start on record: all of exec is service
+    ("no_start", [(flight.EV_COMMITTED, 0, 5),
+                  (flight.EV_EXEC_APPLY, 1, 30)], 0.0, 25.0),
+])
+def test_exec_splits_into_wait_and_run(name, events, wait, run):
+    rec = _fold_events([(flight.EV_PP_ACCEPT, 1, 0)] + events
+                       + [(flight.EV_REPLY, 0, 60)])
+    st = rec["stages_ms"]
+    assert st["exec_wait"] == pytest.approx(wait)
+    assert st["exec_run"] == pytest.approx(run)
+    assert st["exec_wait"] + st["exec_run"] == pytest.approx(st["exec"])
+    assert sum(st[s] for s in flight.PIPELINE_STAGES) \
+        == pytest.approx(rec["total_ms"], abs=0.01)
+
+
+def test_dur_wait_folds_from_the_group_watermark():
+    rec = _fold_events([
+        (flight.EV_PP_ACCEPT, 1, 0), (flight.EV_COMMITTED, 0, 5),
+        (flight.EV_EXEC_START, 1, 6), (flight.EV_EXEC_APPLY, 1, 10),
+        (flight.EV_DUR_GROUP, 4, 11),     # watermark 4: not this slot's
+        (flight.EV_DUR_GROUP, 9, 17),     # watermark 9 covers seq 5
+        (flight.EV_DUR_GROUP, 12, 30),    # a later group moves nothing
+        (flight.EV_REPLY, 0, 19)])
+    st = rec["stages_ms"]
+    assert st["dur_wait"] == pytest.approx(7.0)
+    assert st["dur_wait"] <= st["reply"] == pytest.approx(9.0)
+    # a sibling replica's group is not this replica's
+    flight.reset()
+    tr = flight.slot_tracker()
+    tr.on_event(1, flight.EV_EXEC_APPLY, 5, 0, 1, T0)
+    tr.on_event(2, flight.EV_DUR_GROUP, 9, 0, 1, T0 + MS)
+    tr.on_event(1, flight.EV_REPLY, 5, 0, 0, T0 + 2 * MS)
+    assert tr.recent(rid=1)[-1]["stages_ms"]["dur_wait"] == 0.0
+    # without the pipeline there is no group: 0, and a group that lands
+    # after the reply is clamped into it
+    late = SlotTracker.fold({"applied": T0, "replied": T0 + MS,
+                             "durable": T0 + 5 * MS})
+    assert late["dur_wait"] == late["reply"] == 1.0
+
+
+def test_recent_holds_4096_slots():
+    flight.reset()
+    tr = flight.slot_tracker()
+    assert SlotTracker.KEEP == 4096
+    for seq in range(1, SlotTracker.KEEP + 11):
+        tr.on_event(3, flight.EV_PP_ACCEPT, seq, 0, 0, T0 + seq)
+        tr.on_event(3, flight.EV_REPLY, seq, 0, 0, T0 + seq + 1)
+    rows = tr.recent(limit=SlotTracker.KEEP)
+    assert len(rows) == 4096
+    assert rows[0]["seq"] == 11 and rows[-1]["seq"] == SlotTracker.KEEP + 10
+    tr.reset()
+
+
+def test_tpuprof_replays_the_new_events_like_the_live_tracker():
+    from tools import tpuprof
+    flight.reset()
+    flight.set_thread_rid(6)
+    for code, seq, arg in (
+            (flight.EV_PP_CREATE, 21, 1500), (flight.EV_PP_ACCEPT, 21, 7),
+            (flight.EV_COMMITTED, 21, 0), (flight.EV_EXEC_START, 21, 1),
+            (flight.EV_EXEC_APPLY, 21, 1), (flight.EV_DUR_GROUP, 21, 1),
+            (flight.EV_REPLY, 21, 0)):
+        flight.record(code, seq=seq, arg=arg)
+    with flight.span("tpuprof_span_case", 21):
+        pass
+    live = flight.slot_tracker().recent(rid=6)[-1]["stages_ms"]
+    dump = flight.snapshot()
+    slot = tpuprof.fold_slots(dump)[(6, 21)]
+    replayed = SlotTracker.fold(slot)
+    assert {k: round(v, 3) for k, v in replayed.items()} == live
+    assert live["order_wait"] == 1.5 and slot["reqs"] == 7
+    assert "durable" in slot
+    dump["_path"] = "live"
+    assert any("tpuprof_span_case" in line
+               for line in tpuprof.span_table([dump]))
+    assert any("order_wait" in line for line in tpuprof.stage_table([dump]))
+
+
+def test_live_cluster_write_accounts_for_order_and_lane():
+    """A real write through an f=1 cluster: the primary's row carries
+    the queue wait and the lane's own run; every row's split sums."""
+    from tpubft.apps import counter
+    from tpubft.testing import InProcessCluster
+    flight.reset()
+    with InProcessCluster(f=1) as cluster:
+        cl = cluster.client()
+        for _ in range(3):
+            cl.send_write(counter.encode_add(1), timeout_ms=20000)
+        deadline = time.monotonic() + 10
+        while time.monotonic() < deadline:
+            rows = flight.slot_tracker().recent(limit=SlotTracker.KEEP)
+            if [r for r in rows if r["stages_ms"]["order_wait"] > 0]:
+                break
+            time.sleep(0.05)
+    primary = [r for r in rows if r["stages_ms"]["order_wait"] > 0]
+    assert primary, rows
+    assert len({r["rid"] for r in primary}) == 1      # one primary
+    assert all(r["stages_ms"]["exec_run"] > 0 for r in primary)
+    assert all(r["reqs"] >= 1 for r in primary)
+    for r in rows:
+        st = r["stages_ms"]
+        assert st["exec_wait"] + st["exec_run"] \
+            == pytest.approx(st["exec"], abs=0.002)
+        assert st["dur_wait"] <= st["reply"]
+    # the lane's run and the durability group are flight spans now
+    names = {n for n in ("exec_run", "dur_group")
+             if flight.span_events(n)}
+    assert "exec_run" in names
+
+
+# ---------------- flight.span ----------------
+
+def _my_events(code):
+    me = threading.current_thread().name
+    ring = next(r for r in flight.snapshot()["rings"] if r["thread"] == me)
+    return [e for e in ring["events"] if e[1] == code]
+
+
+def test_span_writes_one_event_with_its_name_and_duration():
+    flight.reset()
+    with flight.span("unit_span", seq=12):
+        time.sleep(0.003)
+    evs = _my_events(flight.EV_SPAN)
+    assert len(evs) == 1
+    t, _code, seq, view, us = evs[0]
+    assert seq == 12 and us >= 2500
+    snap = flight.snapshot()
+    assert snap["span_names"][str(view)] == "unit_span"
+    assert snap["event_names"][str(flight.EV_SPAN)] == "span"
+    assert flight.span_events("unit_span") == [(t, 12, us)]
+    assert flight.span_events("unit_span", since_ns=t + 1) == []
+    assert flight.span_events("never_opened") == []
+    # summed time written once, by the caller
+    flight.record_span("unit_sum", 1234, seq=3)
+    assert [(s, u) for _t, s, u in flight.span_events("unit_sum")] \
+        == [(3, 1234)]
+    # an exception leaves through the span and is still recorded
+    with pytest.raises(KeyError):
+        with flight.span("unit_span"):
+            raise KeyError("x")
+    assert len(flight.span_events("unit_span")) == 2
+
+
+def test_span_events_refuses_a_ring_that_wrapped_past_the_window():
+    flight.reset()
+    with flight.span("wrap_span"):
+        pass
+    t_first = flight.span_events("wrap_span")[0][0]
+    for i in range(flight.RING_SIZE):
+        flight.record(flight.EV_ADM_INGEST, arg=i)
+    with flight.span("wrap_span"):
+        pass
+    # the window reaches back past what the ring still holds
+    assert flight.span_events("wrap_span", since_ns=t_first) is None
+    later = flight.span_events("wrap_span",
+                               since_ns=time.monotonic_ns() - 1000)
+    assert later is not None
+    flight.reset()
+
+
+def test_span_is_nothing_when_the_recorder_is_off():
+    flight.reset()
+    flight._set_enabled(False)
+    try:
+        cm = flight.span("off_span")
+        assert cm is flight.span("other")         # one shared no-op
+        with cm:
+            pass
+        flight.record_span("off_sum", 5)
+        assert not _my_events(flight.EV_SPAN)
+    finally:
+        flight._set_enabled(True)
+    assert not flight.span_events("off_span")
+
+
+def test_span_annotates_the_profiler_only_once_jax_is_imported(
+        monkeypatch):
+    seen = []
+
+    class Ann:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            seen.append(("enter", self.name))
+
+        def __exit__(self, *exc):
+            seen.append(("exit", self.name))
+
+    monkeypatch.setattr(flight, "_trace_annotation", Ann)
+    with flight.span("annotated"):
+        seen.append("body")
+    assert seen == [("enter", "tpubft:annotated"), "body",
+                    ("exit", "tpubft:annotated")]
+
+
+def test_bls_share_decompression_is_one_span_per_combine():
+    from tpubft.crypto.interfaces import Cryptosystem
+    cs = Cryptosystem("threshold-bls", threshold=2, num_signers=3,
+                      seed=b"flight-bls")
+    digest = b"d" * 32
+    verifier = cs.create_threshold_verifier()
+    acc = verifier.new_accumulator(with_share_verification=False)
+    acc.set_expected_digest(digest)
+    shares = [(i, cs.create_threshold_signer(i).sign_share(digest))
+              for i in (1, 2)]
+    flight.reset()
+    for i, share in shares:
+        acc.add(i, share)
+    assert not flight.span_events("bls_share_decompress")   # not per share
+    acc.get_full_signed_data()
+    acc.get_full_signed_data()                  # nothing new to report
+    spans = flight.span_events("bls_share_decompress")
+    assert len(spans) == 1 and spans[0][2] > 0
+
+
 # ---------------- kernel profiler ----------------
 
 def test_device_section_profiles_kernels():
@@ -223,6 +503,145 @@ def test_device_section_profiles_kernels():
                 if r["thread"] == me)
     codes = [e[1] for e in ring["events"]]
     assert flight.EV_DEV_ENTER in codes and flight.EV_DEV_EXIT in codes
+
+
+def test_gate_wait_is_recorded_and_stays_off_the_breaker_s_clock():
+    from tpubft.ops.dispatch import (device_breaker, device_dispatch,
+                                     device_section)
+    flight.reset()
+    br = device_breaker()
+    br.reset()
+    slo0 = br.latency_slo_s
+    br.latency_slo_s = 0.030        # the wait alone would breach it
+    holding, release = threading.Event(), threading.Event()
+
+    def holder():
+        with device_dispatch():         # the raw gate: no attempt of
+            holding.set()               # its own on the breaker's books
+            release.wait(5)
+
+    t = threading.Thread(target=holder, name="gate-holder")
+    t.start()
+    try:
+        assert holding.wait(5)
+        threading.Timer(0.05, release.set).start()
+        before = br.snapshot()
+        with device_section("gatewaiter", batch=2):
+            pass
+        t.join(5)
+        row = flight.kernel_profiler().call_rows("gatewaiter")[-1]
+        assert row["gate_wait_us"] >= 40_000
+        assert row["device_us"] < 20_000 and row["prep_us"] == 0
+        after = br.snapshot()
+        # queueing behind a healthy thread is not a slow device
+        assert after["state"] == "closed"
+        assert after["slo_breaches"] == before["slo_breaches"]
+        assert after["failures"] == before["failures"]
+        snap = flight.kernel_profiler().snapshot()["gatewaiter"]
+        assert snap["gate_wait_ms"] >= 40 and snap["prep_ms"] == 0
+    finally:
+        release.set()
+        br.latency_slo_s = slo0
+        br.reset()
+
+
+def test_call_rows_have_dense_ordinals_and_survive_snapshot():
+    from tpubft.ops.dispatch import device_section
+    flight.reset()
+    prof = flight.kernel_profiler()
+    for i in range(5):
+        with device_section("rowkind_a", batch=i + 1):
+            pass
+        if i % 2:
+            with device_section("rowkind_b", batch=10, shards=2):
+                pass
+    mid = prof.snapshot()
+    for _ in range(2):
+        with device_section("rowkind_a", batch=9):
+            pass
+    a, b = prof.call_rows("rowkind_a"), prof.call_rows("rowkind_b")
+    assert [r["ordinal"] for r in a] == [1, 2, 3, 4, 5, 6, 7]
+    assert [r["ordinal"] for r in b] == [1, 2]
+    assert [r["batch"] for r in a] == [1, 2, 3, 4, 5, 9, 9]
+    # the shard view of a launch has totals, and no row of its own
+    assert prof.snapshot()["rowkind_b.shard"]["calls"] == 2
+    assert not prof.call_rows("rowkind_b.shard")
+    # a reader cuts a window by the `calls` it snapshotted, no clock
+    end = prof.snapshot()
+    cut = [r for r in a if mid["rowkind_a"]["calls"] < r["ordinal"]
+           <= end["rowkind_a"]["calls"]]
+    assert [r["batch"] for r in cut] == [9, 9]
+    assert set(a[0]) == {"kind", "ordinal", "batch", "t_enter_ns",
+                         "prep_us", "gate_wait_us", "device_us"}
+    assert all(x["t_enter_ns"] < y["t_enter_ns"] for x, y in zip(a, a[1:]))
+    # the dump carries them, and taking it changes nothing
+    dumped = [r for r in flight.snapshot()["kernel_calls"]
+              if r["kind"] == "rowkind_a"]
+    assert dumped == a == prof.call_rows("rowkind_a")
+    # bounded
+    for _ in range(flight.KernelProfiler.CALL_ROWS + 5):
+        prof.record("rowkind_c", 1, 1000, "closed")
+    assert len(prof.call_rows()) == flight.KernelProfiler.CALL_ROWS
+    flight.reset()
+    assert prof.call_rows() == []
+
+
+def test_tier_accounts_prep_round_its_sections():
+    from tpubft.ops.dispatch import device_section, device_tier
+    flight.reset()
+    with device_tier("tierkind"):
+        time.sleep(0.004)                          # prep before
+        with device_section("tierkind", batch=3):
+            time.sleep(0.002)
+            with device_section("tierkind.inner", batch=1):   # re-entrant
+                pass
+        time.sleep(0.003)                          # between two launches
+        with device_tier("nested"):                # passes through
+            with device_section("tierkind", batch=4):
+                pass
+        time.sleep(0.005)                          # tail
+    first, second = flight.kernel_profiler().call_rows("tierkind")
+    assert 3500 <= first["prep_us"] < 20_000
+    assert first["device_us"] >= 1500
+    # the second launch owns the gap before it and the tier's tail
+    assert 7000 <= second["prep_us"] < 30_000
+    inner = flight.kernel_profiler().call_rows("tierkind.inner")[0]
+    assert inner["prep_us"] == 0        # ran inside its parent's device
+    snap = flight.kernel_profiler().snapshot()["tierkind"]
+    assert snap["prep_ms"] == pytest.approx(
+        (first["prep_us"] + second["prep_us"]) / 1e3, abs=0.01)
+    # outside any tier a section has no prep to report
+    with device_section("tierkind", batch=1):
+        pass
+    assert flight.kernel_profiler().call_rows("tierkind")[-1][
+        "prep_us"] == 0
+
+
+def test_device_seam_imports_leave_jax_out_and_off_means_off():
+    """A host-only replica must never pay the jax import for its
+    telemetry; with TPUBFT_FLIGHT=0 the new seams keep nothing."""
+    code = (
+        "import sys\n"
+        "from tpubft.ops import dispatch\n"
+        "from tpubft.utils import flight\n"
+        "with dispatch.device_tier('k'):\n"
+        "    with dispatch.device_section('k', batch=2):\n"
+        "        pass\n"
+        "with flight.span('s'):\n"
+        "    pass\n"
+        "flight.record_span('t', 7)\n"
+        "flight.record(flight.EV_EXEC_START, seq=1)\n"
+        "print('jax' in sys.modules, flight.enabled(),\n"
+        "      len(flight.kernel_profiler().call_rows()),\n"
+        "      len(flight.span_events('s') or []),\n"
+        "      flight.stage_summary()['live'])\n")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    for flag, want in (("1", "False True 1 1 1"), ("0", "False False 0 0 0")):
+        env = dict(os.environ, TPUBFT_FLIGHT=flag, PYTHONPATH=root)
+        out = subprocess.run([sys.executable, "-c", code], env=env,
+                             capture_output=True, text=True, timeout=120)
+        assert out.returncode == 0, out.stderr
+        assert out.stdout.split() == want.split(), (flag, out.stdout)
 
 
 # ---------------- diagnostics surfaces ----------------

@@ -8,7 +8,8 @@ named `_verify_*` fallback seams for the admission_workers=0 path).
 
 They must also emit telemetry ONLY through the bounded flight-recorder
 API (`flight.record(...)` — tpubft/utils/flight.py): span allocation
-(`get_tracer`/`start_span`/`set_tag`) and f-string construction are
+(`get_tracer`/`start_span`/`set_tag`, and `flight.span`, which is for
+batch-level work OFF the dispatcher) and f-string construction are
 per-message heap work the hot path must not pay — the recorder exists
 precisely so hot-seam observability costs one tuple into a
 preallocated ring. (Logging through %-style lazy formatting stays
@@ -57,7 +58,7 @@ FORBIDDEN_CALLS = {"unpack", "verify", "verify_batch"}
 # span-allocation observability: per-message heap work the flight
 # recorder replaces on the hot path (flight.record is the ONE allowed
 # telemetry call in the handlers above)
-FORBIDDEN_TELEMETRY = {"get_tracer", "start_span", "set_tag"}
+FORBIDDEN_TELEMETRY = {"get_tracer", "start_span", "set_tag", "span"}
 
 
 def _call_name(node: ast.Call) -> str:

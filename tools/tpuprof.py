@@ -11,9 +11,13 @@ transitions and chaos-campaign red verdicts, or on demand via
     pair), so "replica 2 committed 40ms after replica 0" is a table
     row, not an archaeology session;
   * a STAGE-HISTOGRAM table: adm_wait / dispatch / prepare / commit /
-    exec / reply percentiles over every completed slot in the dumps;
+    exec / reply percentiles over every completed slot in the dumps,
+    with the order_wait / exec_wait / exec_run / dur_wait sub-stages;
   * the KERNEL profile per dump (call counts, batch sizes, compile
-    warmup vs warm time, breaker states at call time);
+    warmup vs warm time, prep / gate-wait totals, breaker states at
+    call time);
+  * the flight SPANS (`flight.span`: lane runs, admission drains,
+    combine flushes, durability groups, the BLS host path) by name;
   * spans grouped by trace id (the cross-replica request join).
 
 Usage:
@@ -57,23 +61,23 @@ def _epoch_of(dump: Dict, t_ns: int) -> float:
 
 
 def fold_slots(dump: Dict) -> Dict[Tuple[int, int], Dict]:
-    """Rebuild slot lifecycles from the dump's raw ring events (the
-    same fold the live SlotTracker applies — flight.SlotTracker.fold is
-    the shared stage math). Keyed (rid, seq)."""
-    field_of = flight.SlotTracker._FIELD
+    """Rebuild slot lifecycles from the dump's raw ring events through
+    the live SlotTracker's own fold (`stamp` / `stamp_durable` /
+    `fold` are the shared stage math). Events are replayed in time
+    order across rings: a durability group marks the slots applied
+    BEFORE it. Keyed (rid, seq)."""
+    tracker = flight.SlotTracker
+    events = sorted((ev[0], ring.get("rid", -1), ev)
+                    for ring in dump.get("rings", [])
+                    for ev in ring.get("events", []))
     slots: Dict[Tuple[int, int], Dict] = {}
-    for ring in dump.get("rings", []):
-        rid = ring.get("rid", -1)
-        for ev in ring.get("events", []):
-            t_ns, code, seq, view, arg = ev
-            field = field_of.get(code)
-            if field is None:
-                continue
+    for _t, rid, (t_ns, code, seq, view, arg) in events:
+        if code == flight.EV_DUR_GROUP:
+            tracker.stamp_durable(slots.values(), rid, seq, t_ns)
+        elif code in tracker._FIELD or code == flight.EV_SPEC_ABORT:
             slot = slots.setdefault((rid, seq),
                                     {"rid": rid, "seq": seq, "view": view})
-            slot.setdefault(field, t_ns)
-            if code == flight.EV_COMMITTED:
-                slot.setdefault("path", "fast" if arg else "slow")
+            tracker.stamp(slot, code, arg, t_ns)
     return slots
 
 
@@ -98,7 +102,7 @@ def timeline(dumps: List[Dict], seq_filter: Optional[int] = None,
                 (_label(d, rid), slot, stages, d))
     out = ["slot timeline (ms per stage; t0 = first event's wall clock)",
            f"{'seq':>6} {'replica':<28} {'t0':>10} "
-           + " ".join(f"{s:>9}" for s in STAGES) + f" {'total':>9} path"]
+           + " ".join(f"{s:>12}" for s in STAGES) + f" {'total':>9} path"]
     seqs = sorted(rows)
     if seq_filter is None and len(seqs) > limit:
         seqs = seqs[-limit:]
@@ -114,7 +118,8 @@ def timeline(dumps: List[Dict], seq_filter: Optional[int] = None,
         for label, slot, stages, dump in sorted(
                 rows[seq], key=lambda r: r[0]):
             ts = [v for k, v in slot.items()
-                  if k not in ("rid", "seq", "view", "path")]
+                  if k not in ("rid", "seq", "view", "path", "reqs",
+                               "order_wait_us")]
             t0 = ""
             if ts and base_epoch is not None:
                 t0 = f"{_epoch_of(dump, min(ts)) - base_epoch:+.3f}s"
@@ -124,7 +129,7 @@ def timeline(dumps: List[Dict], seq_filter: Optional[int] = None,
             total = sum(stages[s] for s in flight.PIPELINE_STAGES)
             out.append(
                 f"{seq:>6} {label:<28} {t0:>10} "
-                + " ".join(f"{stages[s]:>9.3f}" for s in STAGES)
+                + " ".join(f"{stages[s]:>12.3f}" for s in STAGES)
                 + f" {total:>9.3f} {slot.get('path', '?')}")
     return out
 
@@ -147,23 +152,24 @@ def stage_table(dumps: List[Dict]) -> List[str]:
             for s in STAGES:
                 vals[s].append(stages[s])
     out = ["stage histogram (ms over all completed slots)",
-           f"{'stage':<10} {'count':>7} {'avg':>9} {'p50':>9} "
+           f"{'stage':<12} {'count':>7} {'avg':>9} {'p50':>9} "
            f"{'p95':>9} {'max':>9}"]
     for s in STAGES:
         v = sorted(vals[s])
         n = len(v)
         if not n:
-            out.append(f"{s:<10} {0:>7}")
+            out.append(f"{s:<12} {0:>7}")
             continue
-        out.append(f"{s:<10} {n:>7} {sum(v) / n:>9.3f} {v[n // 2]:>9.3f} "
+        out.append(f"{s:<12} {n:>7} {sum(v) / n:>9.3f} {v[n // 2]:>9.3f} "
                    f"{v[min(n - 1, int(n * 0.95))]:>9.3f} {v[-1]:>9.3f}")
     return out
 
 
 def kernel_table(dumps: List[Dict]) -> List[str]:
-    out = ["kernel profile",
+    out = ["kernel profile (ms; prep / gate wait / device are totals)",
            f"{'dump':<24} {'kind':<10} {'calls':>6} {'first(ms)':>10} "
-           f"{'warm avg':>9} {'max':>9} {'batch avg':>10} {'breaker'}"]
+           f"{'warm avg':>9} {'max':>9} {'batch avg':>10} {'prep':>9} "
+           f"{'gate wait':>9} {'device':>9} {'breaker'}"]
     for d in dumps:
         base = os.path.basename(d["_path"])
         for kind, st in sorted(d.get("kernels", {}).items()):
@@ -171,7 +177,30 @@ def kernel_table(dumps: List[Dict]) -> List[str]:
                 f"{base:<24} {kind:<10} {st['calls']:>6} "
                 f"{st['first_call_ms']:>10.3f} {st['warm_avg_ms']:>9.3f} "
                 f"{st['max_ms']:>9.3f} {st['batch_avg']:>10.1f} "
+                f"{st.get('prep_ms', 0.0):>9.3f} "
+                f"{st.get('gate_wait_ms', 0.0):>9.3f} "
+                f"{st['total_ms']:>9.3f} "
                 f"{st.get('breaker_states', {})}")
+    return out
+
+
+def span_table(dumps: List[Dict]) -> List[str]:
+    """EV_SPAN rows by name (`flight.span` / `record_span`): the id in
+    the event's view field resolves through the dump's `span_names`."""
+    vals: Dict[str, List[float]] = {}
+    for d in dumps:
+        names = d.get("span_names", {})
+        for ring in d.get("rings", []):
+            for _t, code, _seq, view, arg in ring.get("events", []):
+                if code == flight.EV_SPAN:
+                    vals.setdefault(names.get(str(view), f"#{view}"),
+                                    []).append(arg / 1e3)
+    out = ["flight spans (ms)",
+           f"{'span':<24} {'count':>7} {'avg':>9} {'p50':>9} {'max':>9}"]
+    for name in sorted(vals):
+        v = sorted(vals[name])
+        out.append(f"{name:<24} {len(v):>7} {sum(v) / len(v):>9.3f} "
+                   f"{v[len(v) // 2]:>9.3f} {v[-1]:>9.3f}")
     return out
 
 
@@ -202,6 +231,7 @@ def render(paths: List[str], seq: Optional[int] = None,
         stage_table(dumps),
         timeline(dumps, seq_filter=seq, limit=limit),
         kernel_table(dumps),
+        span_table(dumps),
         trace_table(dumps),
     ]
     return "\n\n".join("\n".join(s) for s in sections)

@@ -247,6 +247,15 @@ class CombineBatcher:
             flight.set_thread_rid(self._rid)
             self._rid_seeded = True
         flight.record(flight.EV_COMBINE_FLUSH, arg=len(batch))
+        with flight.span("combine_flush"):
+            self._combine(batch)
+        if self._on_flush is not None:
+            try:
+                self._on_flush(len(batch))
+            except Exception:  # noqa: BLE001 — metrics must not kill
+                pass           # the combine plane
+
+    def _combine(self, batch) -> None:
         # group by verifier object (stable identity — see
         # CertBatchVerifier._drain): slow-path prepare/commit share one
         # verifier, fast paths their own, so one flush usually makes
@@ -276,11 +285,6 @@ class CombineBatcher:
                 self._post(CombineResult(c.view, c.seq_num, c.kind,
                                          bool(ok), sig if ok else b"",
                                          list(bad), collector=c))
-        if self._on_flush is not None:
-            try:
-                self._on_flush(len(batch))
-            except Exception:  # noqa: BLE001 — metrics must not kill
-                pass           # the combine plane
 
     def stop(self) -> None:
         self._batcher.stop()

@@ -115,7 +115,6 @@ class _SpecRun:
     t_confirmed: float = 0.0              # monotonic: last commit in
     abort: bool = False
     acc: bool = False                     # accumulation bracket open
-    span: Optional[object] = None
     # checkpoint-boundary digests PRECOMPUTED at staging (ISSUE 18a):
     # (seq, state_digest, head) — the lane parks between staging and
     # seal, so the handler state cannot move; riding them on the
@@ -431,7 +430,8 @@ class ExecutionLane:
                     continue
                 if action == "seal":
                     try:
-                        self._seal_spec_run(sp)
+                        with flight.span("exec_run", sp.first):
+                            self._seal_spec_run(sp)
                         if health is not None:
                             health.beat("exec_lane")   # durable apply
                     except Exception:  # noqa: BLE001 — pre-durability
@@ -455,13 +455,17 @@ class ExecutionLane:
                     self._r.m_exec_lane_depth.set(self.depth)
                     continue
                 if run and run[0][2]:
-                    self._stage_into_spec(sp, [(s, pp)
-                                               for s, pp, _f in run])
+                    with flight.span("exec_run", sp.first):
+                        self._stage_into_spec(sp, [(s, pp)
+                                                   for s, pp, _f in run])
                     self._r.m_exec_lane_depth.set(self.depth)
                     continue
                 plain = [(s, pp) for s, pp, _f in run]
                 try:
-                    self._execute_run(plain)
+                    # the lane's run on both clocks (execute + coalesced
+                    # apply); a speculative run is two: staging, seal
+                    with flight.span("exec_run", plain[0][0]):
+                        self._execute_run(plain)
                     if health is not None:
                         health.beat("exec_lane")      # durable apply
                 except Exception:  # noqa: BLE001 — retry, as inline did
@@ -525,19 +529,16 @@ class ExecutionLane:
         """Execute `slots` into the open speculative accumulation
         (opened here on the first batch). Nothing becomes durable; a
         failure aborts the whole speculation and requeues its slots."""
-        from tpubft.utils.tracing import get_tracer
         r = self._r
         blockchain = getattr(r.handler, "blockchain", None)
-        if sp.span is None:
-            sp.span = get_tracer().start_span("execute")
-            sp.span.set_tag("r", r.id).set_tag("first", sp.first) \
-                .set_tag("spec", True)
         self._run_seen = sp.seen          # one logical run across extends
         try:
             if not sp.acc:
                 blockchain.begin_accumulation(speculative=True)
                 sp.acc = True
             for seq, pp in slots:
+                flight.record(flight.EV_EXEC_START, seq=seq,
+                              arg=len(slots))
                 self._execute_slot(seq, pp, sp.pages_wb, sp.result,
                                    sp.executed_now)
                 sp.result.last = seq
@@ -566,9 +567,6 @@ class ExecutionLane:
                 blockchain.abort_accumulation()
             except Exception:  # noqa: BLE001 — already failing
                 log.exception("abort_accumulation after staging failure")
-        if sp.span is not None:
-            sp.span.set_tag("error", True)
-            sp.span.finish()
         with self._cond:
             if self._spec is sp:
                 self._spec = None
@@ -590,9 +588,6 @@ class ExecutionLane:
                 blockchain.abort_accumulation()
             except Exception:  # noqa: BLE001 — abort must not wedge stop
                 log.exception("spec abort_accumulation failed")
-        if sp.span is not None:
-            sp.span.set_tag("aborted", cause)
-            sp.span.finish()
         log.info("speculative run [%d..%d] aborted (%s): overlay "
                  "discarded, slots re-execute post-commit",
                  sp.first, sp.last, cause)
@@ -607,11 +602,9 @@ class ExecutionLane:
         normal run's apply tail — replies and watermark advancement
         stay strictly post-commit."""
         overlap_ms = max(0.0, (sp.t_confirmed - sp.t_open) * 1e3)
-        if sp.span is not None:
-            sp.span.set_tag("run_len", sp.last - sp.first + 1)
         blockchain = getattr(self._r.handler, "blockchain", None)
         self._apply_run(sp.last - sp.first + 1, sp.result, sp.pages_wb,
-                        sp.executed_now, blockchain, sp.acc, sp.span,
+                        sp.executed_now, blockchain, sp.acc,
                         spec_overlap_ms=overlap_ms, ckpt_pre=sp.ckpt_pre)
 
     # ------------------------------------------------------------------
@@ -619,7 +612,6 @@ class ExecutionLane:
     # ------------------------------------------------------------------
     def _execute_run(self, run: List[Tuple[int, object]]) -> None:
         r = self._r
-        from tpubft.utils.tracing import get_tracer
         blockchain = getattr(r.handler, "blockchain", None)
         can_accumulate = (blockchain is not None
                           and hasattr(blockchain, "begin_accumulation"))
@@ -633,29 +625,25 @@ class ExecutionLane:
         # request into two of the run's slots).
         executed_now: List[Tuple[int, int, object]] = []
         self._run_seen = set()
-        span = get_tracer().start_span("execute")
-        span.set_tag("r", r.id).set_tag("first", result.first) \
-            .set_tag("run_len", len(run))
         acc = False
         if can_accumulate:
             blockchain.begin_accumulation()
             acc = True
         try:
             for seq, pp in run:
+                flight.record(flight.EV_EXEC_START, seq=seq, arg=len(run))
                 self._execute_slot(seq, pp, pages_wb, result,
                                    executed_now)
         except BaseException:
             if acc:
                 blockchain.abort_accumulation()
-            span.set_tag("error", True)
-            span.finish()
             raise
         self._apply_run(len(run), result, pages_wb, executed_now,
-                        blockchain, acc, span)
+                        blockchain, acc)
 
     def _apply_run(self, run_len: int, result: CompletedRun,
                    pages_wb: WriteBatch, executed_now, blockchain,
-                   acc: bool, span,
+                   acc: bool,
                    spec_overlap_ms: Optional[float] = None,
                    ckpt_pre: Optional[Tuple[int, bytes,
                                             Optional[int]]] = None) -> None:
@@ -768,8 +756,6 @@ class ExecutionLane:
                     # strictly worse (duplicate blocks)
                     log.exception("checkpoint snapshot failed at %d",
                                   result.last)
-            span.set_tag("commit_ms", round(commit_ms, 3))
-            span.finish()
             r.record_exec_run(run_len, commit_ms)
             if spec_overlap_ms is not None:
                 r.record_spec_seal(run_len, spec_overlap_ms)

@@ -1490,6 +1490,9 @@ class Replica(IReceiver):
                                                req.req_seq_num):
             return
         self.clients.add_pending(req.sender_id, req.req_seq_num, req.cid)
+        # order_wait's start: stamped once, where the request joins the
+        # queue behind the concurrency_level / work-window gate
+        req.t_pending_ns = time.monotonic_ns()
         self.pending_requests.append(req)
         self._try_send_pre_prepare()
 
@@ -1523,6 +1526,11 @@ class Replica(IReceiver):
             return                              # wedged (ControlStateManager)
         batch = self.pending_requests[:self.cfg.max_num_of_requests_in_batch]
         self.pending_requests = self.pending_requests[len(batch):]
+        # the queue is FIFO: the batch's first request waited longest
+        # (a wedge-fill batch is empty and waited for nothing)
+        flight.record(flight.EV_PP_CREATE, seq=seq, view=self.view,
+                      arg=(time.monotonic_ns() - batch[0].t_pending_ns)
+                      // 1000 if batch else 0)
         raw_reqs = [r.pack() for r in batch]
         pp = m.PrePrepareMsg(
             sender_id=self.id, view=self.view, seq_num=seq,
@@ -1707,7 +1715,8 @@ class Replica(IReceiver):
         self._accept_pre_prepare(pp)
 
     def _accept_pre_prepare(self, pp: m.PrePrepareMsg) -> None:
-        flight.record(flight.EV_PP_ACCEPT, seq=pp.seq_num, view=pp.view)
+        flight.record(flight.EV_PP_ACCEPT, seq=pp.seq_num, view=pp.view,
+                      arg=len(pp.requests))
         info = self.window.get(pp.seq_num)
         info.pre_prepare = pp
         info.commit_path = pp.first_path
@@ -2518,6 +2527,7 @@ class Replica(IReceiver):
         """Inline per-slot execution + apply (the pre-lane path, kept for
         execution_lane=off, restore replay, and lane barrier batches —
         INTERNAL/RECONFIG requests mutate dispatcher-owned subsystems)."""
+        flight.record(flight.EV_EXEC_START, seq=nxt, arg=1)
         for req in info.pre_prepare.client_requests():
             # at-most-once: a request already executed for this client
             # must not re-execute (replay inside a later batch). This

@@ -27,7 +27,7 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 from tpubft.consensus.keys import ClusterKeys
 from tpubft.crypto.interfaces import IVerifier
-from tpubft.ops.dispatch import BreakerOpen, device_breaker
+from tpubft.ops.dispatch import BreakerOpen, device_breaker, device_tier
 from tpubft.utils.logging import get_logger
 from tpubft.utils.metrics import Aggregator, Component
 
@@ -535,8 +535,10 @@ class SigManager:
         # breaker raises BreakerOpen before building any device work
         # (nested ops-level sections are pass-through — one failure is
         # one failure), and a short/garbage verdict vector classifies as
-        # a device failure instead of silently truncating into drops
-        with device_breaker().attempt("sig_verify"):
+        # a device failure instead of silently truncating into drops.
+        # device_tier is that attempt, recorded: the call rows of the
+        # kernels inside read this batch's host prep as `prep_us`
+        with device_tier("sig_verify"):
             verdicts = self._batch_fn(entries)
             if len(verdicts) != len(entries):
                 raise RuntimeError(

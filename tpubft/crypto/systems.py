@@ -20,6 +20,7 @@ reference's SignaturesProcessingJob strategy
 from __future__ import annotations
 
 import struct
+import time
 from typing import Dict, List, Optional, Sequence, Tuple
 
 from tpubft.crypto import bls12381 as bls
@@ -27,6 +28,7 @@ from tpubft.crypto.cpu import Ed25519Signer, Ed25519Verifier
 from tpubft.crypto.interfaces import (Cryptosystem, IThresholdAccumulator,
                                       IThresholdFactory, IThresholdSigner,
                                       IThresholdVerifier)
+from tpubft.utils import flight
 
 
 # ---------------- multisig-ed25519 ----------------
@@ -172,6 +174,7 @@ class BlsThresholdAccumulator(IThresholdAccumulator):
         self._share_verification = share_verification
         self._digest: Optional[bytes] = None
         self._shares: Dict[int, object] = {}
+        self._decompress_ns = 0       # g1_decompress time over add()
 
     def set_expected_digest(self, digest: bytes) -> None:
         self._digest = digest
@@ -179,10 +182,13 @@ class BlsThresholdAccumulator(IThresholdAccumulator):
     def add(self, share_id: int, share: bytes) -> int:
         if not 1 <= share_id <= self._verifier.total_signers:
             return len(self._shares)
+        t0 = time.monotonic_ns()
         try:
             pt = bls.g1_decompress(share)
         except ValueError:
             return len(self._shares)
+        finally:
+            self._decompress_ns += time.monotonic_ns() - t0
         if pt is None:
             return len(self._shares)
         if self._share_verification and self._digest is not None:
@@ -194,7 +200,16 @@ class BlsThresholdAccumulator(IThresholdAccumulator):
     def has_threshold(self) -> bool:
         return len(self._shares) >= self._verifier.threshold
 
+    def _flush_decompress_span(self) -> None:
+        """The shares' decompression, summed over `add`, as ONE flight
+        span at the combine — never a span per share."""
+        if self._decompress_ns:
+            flight.record_span("bls_share_decompress",
+                               self._decompress_ns // 1000)
+            self._decompress_ns = 0
+
     def get_full_signed_data(self) -> bytes:
+        self._flush_decompress_span()
         ids = sorted(self._shares)[: self._verifier.threshold]
         combined = bls.combine_shares(ids, [self._shares[i] for i in ids])
         return bls.g1_compress(combined)
@@ -234,11 +249,13 @@ class BlsThresholdVerifier(IThresholdVerifier):
         return bls.verify(self.share_pk(share_id), data, pt)
 
     def verify(self, data: bytes, sig: bytes) -> bool:
-        try:
-            pt = bls.g1_decompress(sig)
-        except ValueError:
-            return False
-        return bls.verify(self._master_pk, data, pt)
+        # decompress, hash_to_g1 and the pairing check, as one span
+        with flight.span("bls_pairing_verify"):
+            try:
+                pt = bls.g1_decompress(sig)
+            except ValueError:
+                return False
+            return bls.verify(self._master_pk, data, pt)
 
     def verify_batch_certs(self, items) -> List[bool]:
         """Aggregated combined-cert verification: ONE pairing check for

@@ -374,6 +374,7 @@ class TpuBlsThresholdAccumulator(BlsThresholdAccumulator):
         crossover = int(os.environ.get("TPUBFT_MSM_CROSSOVER_K", "128"))
         if len(self._shares) < crossover and k < crossover:
             return super().get_full_signed_data()
+        self._flush_decompress_span()
         try:
             with device_tier("bls_msm"):
                 from tpubft.ops import bls12_381 as dev

@@ -389,7 +389,10 @@ class DurabilityPipeline:
                     self._cond.wait(wait)
                     watchdog.beat(self._name)
             try:
-                self._commit_group(group)
+                # the group's interval on both clocks: apply, fsync,
+                # reply signing and the reply burst
+                with flight.span("dur_group", group[-1].run.last):
+                    self._commit_group(group)
                 if health is not None:
                     health.beat("durability")
             except Exception:  # noqa: BLE001 — the runs are committed

@@ -57,15 +57,25 @@ def device_dispatch():
     return _gate
 
 
+# per-thread seam state: `.tier` is the OUTERMOST open device_tier on
+# this thread (nested tiers pass through, as their breaker attempts
+# do), `.sections` the depth of open sections (the gate is re-entrant)
+_tl = threading.local()
+
+
 class _Section:
     """`with device_section(kind, batch):` — breaker
     admission/classification around the serialized device gate, plus
-    flight-recorder/kernel-profiler annotation (kind, batch size, wall
-    time, breaker state). Raises BreakerOpen without touching the
-    device when tripped."""
+    flight-recorder/kernel-profiler annotation (kind, batch size,
+    breaker state, and the call's three intervals: `prep` inside the
+    enclosing `device_tier`, `gate_wait`, and `device` — the gate held:
+    transfer, launch, read-back, on the host's clock). Where a profile
+    is being taken the gate-held interval is also the host span
+    `tpubft:dev:<kind>` round the launch's device events. Raises
+    BreakerOpen without touching the device when tripped."""
 
     __slots__ = ("_attempt", "_kind", "_batch", "_kid", "_t0", "_rec",
-                 "_shards")
+                 "_shards", "_wait_ns", "_prep_ns", "_tier", "_ann")
 
     def __init__(self, kind: str, batch: int, shards: int = 1) -> None:
         self._attempt = _breaker.attempt(kind)
@@ -88,28 +98,49 @@ class _Section:
         # still holds the gate), so the gate wait lands inside the
         # attempt's clock — credit it back: queueing behind other
         # healthy threads' batches is contention, not device slowness
-        t = time.monotonic()
+        t_req = time.monotonic_ns()
         _gate.acquire()
-        _breaker.exclude_wait(time.monotonic() - t)
+        t_got = time.monotonic_ns()
+        _breaker.exclude_wait((t_got - t_req) / 1e9)
         if self._rec:
+            self._wait_ns = t_got - t_req
+            depth = getattr(_tl, "sections", 0)
+            _tl.sections = depth + 1
+            # a section nested under the re-entrant gate runs inside
+            # its parent's `device` interval: it leaves the tier's prep
+            # accounting alone
+            tier = self._tier = None if depth else getattr(_tl, "tier",
+                                                           None)
+            self._prep_ns = (t_req - tier.cursor) if tier is not None \
+                else 0
             flight.record(flight.EV_DEV_ENTER, view=self._kid,
                           arg=self._batch)
+            self._ann = flight.annotation("dev:" + self._kind)
+            if self._ann is not None:
+                self._ann.__enter__()
             self._t0 = time.monotonic_ns()
         return self
 
     def __exit__(self, *exc) -> bool:
-        elapsed_ns = (time.monotonic_ns() - self._t0) if self._rec else 0
+        t_rel = time.monotonic_ns() if self._rec else 0
+        if self._rec and self._ann is not None:
+            self._ann.__exit__(*exc)
         _gate.release()
         suppressed = bool(self._attempt.__exit__(*exc))
         if self._rec:
+            elapsed_ns = t_rel - self._t0
+            _tl.sections -= 1
             # profile AFTER the breaker's verdict so the recorded state
             # is the post-call one (a call that just tripped the
             # breaker shows up as such in the kernel profile)
             flight.record(flight.EV_DEV_EXIT, view=self._kid,
                           arg=int(elapsed_ns // 1000))
             prof = flight.kernel_profiler()
-            prof.record(self._kind, self._batch, elapsed_ns,
-                        _breaker.state)
+            row = prof.record(self._kind, self._batch, elapsed_ns,
+                              _breaker.state, self._wait_ns,
+                              self._prep_ns, self._t0)
+            if self._tier is not None:
+                self._tier.cursor, self._tier.last = t_rel, row
             if self._shards > 1:
                 # per-shard view of the same launch: the shards run in
                 # lockstep, so wall time is shared and the per-shard
@@ -119,7 +150,7 @@ class _Section:
                 # against the unsharded kind
                 prof.record(f"{self._kind}.shard",
                             max(1, -(-self._batch // self._shards)),
-                            elapsed_ns, _breaker.state)
+                            elapsed_ns, _breaker.state, row=False)
         return suppressed
 
 
@@ -131,7 +162,44 @@ def device_section(kind: str, batch: int = 0, shards: int = 1) -> _Section:
     return _Section(kind, batch, shards)
 
 
-def device_tier(kind: str):
+class _Tier:
+    """`with device_tier(kind):` — the breaker attempt round a whole
+    device tier, recorded: the outermost tier on a thread is the
+    interval its sections' `prep` is measured in (tier enter -> gate
+    requested, gate released -> the next request or the tier's exit),
+    and the host span `tpubft:tier:<kind>` in a profile."""
+
+    __slots__ = ("_attempt", "_kind", "_ann", "_mine", "cursor", "last")
+
+    def __init__(self, kind: str) -> None:
+        self._attempt = _breaker.attempt(kind)
+        self._kind = kind
+        self._mine = False
+        self.last = None
+
+    def __enter__(self) -> "_Tier":
+        self._attempt.__enter__()       # BreakerOpen: nothing opened yet
+        if flight.enabled() and getattr(_tl, "tier", None) is None:
+            self._mine = True
+            _tl.tier = self
+            self._ann = flight.annotation("tier:" + self._kind)
+            if self._ann is not None:
+                self._ann.__enter__()
+            self.cursor = time.monotonic_ns()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        if self._mine:
+            _tl.tier = None
+            if self.last is not None:
+                flight.kernel_profiler().add_prep(
+                    self.last, time.monotonic_ns() - self.cursor)
+            if self._ann is not None:
+                self._ann.__exit__(*exc)
+        return bool(self._attempt.__exit__(*exc))
+
+
+def device_tier(kind: str) -> _Tier:
     """One breaker attempt around a WHOLE device tier — for callers
     that answer on the host when the tier raises (crypto/tpu.py,
     sparse_merkle, state transfer). Such an answer is only safe to read
@@ -141,7 +209,7 @@ def device_tier(kind: str):
     outermost attempt owns the verdict (nested seams pass through), so
     a tier that falls to the host for ANY reason is one recorded
     failure, and an OPEN breaker is one recorded fast-fail."""
-    return _breaker.attempt(kind)
+    return _Tier(kind)
 
 
 # ---------------------------------------------------------------------

@@ -19,11 +19,13 @@ bounded by slot rate, which is orders of magnitude below message rate.
 Three consumers fold the rings:
 
   * ``SlotTracker`` — folds slot-stage events into per-seq timings
-    (adm_wait / dispatch / prepare / commit / exec / reply), feeding
+    (adm_wait / dispatch / prepare / commit / exec / reply, plus the
+    order_wait / exec_wait / exec_run / dur_wait sub-stages), feeding
     the diagnostics histograms (``slot.<stage>``) and
     ``status get slots``;
   * ``KernelProfiler`` — per-kernel call count, batch-size stats, wall
-    time and the first-call compile-warmup split, recorded by
+    time and the first-call compile-warmup split, plus one bounded row
+    per call (prep / gate wait / device), recorded by
     ``ops.dispatch.device_section`` and served as
     ``status get kernels``;
   * the dump plane — ``status get flight`` on demand, plus
@@ -32,6 +34,14 @@ Three consumers fold the rings:
     stalled/degraded health transition (consensus/health.py) and on
     chaos-campaign red verdicts (testing/campaign.py); offline,
     ``tools/tpuprof.py`` merges per-replica dumps into a slot timeline.
+
+``span(name)`` is the one helper for batch-level host work off the
+dispatcher (a lane run, an admission drain, a combine flush, a
+durability group, the BLS host path): one ``EV_SPAN`` ring event on
+exit and, where ``jax`` is already imported, a
+``jax.profiler.TraceAnnotation("tpubft:<name>")`` for the same interval
+— so the profiler's trace and the rings name one interval on two
+clocks. This module never imports ``jax`` itself.
 
 Knobs (environment — read once at import, like TPUBFT_THREADCHECK):
 
@@ -50,6 +60,7 @@ from __future__ import annotations
 
 import json
 import os
+import sys
 import tempfile
 import threading
 import time
@@ -130,6 +141,16 @@ EV_OFF_REJECTED = 36    # helper result FAILED the soundness check or
 #                         locally (arg=helper ordinal)
 EV_OFF_EVICT = 37       # helper evicted (arg: 0=sick/timeout,
 #                         1=byzantine quarantine — no auto re-admission)
+# request accounting inside the replica (ISSUE 25)
+EV_PP_CREATE = 38       # primary cut a batch into a PrePrepare
+#                         (dispatcher; arg=µs its OLDEST request waited
+#                         in pending_requests — the order_wait stage)
+EV_EXEC_START = 39      # lane began executing a slot: normal run,
+#                         speculative staging or the inline path
+#                         (arg=run length)
+EV_SPAN = 40            # flight.span() closed (view=span-name id,
+#                         arg=µs; the id → name table rides every
+#                         snapshot as `span_names`)
 
 EV_NAMES = {
     EV_ADM_INGEST: "adm_ingest", EV_ADM_DRAIN: "adm_drain",
@@ -152,6 +173,8 @@ EV_NAMES = {
     EV_CERT_ASYNC_LAG: "cert_async_lag",
     EV_OFF_LEASE: "lease_issued", EV_OFF_VERIFIED: "lease_verified",
     EV_OFF_REJECTED: "lease_rejected", EV_OFF_EVICT: "helper_evicted",
+    EV_PP_CREATE: "pp_create", EV_EXEC_START: "exec_start",
+    EV_SPAN: "span",
 }
 
 # events the slot tracker folds inline (everything else is ring-only)
@@ -159,7 +182,8 @@ _SLOT_CODES = frozenset((EV_ADM_ADMIT, EV_PP_DISPATCH, EV_PP_ACCEPT,
                          EV_PREPARED, EV_COMMITTED, EV_EXEC_ENQ,
                          EV_EXEC_APPLY, EV_REPLY, EV_SPEC_ENQ,
                          EV_SPEC_SEAL, EV_SPEC_ABORT,
-                         EV_CERT_ASYNC_LAG))
+                         EV_CERT_ASYNC_LAG, EV_PP_CREATE,
+                         EV_EXEC_START, EV_DUR_GROUP))
 
 # the six PIPELINE stages partition a slot's lifetime (they sum to the
 # slot total); spec_overlap is an OVERLAY — the slice of the commit
@@ -170,10 +194,16 @@ _SLOT_CODES = frozenset((EV_ADM_ADMIT, EV_PP_DISPATCH, EV_PP_ACCEPT,
 # that runs AFTER the client already has its reply (> 0 only under
 # ReplicaConfig.optimistic_replies; fed by EV_CERT_ASYNC_LAG samples,
 # which usually land after the slot finalized on EV_REPLY — so it is
-# tracked as a sample stream, never part of a slot's total)
+# tracked as a sample stream, never part of a slot's total).
+# The last four account for a request INSIDE the stages above and are
+# excluded from the total as well: order_wait precedes the slot (the
+# primary's pending_requests queue, 0 on backups); exec_wait + exec_run
+# split `exec` at the lane's EV_EXEC_START (queue wait vs service);
+# dur_wait is the slice of `reply` spent waiting for the group fsync.
 PIPELINE_STAGES = ("adm_wait", "dispatch", "prepare", "commit", "exec",
                    "reply")
-STAGES = PIPELINE_STAGES + ("spec_overlap", "cert_lag")
+STAGES = PIPELINE_STAGES + ("spec_overlap", "cert_lag", "order_wait",
+                            "exec_wait", "exec_run", "dur_wait")
 
 RING_SIZE = max(64, int(os.environ.get("TPUBFT_FLIGHT_RING", "4096")
                         or 4096))
@@ -276,10 +306,121 @@ def _record_off(code: int, seq: int = 0, view: int = 0,
     return None
 
 
+# ---------------------------------------------------------------------
+# spans: batch-level host work, one ring event per interval
+# ---------------------------------------------------------------------
+_span_ids: Dict[str, int] = {}
+_span_mu = make_lock("flight.spans")
+
+
+def span_id(name: str) -> int:
+    """Interned id of a span name (EV_SPAN carries it in `view`)."""
+    sid = _span_ids.get(name)         # GIL-atomic read of a grow-only dict
+    if sid is None:
+        with _span_mu:
+            sid = _span_ids.setdefault(name, len(_span_ids) + 1)
+    return sid
+
+
+_trace_annotation = None              # jax.profiler.TraceAnnotation, once seen
+
+
+def annotation(name: str):
+    """`jax.profiler.TraceAnnotation("tpubft:" + name)` when `jax` is
+    ALREADY imported (a no-op unless a profile is being taken), else
+    None: a host-only replica never pays the import, and this module
+    never makes it."""
+    global _trace_annotation
+    cls = _trace_annotation
+    if cls is None:
+        jax = sys.modules.get("jax")
+        # mid-import on another thread the attribute may not exist yet
+        cls = getattr(getattr(jax, "profiler", None),
+                      "TraceAnnotation", None)
+        if cls is None:
+            return None
+        _trace_annotation = cls
+    return cls("tpubft:" + name)
+
+
+class _Span:
+    """`with flight.span(name, seq):` — see the module docstring."""
+
+    __slots__ = ("_name", "_seq", "_t0", "_ann")
+
+    def __init__(self, name: str, seq: int = 0) -> None:
+        self._name = name
+        self._seq = seq
+
+    def __enter__(self) -> "_Span":
+        self._ann = annotation(self._name)
+        if self._ann is not None:
+            self._ann.__enter__()
+        self._t0 = time.monotonic_ns()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        us = (time.monotonic_ns() - self._t0) // 1000
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        record(EV_SPAN, self._seq, span_id(self._name), us)
+
+
+class _NullSpan:
+    __slots__ = ()
+
+    def __enter__(self) -> "_NullSpan":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        return None
+
+
+_NULL_SPAN = _NullSpan()
+
+
+def _span_off(name: str, seq: int = 0) -> _NullSpan:
+    return _NULL_SPAN
+
+
+def record_span(name: str, us: int, seq: int = 0) -> None:
+    """One EV_SPAN for time SUMMED by the caller over many small pieces
+    (share decompression inside an accumulator's `add` calls) — never a
+    span per piece. No profiler half: there is no one interval."""
+    record(EV_SPAN, seq, span_id(name), int(us))
+
+
+def span_events(name: str, since_ns: int = 0
+                ) -> Optional[List[Tuple[int, int, int]]]:
+    """`(t_end_ns, seq, us)` of every retained span `name` that closed
+    at or after `since_ns`, oldest first. None where a ring that holds
+    such spans has wrapped past `since_ns`: the window's spans can no
+    longer be told complete, and a reader must say so, not average the
+    survivors."""
+    sid = _span_ids.get(name)
+    if sid is None:
+        return []
+    with _rings_mu:
+        rings = list(_rings)
+    out: List[Tuple[int, int, int]] = []
+    for r in rings:
+        evs = r.events()
+        mine = [(t, seq, arg) for t, code, seq, view, arg in evs
+                if code == EV_SPAN and view == sid]
+        if not mine:
+            continue
+        if len(evs) == RING_SIZE and evs[0][0] > since_ns:
+            return None
+        out.extend(e for e in mine if e[0] >= since_ns)
+    return sorted(out)
+
+
 ENABLED = os.environ.get("TPUBFT_FLIGHT", "1") not in ("", "0")
 # the ONE hot-path entry point: callers use `flight.record(...)` (a
-# module-attribute lookup) so enable/disable swaps take effect
+# module-attribute lookup) so enable/disable swaps take effect; `span`
+# swaps with it
 record = _record if ENABLED else _record_off
+span = _Span if ENABLED else _span_off
 
 
 def enabled() -> bool:
@@ -289,8 +430,9 @@ def enabled() -> bool:
 def _set_enabled(on: bool) -> None:
     """Test hook (the production compile-out is TPUBFT_FLIGHT=0 at
     process start)."""
-    global record
+    global record, span
     record = _record if on else _record_off
+    span = _Span if on else _span_off
 
 
 def configure(dump_dir: Optional[str] = None) -> None:
@@ -316,6 +458,22 @@ class SlotTracker:
         exec      commit -> durable apply (lane thread)
         reply     durable apply -> slot integrated + replies sent
 
+    Sub-stages, excluded from the slot total (they account for time
+    INSIDE the stages above, or before the slot existed):
+
+        order_wait  the batch's OLDEST request joined the primary's
+                  pending_requests -> the PrePrepare was cut (the
+                  concurrency_level / work-window gate; EV_PP_CREATE's
+                  arg, so 0 on every backup's row)
+        exec_wait   commit -> the lane began the slot (EV_EXEC_START):
+                  queueing behind earlier runs; 0 for a slot whose
+                  speculation ran ahead of its commit
+        exec_run    max(lane start, commit) -> durable apply: the lane's
+                  own work; exec_wait + exec_run == exec, always
+        dur_wait    durable apply -> the durability group that covers
+                  the slot committed (EV_DUR_GROUP, io thread); a slice
+                  of `reply`, 0 without the pipeline
+
     Plus one OVERLAY stage that runs concurrently with ``commit`` and
     is excluded from the slot total:
 
@@ -331,7 +489,8 @@ class SlotTracker:
     deque of recent completed slots behind ``status get slots``."""
 
     MAX_LIVE = 4096
-    KEEP = 512
+    KEEP = 4096       # a row is one small dict; the cell benchmark reads
+    #                   a whole window's slots through recent(limit=KEEP)
 
     def __init__(self) -> None:
         self._mu = make_lock("flight.slots")
@@ -371,7 +530,40 @@ class SlotTracker:
               EV_PP_ACCEPT: "accept", EV_PREPARED: "prepared",
               EV_COMMITTED: "committed", EV_EXEC_ENQ: "enqueued",
               EV_EXEC_APPLY: "applied", EV_REPLY: "replied",
-              EV_SPEC_ENQ: "spec_enq", EV_SPEC_SEAL: "spec_seal"}
+              EV_SPEC_ENQ: "spec_enq", EV_SPEC_SEAL: "spec_seal",
+              EV_PP_CREATE: "created", EV_EXEC_START: "started"}
+
+    @classmethod
+    def stamp(cls, slot: Dict, code: int, arg: int, t_ns: int) -> None:
+        """Fold one per-slot event into a slot's raw record — shared
+        with tools/tpuprof.py, which replays dumped rings through it.
+        First sighting wins (a retransmitted PrePrepare or a retried
+        run must not move an anchor)."""
+        if code == EV_SPEC_ABORT:
+            # the speculation was discarded: this slot re-executes
+            # from its committed body, so no combine window was
+            # reclaimed — spec_overlap must fold to 0, and the lane's
+            # start is the re-execution's, not the discarded staging's
+            for field in ("spec_enq", "spec_seal", "started"):
+                slot.pop(field, None)
+            return
+        slot.setdefault(cls._FIELD[code], t_ns)
+        if code == EV_COMMITTED:
+            slot.setdefault("path", "fast" if arg else "slow")
+        elif code == EV_PP_CREATE:
+            slot.setdefault("order_wait_us", arg)
+        elif code == EV_PP_ACCEPT:
+            slot.setdefault("reqs", arg)
+
+    @staticmethod
+    def stamp_durable(slots, rid: int, watermark: int, t_ns: int) -> None:
+        """EV_DUR_GROUP: every applied slot of `rid` at or under the
+        group's watermark became durable at `t_ns` (shared with
+        tools/tpuprof.py; `slots` iterates raw slot records)."""
+        for slot in slots:
+            if slot["rid"] == rid and slot["seq"] <= watermark \
+                    and "applied" in slot:
+                slot.setdefault("durable", t_ns)
 
     def on_event(self, rid: int, code: int, seq: int, view: int,
                  arg: int, t_ns: int) -> None:
@@ -382,6 +574,11 @@ class SlotTracker:
             with self._mu:
                 self._cert_lag.append((rid, lag_ms))
             self._hist("cert_lag").record(arg)      # histograms in µs
+            return
+        if code == EV_DUR_GROUP:
+            # seq carries the group's watermark, not one slot
+            with self._mu:
+                self.stamp_durable(self._live.values(), rid, seq, t_ns)
             return
         key = (rid, seq)
         with self._mu:
@@ -397,17 +594,7 @@ class SlotTracker:
                     self._live.pop(next(iter(self._live)))
                 slot = self._live[key] = {"rid": rid, "seq": seq,
                                           "view": view}
-            if code == EV_SPEC_ABORT:
-                # the speculation was discarded: this slot re-executes
-                # from its committed body, so no combine window was
-                # reclaimed — spec_overlap must fold to 0
-                slot.pop("spec_enq", None)
-                slot.pop("spec_seal", None)
-                return
-            field = self._FIELD[code]
-            slot.setdefault(field, t_ns)
-            if code == EV_COMMITTED:
-                slot.setdefault("path", "fast" if arg else "slow")
+            self.stamp(slot, code, arg, t_ns)
             if code != EV_REPLY:
                 return
             del self._live[key]
@@ -427,14 +614,22 @@ class SlotTracker:
             return (b - a) / 1e6
         accept = slot.get("accept")
         prepared = slot.get("prepared")
+        committed, applied = slot.get("committed"), slot.get("applied")
+        exec_ms = ms(committed, applied)
+        # the split is clamped into `exec` so the two parts always sum
+        # to it: a slot with no lane start on record (an event lost to a
+        # reset) reads as all service, one that started before its
+        # commit (speculation ran ahead) as no wait
+        exec_wait = min(ms(committed, slot.get("started")), exec_ms)
+        reply_ms = ms(applied, slot.get("replied"))
         return {
             "adm_wait": ms(slot.get("admit"), slot.get("handler")),
             "dispatch": ms(slot.get("handler"), accept),
             "prepare": ms(accept, prepared),
             "commit": ms(prepared if prepared is not None else accept,
                          slot.get("committed")),
-            "exec": ms(slot.get("committed"), slot.get("applied")),
-            "reply": ms(slot.get("applied"), slot.get("replied")),
+            "exec": exec_ms,
+            "reply": reply_ms,
             # combine-window slice reclaimed by speculation: counted
             # only when the speculative run actually SEALED (an aborted
             # or commit-first speculation reclaimed nothing)
@@ -447,6 +642,10 @@ class SlotTracker:
             # EV_CERT_ASYNC_LAG sample stream (see summary()), never
             # from a slot's own timestamps
             "cert_lag": 0.0,
+            "order_wait": slot.get("order_wait_us", 0) / 1e3,
+            "exec_wait": exec_wait,
+            "exec_run": exec_ms - exec_wait,
+            "dur_wait": min(ms(applied, slot.get("durable")), reply_ms),
         }
 
     def _finalize(self, slot: Dict) -> None:
@@ -455,6 +654,8 @@ class SlotTracker:
                "view": slot.get("view", 0),
                "path": slot.get("path", "?"),
                "spec": slot.get("spec_seal") is not None,
+               "reqs": slot.get("reqs", 0),
+               "primary": "created" in slot,
                "total_ms": round(sum(stages[s]
                                      for s in PIPELINE_STAGES), 3),
                "stages_ms": {k: round(v, 3) for k, v in stages.items()}}
@@ -530,12 +731,26 @@ def stage_summary(rid: Optional[int] = None) -> Dict:
 class KernelProfiler:
     """Per-kernel-kind device profile. The first call is split out —
     it pays the XLA compile, and folding it into the mean makes every
-    warm-path number a lie."""
+    warm-path number a lie.
+
+    Beside the totals it keeps one bounded row per call:
+    `{kind, ordinal, batch, t_enter_ns, prep_us, gate_wait_us,
+    device_us}`. `ordinal` is the call's number within its kind since
+    process start (== `calls` in `snapshot()` once the call is booked),
+    so a reader that snapshots `calls` at both ends of a window cuts
+    exactly that window's rows, with no clock. The three intervals are
+    the device seam's (ops/dispatch.py): `prep` host work inside the
+    enclosing `device_tier` but outside the gate, `gate_wait` queueing
+    for the gate, `device` the gate held — transfer, launch and
+    read-back on the HOST's clock."""
+
+    CALL_ROWS = 4096
 
     def __init__(self) -> None:
         self._mu = make_lock("flight.kernels")
         self._stats: Dict[str, Dict] = {}
         self._kind_ids: Dict[str, int] = {}
+        self._rows: "deque[Dict]" = deque(maxlen=self.CALL_ROWS)
 
     def kind_id(self, kind: str) -> int:
         with self._mu:
@@ -545,7 +760,11 @@ class KernelProfiler:
             return kid
 
     def record(self, kind: str, batch: int, elapsed_ns: int,
-               breaker_state: str) -> None:
+               breaker_state: str, gate_wait_ns: int = 0,
+               prep_ns: int = 0, t_enter_ns: int = 0,
+               row: bool = True) -> Optional[Dict]:
+        """Book one call; returns its call row (None with `row=False`:
+        the `<kind>.shard` view of a launch that already has one)."""
         us = elapsed_ns / 1e3
         with self._mu:
             st = self._stats.get(kind)
@@ -554,7 +773,8 @@ class KernelProfiler:
                     "calls": 0, "first_call_us": us, "total_us": 0.0,
                     "warm_us": 0.0, "max_us": 0.0,
                     "batch_sum": 0, "batch_max": 0,
-                    "batch_min": batch, "breaker": {}}
+                    "batch_min": batch, "breaker": {},
+                    "gate_wait_us": 0.0, "prep_us": 0.0}
             st["calls"] += 1
             st["total_us"] += us
             if st["calls"] > 1:
@@ -565,6 +785,30 @@ class KernelProfiler:
             st["batch_min"] = min(st["batch_min"], batch)
             st["breaker"][breaker_state] = \
                 st["breaker"].get(breaker_state, 0) + 1
+            st["gate_wait_us"] += gate_wait_ns / 1e3
+            st["prep_us"] += prep_ns / 1e3
+            if not row:
+                return None
+            rec = {"kind": kind, "ordinal": st["calls"], "batch": batch,
+                   "t_enter_ns": t_enter_ns, "prep_us": prep_ns / 1e3,
+                   "gate_wait_us": gate_wait_ns / 1e3, "device_us": us}
+            self._rows.append(rec)
+            return rec
+
+    def add_prep(self, rec: Dict, prep_ns: int) -> None:
+        """The tier's tail — gate released -> tier exit — lands after
+        the call was booked: credit it to the call's row and totals."""
+        with self._mu:
+            rec["prep_us"] += prep_ns / 1e3
+            st = self._stats.get(rec["kind"])
+            if st is not None:
+                st["prep_us"] += prep_ns / 1e3
+
+    def call_rows(self, kind: Optional[str] = None) -> List[Dict]:
+        """Copies of the retained call rows, oldest first."""
+        with self._mu:
+            return [dict(r) for r in self._rows
+                    if kind is None or r["kind"] == kind]
 
     def snapshot(self) -> Dict:
         with self._mu:
@@ -583,6 +827,8 @@ class KernelProfiler:
                     "batch_min": st["batch_min"],
                     "batch_max": st["batch_max"],
                     "breaker_states": dict(st["breaker"]),
+                    "gate_wait_ms": round(st["gate_wait_us"] / 1e3, 3),
+                    "prep_ms": round(st["prep_us"] / 1e3, 3),
                 }
             return out
 
@@ -593,6 +839,7 @@ class KernelProfiler:
     def reset(self) -> None:
         with self._mu:
             self._stats.clear()
+            self._rows.clear()
 
 
 _profiler = KernelProfiler()
@@ -669,8 +916,11 @@ def snapshot(max_events_per_ring: Optional[int] = None) -> Dict:
         "event_names": {str(k): v for k, v in EV_NAMES.items()},
         "kernel_kinds": {str(k): v for k, v in
                          _profiler.kind_table().items()},
+        "span_names": {str(v): k for k, v in list(_span_ids.items())},
         "rings": ring_dumps,
         "kernels": _profiler.snapshot(),
+        "kernel_calls": _profiler.call_rows()[
+            -(max_events_per_ring or KernelProfiler.CALL_ROWS):],
         "slots": {"summary": _tracker.summary(),
                   "recent": _tracker.recent(limit=SlotTracker.KEEP)},
         "lock_hold_s": hold_stats(),

@@ -6,15 +6,18 @@ messages via MessageBase::spanContext<T>(), child spans per protocol
 stage — ReplicaImp.cpp:409-413,1070): spans carry (trace_id, span_id,
 parent) plus timing; contexts serialize to a compact string that rides
 the ClientRequestMsg `cid` field, so one client request is joinable
-across every replica's logs and span exports. The exporter is pluggable
-(in-memory ring for tests, log line by default — Jaeger's role).
+across every replica's logs and span exports. Finished spans land in a
+bounded in-memory ring (tests, the diagnostics server and the flight
+dump read it). Batch-level host work — lane runs, admission drains —
+is NOT here: `flight.span` (utils/flight.py) is the span source for
+that, on the profiler's clock too.
 """
 from __future__ import annotations
 
 import os
 import time
 from dataclasses import dataclass, field
-from typing import Callable, Dict, List, Optional
+from typing import Dict, List, Optional
 
 from tpubft.utils.racecheck import make_lock
 
@@ -72,15 +75,14 @@ class Span:
 
 
 class Tracer:
-    """Process tracer with a bounded in-memory export ring (exporters can
-    be attached; the ring is what tests and the diagnostics server read)."""
+    """Process tracer with a bounded in-memory ring of finished spans
+    (what tests, the diagnostics server and the flight dump read)."""
 
     RING = 2048
 
     def __init__(self) -> None:
         self._lock = make_lock("tracer")
         self._ring: List[Span] = []
-        self._exporters: List[Callable[[Span], None]] = []
         self._counter = 0
 
     def _next_id(self) -> str:
@@ -102,24 +104,11 @@ class Tracer:
             span.set_tag(k, v)
         return span
 
-    def add_exporter(self, fn: Callable[[Span], None]) -> None:
-        with self._lock:
-            self._exporters.append(fn)
-
     def _export(self, span: Span) -> None:
-        # exporters snapshotted under the same lock that add_exporter
-        # appends under: a concurrent registration must never race the
-        # list while a finishing span iterates it
         with self._lock:
             self._ring.append(span)
             if len(self._ring) > self.RING:
                 del self._ring[:len(self._ring) - self.RING]
-            exporters = list(self._exporters)
-        for fn in exporters:
-            try:
-                fn(span)
-            except Exception:  # noqa: BLE001 — exporters must not crash
-                pass
 
     def finished_spans(self, trace_id: Optional[str] = None) -> List[Span]:
         with self._lock:
