@@ -129,6 +129,50 @@ def test_native_compaction(tmp_path):
     db.close()
 
 
+@pytest.mark.parametrize("growth,want", [
+    # an append-only store outgrows the floor for good and holds no
+    # garbage: rewriting it would reclaim nothing, on every write
+    ("append_only", (0, 0)),
+    # one hot key: the live set stays small, the floor alone decides
+    ("overwrites", (8, 16)),
+    # half of every write is garbage: the log is rewritten each time
+    # it has doubled, not on every write past the floor
+    ("half_and_half", (2, 6)),
+])
+def test_native_auto_compaction_waits_for_garbage(tmp_path, monkeypatch,
+                                                  growth, want):
+    path = str(tmp_path / "db.kvlog")
+    db = NativeDB(path, sync_writes=False, compact_bytes=4096)
+    compactions = []
+    real = db.compact
+
+    def counted():
+        compactions.append(1)
+        real()
+    monkeypatch.setattr(db, "compact", counted)
+    model = {}
+    for i in range(400):            # ~48 KiB appended over a 4 KiB floor
+        key = {"append_only": b"key-%04d" % i, "overwrites": b"hot",
+               "half_and_half": b"key-%04d" % (i // 2)}[growth]
+        model[key] = b"v" * 100 + b"%04d" % i
+        db.put(key, model[key])
+    assert want[0] <= len(compactions) <= want[1], len(compactions)
+    # the engine's count of live bytes is what a compaction writes,
+    # and a reopened log counts the same
+    live = db._lib.kvlog_live_bytes(db._h)
+    db.compact()
+    assert os.path.getsize(path) == 12 + live
+    db.delete(sorted(model)[0])
+    del model[sorted(model)[0]]
+    live = db._lib.kvlog_live_bytes(db._h)
+    db.close()
+    db = NativeDB(path)
+    assert db._lib.kvlog_live_bytes(db._h) == live
+    assert {k: db.get(k) for k in model} == model
+    assert db.count() == len(model)
+    db.close()
+
+
 def test_metadata_storage_transactions(tmp_path):
     db = NativeDB(str(tmp_path / "meta.kvlog"))
     ms = MetadataStorage(db)

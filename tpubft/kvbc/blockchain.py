@@ -74,6 +74,10 @@ class _MirroredBatch(WriteBatch):
         self._overlay[fkey(family, key)] = None
         return super().delete(key, family)
 
+    def extend(self, ops) -> "WriteBatch":
+        self._overlay.update(ops)
+        return super().extend(ops)
+
 
 class _StagedReadView(IDBClient):
     """Read view over (overlay, base db) used while linking several

@@ -91,6 +91,10 @@ class KvbcReplica:
                                storage=DBPersistentStorage(self.db),
                                aggregator=aggregator,
                                reserved_pages=pages)
+        # the merkle walk's read totals are process-wide (`kvbc`
+        # component): served with this replica's own components
+        from tpubft.kvbc import sparse_merkle
+        self.replica.aggregator.register(sparse_merkle.METRICS)
         from tpubft.statetransfer import StateTransferManager
         from tpubft.statetransfer.manager import StConfig
         self.state_transfer = StateTransferManager(

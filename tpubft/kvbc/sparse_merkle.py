@@ -22,6 +22,14 @@ at or below that version (absence = default subtree — any older change
 would have been archived). `prune_versions(before)` is the stale-node
 GC: it drops archive rows superseded before the retention point, exactly
 the role of the reference's stale-node index.
+
+Reads: a node that hashes to its depth's default is deleted, never
+stored, so where a changed leaf's path has no stored node the subtree
+under it is empty and `update_batch` takes every sibling below that
+depth as the default without asking the engine. This is no cache: the
+depth is found anew on every call through the tree's own read view (so
+a run's staged rows, the pending store and a speculative overlay are
+seen as ever), and nothing is remembered between calls.
 """
 from __future__ import annotations
 
@@ -29,7 +37,8 @@ import hashlib
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Sequence, Tuple
 
-from tpubft.storage.interfaces import IDBClient, WriteBatch
+from tpubft.storage.interfaces import IDBClient, WriteBatch, fkey
+from tpubft.utils.metrics import Component
 
 DEPTH = 256
 _EMPTY = b"\x00" * 32
@@ -44,6 +53,16 @@ for _d in range(DEPTH - 1, -1, -1):
 
 # below this many nodes in a level, hashlib beats device dispatch
 _DEVICE_THRESHOLD = 192
+
+# what update_batch asked of the engine, process-wide (every tree of every
+# ledger in the process): leaves changed, node reads issued (the probes
+# for the empty depth and the sibling reads), and sibling lookups the
+# empty depth answered with no read. Totals only — nothing here is read
+# back by the tree.
+METRICS = Component("kvbc")
+_M_KEYS = METRICS.register_counter("smt_keys_updated")
+_M_ENGINE_READS = METRICS.register_counter("smt_engine_reads")
+_M_BOUNDED = METRICS.register_counter("smt_siblings_bounded")
 
 
 def _hash_level(messages: Sequence[bytes], use_device: bool) -> List[bytes]:
@@ -90,6 +109,12 @@ class SparseMerkleTree:
         self._arch_family = family + b".arch"        # node_key+ver8 -> hash
         self._leaf_arch_family = family + b".leafarch"  # path+ver8 -> vh
         self._use_device = use_device
+        # physical-key prefixes of the four families: update_batch
+        # composes its rows' keys itself and stages them in one call
+        self._pre, self._leaf_pre, self._arch_pre, self._leaf_arch_pre = (
+            fkey(f, b"") for f in (self._family, self._leaf_family,
+                                   self._arch_family,
+                                   self._leaf_arch_family))
 
     # ---- reads ----
     # Reads go straight to the DB (no node cache): staged-but-uncommitted
@@ -121,41 +146,87 @@ class SparseMerkleTree:
         wb = WriteBatch() if own_batch else batch
         ver = version.to_bytes(8, "big") if version > 0 else None
 
-        # leaf level
+        # leaf level. Each leaf's empty depth is taken here, before any
+        # node row is staged: `wb` may mirror into the read view, and
+        # the walk stages a row for every node of the path
         changed: Dict[int, bytes] = {}
+        bound: Dict[int, int] = {}
+        reads = bounded = 0
+        # every row of the walk, in the order the engine gets them
+        rows: List[Tuple[bytes, Optional[bytes]]] = []
         for key, vh in updates.items():
             path = hashlib.sha256(key).digest()
             bits = int.from_bytes(path, "big")
-            if vh is None:
-                changed[bits] = _EMPTY
-                wb.delete(path, self._leaf_family)
-            else:
-                changed[bits] = _leaf_hash(path, vh)
-                wb.put(path, vh, self._leaf_family)
+            bound[bits], probes = self._empty_depth(bits)
+            reads += probes
+            changed[bits] = _EMPTY if vh is None else _leaf_hash(path, vh)
+            rows.append((self._leaf_pre + path, vh))
             if ver is not None:
-                wb.put(path + ver, vh if vh is not None else b"",
-                       self._leaf_arch_family)
-        self._stage_level(wb, DEPTH, changed, ver)
+                rows.append((self._leaf_arch_pre + path + ver,
+                             vh if vh is not None else b""))
+        self._level_rows(rows, DEPTH, changed, ver)
 
-        # ascend, rehashing all changed nodes of each level in one batch
+        # ascend, rehashing all changed nodes of each level in one batch.
+        # A sibling that is not changed hangs from a changed node's path:
+        # below that path's empty depth it is the default, unread
         for depth in range(DEPTH, 0, -1):
             parents = sorted({bits >> 1 for bits in changed})
             msgs = []
+            up: Dict[int, int] = {}
             for pb in parents:
-                left = changed.get(pb << 1)
-                if left is None:
-                    left = self._node(depth, pb << 1)
-                right = changed.get((pb << 1) | 1)
-                if right is None:
-                    right = self._node(depth, (pb << 1) | 1)
+                lb, rb = pb << 1, (pb << 1) | 1
+                left, right = changed.get(lb), changed.get(rb)
+                if left is not None and right is not None:
+                    # two changed children share their path from the
+                    # parent up, so their bounds agree wherever either
+                    # still decides anything
+                    empty = min(bound[lb], bound[rb])
+                else:
+                    empty = bound[rb if left is None else lb]
+                    if depth > empty:
+                        sibling = _DEFAULTS[depth]
+                        bounded += 1
+                    else:
+                        sibling = self._node(depth,
+                                             lb if left is None else rb)
+                        reads += 1
+                    if left is None:
+                        left = sibling
+                    else:
+                        right = sibling
+                up[pb] = empty
                 msgs.append(b"\x01" + left + right)
             hashes = _hash_level(msgs, self._use_device)
             changed = dict(zip(parents, hashes))
-            self._stage_level(wb, depth - 1, changed, ver)
+            bound = up
+            self._level_rows(rows, depth - 1, changed, ver)
+        wb.extend(rows)
 
+        _M_KEYS.inc(len(updates))
+        _M_ENGINE_READS.inc(reads)
+        _M_BOUNDED.inc(bounded)
         if own_batch:
             self._db.write(wb)
         return changed[0]
+
+    def _empty_depth(self, bits: int) -> Tuple[int, int]:
+        """-> (the least depth at which the path of leaf `bits` has no
+        stored node, DEPTH + 1 if the leaf itself is stored; node reads
+        made). Only non-default nodes are stored, so the subtree under
+        an absent path node is empty and every path node below it is
+        absent too: one read of the leaf, then a bisection."""
+        get, family = self._db.get, self._family
+        if get(_node_key(DEPTH, bits), family) is not None:
+            return DEPTH + 1, 1
+        lo, hi, reads = 1, DEPTH, 1        # the answer is in [lo, hi]
+        while lo < hi:
+            mid = (lo + hi) // 2
+            reads += 1
+            if get(_node_key(mid, bits >> (DEPTH - mid)), family) is None:
+                hi = mid
+            else:
+                lo = mid + 1
+        return lo, reads
 
     # ---- multi-block batch update ----
     def update_batches(self, updates_list: Sequence[Dict[bytes,
@@ -286,22 +357,21 @@ class SparseMerkleTree:
                     wb.put(k + vers[i], b"" if h == default else h,
                            self._arch_family)
 
-    def _stage_level(self, wb: WriteBatch, depth: int,
-                     nodes: Dict[int, bytes],
-                     ver: Optional[bytes] = None) -> None:
+    def _level_rows(self, rows: List[Tuple[bytes, Optional[bytes]]],
+                    depth: int, nodes: Dict[int, bytes],
+                    ver: Optional[bytes]) -> None:
+        """Append a level's rows: a node that hashes to its depth's
+        default is deleted, so only non-default nodes are stored."""
         default = _DEFAULTS[depth]
         for bits, h in nodes.items():
             k = _node_key(depth, bits)
-            if h == default:
-                wb.delete(k, self._family)
-            else:
-                wb.put(k, h, self._family)
+            rows.append((self._pre + k, None if h == default else h))
             if ver is not None:
                 # archive row; default is stored as empty so a historical
                 # walk can tell "reverted to default at ver" from "never
                 # touched" (the latter = default since genesis)
-                wb.put(k + ver, b"" if h == default else h,
-                       self._arch_family)
+                rows.append((self._arch_pre + k + ver,
+                             b"" if h == default else h))
 
     # ---- versioned reads ----
     def _newest_row_at(self, family: bytes, prefix: bytes,

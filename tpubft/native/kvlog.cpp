@@ -61,6 +61,9 @@ struct KvLog {
   std::string path;
   int fd = -1;
   uint64_t wal_bytes = 0;
+  // the index encoded as one batch payload: what a compaction would
+  // write. wal_bytes less this (and a record header) is garbage.
+  uint64_t live_bytes = 0;
   bool sync_writes = true;
   std::mutex mu;
 };
@@ -81,10 +84,18 @@ bool apply_payload(KvLog* db, const uint8_t* p, size_t n) {
       uint32_t vlen = rd_u32(p + off);
       off += 4;
       if (off + vlen > n) return false;
-      db->index[std::move(key)] = std::string((const char*)p + off, vlen);
+      auto [it, fresh] = db->index.try_emplace(std::move(key));
+      if (fresh) db->live_bytes += 9 + klen;
+      else db->live_bytes -= it->second.size();
+      db->live_bytes += vlen;
+      it->second.assign((const char*)p + off, vlen);
       off += vlen;
     } else if (op == 2) {
-      db->index.erase(key);
+      auto it = db->index.find(key);
+      if (it != db->index.end()) {
+        db->live_bytes -= 9 + klen + it->second.size();
+        db->index.erase(it);
+      }
     } else {
       return false;
     }
@@ -231,6 +242,11 @@ uint64_t kvlog_count(KvLog* db) {
 uint64_t kvlog_wal_bytes(KvLog* db) {
   std::lock_guard<std::mutex> g(db->mu);
   return db->wal_bytes;
+}
+
+uint64_t kvlog_live_bytes(KvLog* db) {
+  std::lock_guard<std::mutex> g(db->mu);
+  return db->live_bytes;
 }
 
 // Range scan [start, end) materialized as one buffer in batch-payload

@@ -62,6 +62,14 @@ class WriteBatch:
         self.ops.append((fkey(family, key), None))
         return self
 
+    def extend(self, ops: List[Tuple[bytes, Optional[bytes]]]
+               ) -> "WriteBatch":
+        """Append `ops` in order: (physical key, value — None deletes),
+        the key already composed with `fkey`. For a caller that stages
+        many rows of a few families at once."""
+        self.ops.extend(ops)
+        return self
+
     def __len__(self) -> int:
         return len(self.ops)
 

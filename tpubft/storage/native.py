@@ -32,6 +32,8 @@ def _lib():
     lib.kvlog_count.argtypes = [ctypes.c_void_p]
     lib.kvlog_wal_bytes.restype = ctypes.c_uint64
     lib.kvlog_wal_bytes.argtypes = [ctypes.c_void_p]
+    lib.kvlog_live_bytes.restype = ctypes.c_uint64
+    lib.kvlog_live_bytes.argtypes = [ctypes.c_void_p]
     lib.kvlog_scan.restype = ctypes.c_int
     lib.kvlog_scan.argtypes = [ctypes.c_void_p, ctypes.c_char_p,
                                ctypes.c_uint32, ctypes.c_char_p,
@@ -135,8 +137,13 @@ class NativeDB(IDBClient):
                 rc = self._lib.kvlog_sync(self._h)
                 if rc != 0:
                     raise StorageError(f"kvlog_sync rc={rc}")
-            need_compact = (self._lib.kvlog_wal_bytes(self._h)
-                            > self._compact_bytes)
+            # past the floor, and at least half of the log is garbage:
+            # a live set that has outgrown `compact_bytes` (an
+            # append-only ledger does, for good) must not be rewritten
+            # whole on every write
+            wal = self._lib.kvlog_wal_bytes(self._h)
+            need_compact = (wal > self._compact_bytes and
+                            wal > 2 * self._lib.kvlog_live_bytes(self._h))
         if need_compact:
             self.compact()
 
