@@ -274,6 +274,14 @@ class TestPolicies:
         # stale kernel counters (no fresh launches): hold
         assert pol(prev, prev, _knob()) == HOLD
         assert pol(falling, None, _knob()) == HOLD
+        # ... unless the floor has grown past its configured value: it
+        # may have shut the device out, and only launches teach this
+        # policy anything — step back toward the configuration
+        grown = Knob(name="k", value=72, default=32, lo=1, hi=1024)
+        assert pol(prev, prev, grown) == SHRINK
+        assert pol(falling, None, grown) == HOLD
+        lowered = Knob(name="k", value=16, default=32, lo=1, hi=1024)
+        assert pol(prev, prev, lowered) == HOLD
 
     def test_optimistic_combine_policy_vetoes_shrink_on_cert_lag(self):
         pol = optimistic_combine_policy(

@@ -71,7 +71,12 @@ def test_crash_between_commit_and_apply_replays_exactly_once(tmp_path):
         # reply ring intact across the crash: the restarted replica
         # reloaded executed-request records from the persisted ring
         cid = cluster.client(0).cfg.client_id
-        info = rep.clients._clients[cid]
+        # the paged table starts empty after a restart and holds a client
+        # only once something has touched it: page the record in from the
+        # persisted ring, as the replica itself would, instead of racing
+        # the client's next retransmission for it
+        with rep.clients._mu:
+            info = rep.clients._resident(cid)
         assert info.replies, "reply ring lost across restart"
         assert all(rep.clients.was_executed(cid, s) for s in info.replies)
         # cluster keeps committing with the recovered replica

@@ -1754,10 +1754,16 @@ class Replica(IReceiver):
     # ------------------------------------------------------------------
     # slow path: shares → collectors (ReplicaImp.cpp:1373,1399)
     # ------------------------------------------------------------------
+    def _sign_share(self, signer, d: bytes, seq_num: int) -> bytes:
+        """This replica's threshold share over `d`, as one `share_sign`
+        flight span (ed25519 or BLS: whatever the scheme's signer is)."""
+        with flight.span("share_sign", seq_num):
+            return signer.sign_share(d)
+
     def _send_prepare_partial(self, info: SeqNumInfo) -> None:
         pp = info.pre_prepare
         d = self._share_digest("prepare", self.view, pp.seq_num, pp.digest())
-        share = self.slow_signer.sign_share(d)
+        share = self._sign_share(self.slow_signer, d, pp.seq_num)
         msg = m.PreparePartialMsg(sender_id=self.id, view=self.view,
                                   seq_num=pp.seq_num, digest=d, sig=share,
                                   epoch=self.epoch)
@@ -1766,7 +1772,7 @@ class Replica(IReceiver):
     def _send_commit_partial(self, info: SeqNumInfo) -> None:
         pp = info.pre_prepare
         d = self._share_digest("commit", self.view, pp.seq_num, pp.digest())
-        share = self.slow_signer.sign_share(d)
+        share = self._sign_share(self.slow_signer, d, pp.seq_num)
         msg = m.CommitPartialMsg(sender_id=self.id, view=self.view,
                                  seq_num=pp.seq_num, digest=d, sig=share,
                                  epoch=self.epoch)
@@ -2065,7 +2071,8 @@ class Replica(IReceiver):
         msg = m.PartialCommitProofMsg(sender_id=self.id, view=self.view,
                                       epoch=self.epoch,
                                       seq_num=pp.seq_num, digest=d,
-                                      sig=signer.sign_share(d),
+                                      sig=self._sign_share(signer, d,
+                                                           pp.seq_num),
                                       path=pp.first_path)
         collector_id = self.info.collector_for(self.view, pp.seq_num)
         if collector_id == self.id:

@@ -264,7 +264,14 @@ class BlsThresholdVerifier(IThresholdVerifier):
         soundness argument as batch_verify_shares (forged certs survive
         with probability 2^-128); on aggregate failure the rare path
         verifies per cert. Replaces k sequential ~2-pairing verifies with
-        2 pairings + two k-point G1 MSMs."""
+        2 pairings + two k-point G1 MSMs. One `bls_pairing_verify` span
+        a call (seq: certificates covered), as `verify` writes one a
+        certificate: the fused combine's own check and a backup's
+        CertBatchVerifier flush both come through here."""
+        with flight.span("bls_pairing_verify", len(items)):
+            return self._verify_batch_certs(items)
+
+    def _verify_batch_certs(self, items) -> List[bool]:
         out = [False] * len(items)
         pts, hs, idxs = [], [], []
         for i, (d, s) in enumerate(items):
@@ -343,14 +350,21 @@ class BlsThresholdVerifier(IThresholdVerifier):
         identification — one slot's byzantine share fails only its own
         job, sibling slots in the same flush still land. Verdicts are
         identical to the per-job default (interfaces.combine_batch)."""
+        t0 = time.monotonic_ns()
         decoded = [(digest, self._decode_job_shares(shares))
                    for digest, shares in jobs]
+        # the flush's share decompression as ONE span (seq: slots
+        # covered), where the per-slot accumulator sums it over `add`
+        flight.record_span("bls_share_decompress",
+                           (time.monotonic_ns() - t0) // 1000, len(jobs))
         segments = []
         for _digest, pts in decoded:
             ids = sorted(pts)[: self._threshold]
             segments.append((ids, [pts[i] for i in ids]))
-        combined = self._combine_segments(
-            segments, digests=[digest for digest, _ in decoded])
+        # Lagrange + MSM of every slot of the flush, host or device
+        with flight.span("bls_combine", len(segments)):
+            combined = self._combine_segments(
+                segments, digests=[digest for digest, _ in decoded])
         sigs = [bls.g1_compress(pt) for pt in combined]
         verdicts = self.verify_batch_certs(
             [(digest, sig) for (digest, _), sig in zip(decoded, sigs)])

@@ -20,7 +20,9 @@ The shared doctrine (ISSUE 14 / ROADMAP item 8):
   * the ECDSA device/host crossover follows the measured per-item cost
     of the `ecdsa` kernel vs the batched host engine;
   * every policy HOLDs without fresh signal — an idle replica's knobs
-    must not wander.
+    must not wander (one exception, toward the configuration and never
+    past it: `device_min_batch_policy`, whose own growth can cut off
+    the only signal it has).
 """
 from __future__ import annotations
 
@@ -193,13 +195,18 @@ def device_min_batch_policy() -> Policy:
     batches ride it too; a rising per-item cost means launches stopped
     amortizing (the floor admits batches too small to pay the dispatch
     overhead) — GROW it back toward host territory. No fresh kernel
-    calls => HOLD."""
+    calls => HOLD, unless the floor stands ABOVE its configured value:
+    it may then have outgrown every batch the traffic forms, the device
+    never launches again, and a policy that learns only from launches
+    could never bring it back (seen on the chip at n=7: one launch in a
+    48 s window) — SHRINK back toward the configured floor."""
 
     def policy(cur: Telemetry, prev: Optional[Telemetry],
                knob: Knob) -> int:
-        if prev is None or kernel_calls(cur, "ed25519") \
-                <= kernel_calls(prev, "ed25519"):
+        if prev is None:
             return HOLD
+        if kernel_calls(cur, "ed25519") <= kernel_calls(prev, "ed25519"):
+            return SHRINK if knob.value > knob.default else HOLD
         a = kernel_per_item_us(cur, "ed25519")
         b = kernel_per_item_us(prev, "ed25519")
         if a is None or b is None or b <= 0.0:

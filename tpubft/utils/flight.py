@@ -390,29 +390,42 @@ def record_span(name: str, us: int, seq: int = 0) -> None:
     record(EV_SPAN, seq, span_id(name), int(us))
 
 
-def span_events(name: str, since_ns: int = 0
-                ) -> Optional[List[Tuple[int, int, int]]]:
-    """`(t_end_ns, seq, us)` of every retained span `name` that closed
-    at or after `since_ns`, oldest first. None where a ring that holds
-    such spans has wrapped past `since_ns`: the window's spans can no
-    longer be told complete, and a reader must say so, not average the
-    survivors."""
+def span_events_tail(name: str, since_ns: int = 0
+                     ) -> Tuple[List[Tuple[int, int, int]], int]:
+    """`(spans, from_ns)`: `(t_end_ns, seq, us)` of every retained span
+    `name` that closed at or after `from_ns`, oldest first. `from_ns` is
+    `since_ns`, moved up to the oldest event of any ring that holds such
+    spans and has wrapped past it — from there on every ring is whole,
+    so the spans are complete (a dispatcher's ring, one event a message,
+    wraps many times inside a window its spans are wanted from)."""
     sid = _span_ids.get(name)
     if sid is None:
-        return []
+        return [], since_ns
     with _rings_mu:
         rings = list(_rings)
     out: List[Tuple[int, int, int]] = []
+    from_ns = since_ns
     for r in rings:
         evs = r.events()
         mine = [(t, seq, arg) for t, code, seq, view, arg in evs
                 if code == EV_SPAN and view == sid]
         if not mine:
             continue
-        if len(evs) == RING_SIZE and evs[0][0] > since_ns:
-            return None
-        out.extend(e for e in mine if e[0] >= since_ns)
-    return sorted(out)
+        if len(evs) == RING_SIZE:
+            from_ns = max(from_ns, evs[0][0])
+        out.extend(mine)
+    return sorted(e for e in out if e[0] >= from_ns), from_ns
+
+
+def span_events(name: str, since_ns: int = 0
+                ) -> Optional[List[Tuple[int, int, int]]]:
+    """`(t_end_ns, seq, us)` of every retained span `name` that closed
+    at or after `since_ns`, oldest first. None where a ring that holds
+    such spans has wrapped past `since_ns`: the window's spans can no
+    longer be told complete, and a reader must say so, not average the
+    survivors (or ask `span_events_tail` from where they are)."""
+    spans, from_ns = span_events_tail(name, since_ns)
+    return spans if from_ns == since_ns else None
 
 
 ENABLED = os.environ.get("TPUBFT_FLIGHT", "1") not in ("", "0")
