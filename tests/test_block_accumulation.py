@@ -163,6 +163,70 @@ def test_accumulation_abort_rolls_back():
     assert bc.end_accumulation() == 2
 
 
+def _merkle_block(key, value=b"v"):
+    bu = BlockUpdates()
+    bu.put("mk", key, value, cat_type=BLOCK_MERKLE)
+    return bu
+
+
+def _has_encoded_rows(wb) -> bool:
+    from tpubft.storage.interfaces import EncodedRows
+    return any(isinstance(p, EncodedRows) for p in wb._parts)
+
+
+@pytest.mark.parametrize("speculative", [False, True])
+def test_abort_of_a_run_with_encoded_rows_leaves_nothing_readable(
+        speculative):
+    """A merkle block's walk stages its rows encoded. An aborted run's
+    are gone from every view: the ledger's, a fresh tree's, the store's."""
+    db = MemoryDB()
+    bc = KeyValueBlockchain(db, use_device_hashing=False)
+    bc.add_block(_merkle_block(b"kept"))
+    before, root = _dump(db), bc.merkle_root("mk")
+    bc.begin_accumulation(speculative=speculative)
+    for i in range(3):
+        bc.add_block(_merkle_block(b"doomed-%d" % i))
+    assert _has_encoded_rows(bc._accum.master)
+    assert bc.merkle_root("mk") != root          # the run reads its own
+    assert bc.get_latest("mk", b"doomed-1", cat_type=BLOCK_MERKLE) \
+        is not None
+    bc.abort_accumulation()
+    assert bc.last_block_id == 1 and bc.merkle_root("mk") == root
+    assert bc.get_latest("mk", b"doomed-1", cat_type=BLOCK_MERKLE) is None
+    assert SparseMerkleTree(db, family=b"smt.mk",
+                            use_device=False).root() == root
+    assert _dump(db) == before
+    # and the next run starts from the kept state
+    bc.begin_accumulation()
+    bc.add_block(_merkle_block(b"next"))
+    assert bc.end_accumulation() == 2
+    seq = KeyValueBlockchain(MemoryDB(), use_device_hashing=False)
+    seq.add_block(_merkle_block(b"kept"))
+    seq.add_block(_merkle_block(b"next"))
+    assert bc.state_digest() == seq.state_digest()
+
+
+def test_block_n_plus_1_reads_the_nodes_block_n_staged_encoded():
+    """Inside one run nothing has reached the store: block N+1's walk
+    finds block N's nodes (its probes, its sibling reads, the root) only
+    through the overlay that block N's encoded rows fed."""
+    db = MemoryDB()
+    bc = KeyValueBlockchain(db, use_device_hashing=False)
+    seq = KeyValueBlockchain(MemoryDB(), use_device_hashing=False)
+    bc.begin_accumulation()
+    for i in range(6):
+        key = b"shared" if i % 3 == 2 else b"k%d" % i     # overwrites too
+        bc.add_block(_merkle_block(key, b"v%d" % i))
+        seq.add_block(_merkle_block(key, b"v%d" % i))
+        assert bc.merkle_root("mk") == seq.merkle_root("mk"), i
+        assert bc.prove("mk", key) == seq.prove("mk", key), i
+    assert _has_encoded_rows(bc._accum.master)
+    assert not _dump(db), "nothing reaches the store before the run ends"
+    assert bc.end_accumulation() == 6
+    assert bc.state_digest() == seq.state_digest()
+    assert _dump(db) == _dump(seq._db)
+
+
 def test_accumulation_extra_ops_ride_the_same_batch():
     from tpubft.storage.interfaces import WriteBatch
     db = MemoryDB()

@@ -59,14 +59,10 @@ def test_pending_store_stage_lookup_apply():
     assert st.lookup(b"\x01ak2") == (n1, None)   # pending delete
     assert st.lookup(b"\x01ak3") is None
     # applying run 1 must NOT drop k1 (run 2's value still pending)
-    wb1 = WriteBatch()
-    wb1.ops = [(b"\x01ak1", b"v1"), (b"\x01ak2", None)]
-    st.mark_applied(n1, wb1)
+    st.mark_applied(n1)
     assert st.lookup(b"\x01ak1") == (n2, b"v2")
     assert st.lookup(b"\x01ak2") is None
-    wb2 = WriteBatch()
-    wb2.ops = [(b"\x01ak1", b"v2")]
-    st.mark_applied(n2, wb2)
+    st.mark_applied(n2)
     assert st.empty
     assert st.wait_empty(0.1)
 

@@ -4,10 +4,16 @@ the same ascent with every sibling read. Roots after every block and the
 full set of stored rows (live nodes, leaves, both archive families) must
 be equal — on a bare tree, and through `KeyValueBlockchain` inside plain,
 aborted and speculative accumulations, where the bound is taken through
-the staged view (block N+1 of a run sees block N's path)."""
+the staged view (block N+1 of a run sees block N's path).
+
+A batch of fewer than 192 changed leaves takes the native walk, whose rows
+arrive encoded: its payload is held, byte for byte, to the golden walk's
+rows put one at a time and encoded by the plain encoder kept here — on a
+bare tree, and as the engine receives it from a ledger's runs."""
 import hashlib
 import math
 import random
+import struct
 import threading
 import types
 
@@ -17,6 +23,7 @@ from tpubft.kvbc import BLOCK_MERKLE, BlockUpdates, KeyValueBlockchain
 from tpubft.kvbc import sparse_merkle
 from tpubft.kvbc.sparse_merkle import (DEPTH, _EMPTY, SparseMerkleTree,
                                        _leaf_hash)
+from tpubft.durability import PendingStore
 from tpubft.storage.interfaces import IDBClient, WriteBatch
 from tpubft.storage.memorydb import MemoryDB
 from tpubft.storage.native import NativeDB
@@ -86,6 +93,17 @@ class FullReadTree(SparseMerkleTree):
         if own_batch:
             self._db.write(wb)
         return changed[0]
+
+
+def plain_payload(ops) -> bytes:
+    """The engine's wire encoding, one row at a time:
+    u8 op(1=put, 2=del) | u32le klen | key | [u32le vlen | val]."""
+    out = []
+    for k, v in ops:
+        out += [struct.pack("<BI", 2 if v is None else 1, len(k)), k]
+        if v is not None:
+            out += [struct.pack("<I", len(v)), v]
+    return b"".join(out)
 
 
 class FullReadLedger(KeyValueBlockchain):
@@ -217,10 +235,77 @@ def test_siblings_at_depth_256(monkeypatch):
     gold = FullReadTree(gold_db, use_device=False)
     tree = SparseMerkleTree(db, use_device=False)
     for i, ups in enumerate(blocks):
-        assert tree.update_batch(dict(ups), version=1 + i) \
-            == gold.update_batch(dict(ups), version=1 + i), i
+        wb, gold_wb = WriteBatch(), WriteBatch()
+        assert tree.update_batch(dict(ups), batch=wb, version=1 + i) \
+            == gold.update_batch(dict(ups), batch=gold_wb,
+                                 version=1 + i), i
+        assert wb.encode() == plain_payload(gold_wb.ops), i
+        db.write(wb)
+        gold_db.write(gold_wb)
         assert dump(db) == dump(gold_db), i
     assert not list(db.range_iter(b"smt"))
+
+
+def blocks_of(leaves, rng):
+    """At most `leaves` changed leaves a block, the first two blocks
+    exactly: fresh keys, then overwrites and deletes beside fresh keys,
+    then the first block's rest deleted, then one key back."""
+    keys = [b"n%d" % i for i in range(leaves)]
+    some = keys[:max(1, leaves // 2)]
+    mixed = {k: (None if i % 3 == 0 else vh(rng.random()))
+             for i, k in enumerate(some)}
+    mixed.update((b"late%d" % i, vh(i)) for i in range(leaves - len(some)))
+    gone = {k for k, v in mixed.items() if v is None}
+    return [{k: vh(i) for i, k in enumerate(keys)}, mixed,
+            {k: None for k in keys if k not in gone}, {keys[0]: vh(-1)}]
+
+
+@pytest.mark.parametrize("store", ["memory", "native"])
+@pytest.mark.parametrize("version", [0, 7])
+@pytest.mark.parametrize("leaves", [1, 2, 7, 191])
+def test_native_walk_payload_is_the_full_read_walk_s(leaves, version, store,
+                                                     tmp_path):
+    def open_db(name):
+        return MemoryDB() if store == "memory" else NativeDB(
+            str(tmp_path / name), sync_writes=False)
+    gold_db, db = open_db("gold.kvlog"), open_db("tree.kvlog")
+    gold = FullReadTree(gold_db, use_device=False)
+    tree = SparseMerkleTree(db, use_device=False)
+    for i, ups in enumerate(blocks_of(leaves, random.Random(leaves))):
+        assert len(ups) == leaves if i < 2 else len(ups) <= leaves
+        ver = version + i if version else 0
+        c0 = counters()
+        wb, gold_wb = WriteBatch(), WriteBatch()
+        assert tree.update_batch(dict(ups), batch=wb, version=ver) \
+            == gold.update_batch(dict(ups), batch=gold_wb, version=ver), i
+        assert wb.encode() == plain_payload(gold_wb.ops), i
+        assert wb.ops == gold_wb.ops and len(wb) == len(gold_wb), i
+        assert wb.families == gold_wb.families, i
+        assert counters()["smt_keys_native"] - c0["smt_keys_native"] \
+            == len(ups), i
+        db.write(wb)
+        gold_db.write(gold_wb)
+        assert dump(db) == dump(gold_db), i
+    assert tree.root() == gold.root()
+
+
+def test_192_leaves_take_the_level_loop():
+    gold_db, db = MemoryDB(), MemoryDB()
+    gold = FullReadTree(gold_db, use_device=False)
+    tree = SparseMerkleTree(db, use_device=False)
+    ups = {b"w%d" % i: vh(i) for i in range(sparse_merkle._DEVICE_THRESHOLD)}
+    c0 = counters()
+    wb, gold_wb = WriteBatch(), WriteBatch()
+    assert tree.update_batch(dict(ups), batch=wb, version=3) \
+        == gold.update_batch(dict(ups), batch=gold_wb, version=3)
+    c1 = counters()
+    assert c1["smt_keys_native"] == c0["smt_keys_native"]
+    assert c1["smt_keys_updated"] - c0["smt_keys_updated"] == len(ups)
+    assert wb.encode() == plain_payload(gold_wb.ops)
+    # one leaf fewer is the native walk's
+    ups.popitem()
+    tree.update_batch(ups, batch=WriteBatch(), version=4)
+    assert counters()["smt_keys_native"] - c1["smt_keys_native"] == len(ups)
 
 
 def merkle_block(ups) -> BlockUpdates:
@@ -332,6 +417,95 @@ def test_ledger_rows_match_through_staged_views(mode):
         assert dump(db) == dump(gold_db), at
     for b in range(1, bc.last_block_id + 1):
         assert bc.get_raw_block(b) == gold.get_raw_block(b)
+
+
+def run_deferred(bc, blocks):
+    """As the execution lane seals a run: the overlay goes to the pending
+    store, the batch to the engine as a group of one, later."""
+    bc.begin_accumulation()
+    run_plain(bc, blocks)
+    bc.end_accumulation(defer=True)
+    return bc.take_deferred()
+
+
+def run_deferred_applied(bc, blocks, store):
+    run_no, batch, base = run_deferred(bc, blocks)
+    base.write_group([batch])
+    store.mark_applied(run_no)
+
+
+def run_deferred_two_pending(bc, blocks, store):
+    """Two runs sealed before either is applied: the second run's walks
+    read the first's rows from the pending store."""
+    half = len(blocks) // 2
+    sealed = [run_deferred(bc, blocks[:half]),
+              run_deferred(bc, blocks[half:])]
+    sealed[0][2].write_group([batch for _no, batch, _db in sealed])
+    for run_no, _batch, _db in sealed:
+        store.mark_applied(run_no)
+
+
+def run_speculative_deferred(bc, blocks, store):
+    def spec():
+        bc.begin_accumulation(speculative=True)
+        run_plain(bc, blocks)
+        bc.end_accumulation(defer=True)
+    in_thread(spec)
+    run_no, batch, base = bc.take_deferred()
+    base.write_group([batch])
+    store.mark_applied(run_no)
+
+
+ENGINE_MODES = {
+    "accumulated": lambda bc, blocks, store: run_accumulated(bc, blocks),
+    "speculative": lambda bc, blocks, store: run_speculative(bc, blocks),
+    "deferred": run_deferred_applied,
+    "deferred_two_pending": run_deferred_two_pending,
+    "speculative_deferred": run_speculative_deferred}
+
+
+@pytest.mark.parametrize("mode", sorted(ENGINE_MODES))
+def test_the_engine_receives_the_full_read_walk_s_payload(mode, tmp_path,
+                                                          monkeypatch):
+    """What `kvlog_apply` is handed for a ledger's runs — under a staged
+    accumulation, a pending store and a speculative overlay — is the
+    payload of the golden ledger's rows, put one at a time."""
+    applied = {}
+    real = NativeDB._apply
+
+    def recording(self, payload, families):
+        applied.setdefault(id(self), []).append(bytes(payload))
+        return real(self, payload, families)
+    monkeypatch.setattr(NativeDB, "_apply", recording)
+    gold_db = NativeDB(str(tmp_path / "gold.kvlog"), sync_writes=False)
+    db = NativeDB(str(tmp_path / "bc.kvlog"), sync_writes=False)
+    gold = FullReadLedger(gold_db, use_device_hashing=False)
+    bc = KeyValueBlockchain(db, use_device_hashing=False)
+    store = PendingStore("t")
+    bc.attach_durability(store)
+    blocks = ledger_blocks(random.Random(11), 40)
+    c0 = counters()
+    for at in range(0, len(blocks), 8):
+        run = blocks[at:at + 8]
+        gold.begin_accumulation()
+        for ups in run:
+            gold.add_block(merkle_block(ups))
+        gold_rows = plain_payload(gold._accum.master.ops)
+        gold.end_accumulation()
+        ENGINE_MODES[mode](bc, run, store)
+        assert b"".join(applied[id(db)]) \
+            == b"".join(applied[id(gold_db)]), at
+        assert applied[id(gold_db)][-1] == gold_rows
+        assert store.empty
+        assert bc.state_digest() == gold.state_digest(), at
+    c1 = counters()
+    assert c1["smt_keys_native"] - c0["smt_keys_native"] \
+        == sum(map(len, blocks))
+    db.close()
+    gold_db.close()
+    # the log replays to the same store
+    assert dump(NativeDB(str(tmp_path / "bc.kvlog"))) \
+        == dump(NativeDB(str(tmp_path / "gold.kvlog")))
 
 
 class CountingDB(IDBClient):

@@ -110,7 +110,131 @@ def test_native_sync_families_carveout(tmp_path):
     db.close()
     # sync_writes=True ignores the carve-out (everything already syncs)
     db = NativeDB(path, sync_writes=True, sync_families=(b"metadata",))
-    assert db._sync_prefixes == ()
+    assert not db._sync_prefixes
+    db.close()
+
+
+def _encoded(ops):
+    """`ops` as the native walk hands rows over: payload and index."""
+    import struct
+    from tpubft.storage.interfaces import EncodedRows
+    payload, index, families = b"", b"", set()
+    for k, v in ops:
+        payload += struct.pack("<BI", 2 if v is None else 1, len(k))
+        k0 = len(payload)
+        payload += k
+        v0 = v1 = 0
+        if v is not None:
+            payload += struct.pack("<I", len(v))
+            v0, v1 = len(payload), len(payload) + len(v)
+            payload += v
+        index += struct.pack("<4I", k0, k0 + len(k), v0, v1)
+        families.add(k[:1 + k[0]])
+    return EncodedRows(payload, index, tuple(families))
+
+
+_SEGMENT = [(fkey(b"smt", b"\x01\x00node"), b"h" * 32),
+            (fkey(b"smt", b"\x00\xffgone"), None),
+            (fkey(b"smt.arch", b"\x00\xffgone" + b"\x00" * 8), b""),
+            (fkey(b"smt.leaf", b"p" * 32), b"v" * 32)]
+
+
+def _mixed_batches():
+    """The same rows twice: put/delete round an encoded segment, and
+    every row through put/delete."""
+    mixed = WriteBatch().put(b"first", b"1", b"blk").delete(b"old", b"blk")
+    mixed.extend_encoded(_encoded(_SEGMENT))
+    mixed.put(b"last", b"", b"blk.misc").extend_encoded(
+        _encoded(_SEGMENT[:1]))
+    plain = WriteBatch().put(b"first", b"1", b"blk").delete(b"old", b"blk")
+    plain.extend(_SEGMENT).put(b"last", b"", b"blk.misc")
+    plain.extend(_SEGMENT[:1])
+    return mixed, plain
+
+
+def test_batch_with_encoded_rows_encodes_as_the_all_ops_batch():
+    mixed, plain = _mixed_batches()
+    assert mixed.encode() == plain.encode()
+    assert mixed.ops == plain.ops and len(mixed) == len(plain) == 8
+    assert mixed.families == plain.families == {
+        b"\x03blk", b"\x08blk.misc", b"\x03smt", b"\x08smt.arch",
+        b"\x08smt.leaf"}
+    assert not len(WriteBatch()) and WriteBatch().encode() == b""
+    # a store without the wire format reads the rows through `ops`
+    a, b = MemoryDB(), MemoryDB()
+    a.write(mixed)
+    b.write(plain)
+    assert sorted(a.scan_all()) == sorted(b.scan_all())
+    assert a.get(b"\x00\xffgone" + b"\x00" * 8, b"smt.arch") == b""
+
+
+@pytest.mark.parametrize("group", [False, True])
+def test_encoded_rows_reopen_after_a_torn_tail_to_the_same_prefix(
+        tmp_path, group):
+    def write(db, batches):
+        if group:
+            db.write_group(batches)
+        else:
+            for b in batches:
+                db.write(b)
+    logs = []
+    for name, batch in zip(("mixed", "plain"), _mixed_batches()):
+        path = str(tmp_path / f"{name}.kvlog")
+        db = NativeDB(path)
+        db.put(b"before", b"0")
+        write(db, [batch, WriteBatch().put(b"after", b"2")])
+        db.close()
+        logs.append(open(path, "rb").read())
+    assert logs[0] == logs[1], "the log is the same bytes, CRCs included"
+    # cut inside the last record (and, alone, inside the batch's own):
+    # replay stops at the last whole record, the same one for both
+    for cut in (3, 40):
+        seen = []
+        for name in ("mixed", "plain"):
+            path = str(tmp_path / f"{name}.{cut}.kvlog")
+            with open(path, "wb") as fh:
+                fh.write(logs[0][:-cut])
+            db = NativeDB(path)
+            seen.append(sorted(db.scan_all()))
+            db.close()
+        assert seen[0] == seen[1]
+        assert (b"default", b"before", b"0") in seen[0]
+        assert (b"default", b"after", b"2") not in seen[0]
+        whole = (b"smt.leaf", b"p" * 32, b"v" * 32) in seen[0]
+        assert whole == (cut == 3 and not group)
+
+
+class _CountingLib:
+    """The engine's library, counting fsyncs."""
+
+    def __init__(self, lib):
+        self._lib, self.syncs = lib, 0
+
+    def __getattr__(self, name):
+        return getattr(self._lib, name)
+
+    def kvlog_sync(self, handle):
+        self.syncs += 1
+        return self._lib.kvlog_sync(handle)
+
+
+@pytest.mark.parametrize("group", [False, True])
+def test_encoded_rows_of_a_sync_family_still_sync(tmp_path, group):
+    db = NativeDB(str(tmp_path / "db.kvlog"), sync_writes=False,
+                  sync_families=(b"smt.leaf", b"metadata"))
+    db._lib = _CountingLib(db._lib)
+    write = db.write_group if group else (lambda bs: [db.write(b)
+                                                      for b in bs])
+    ledger_rows = WriteBatch().put(b"blk1", b"x", b"blk.blocks")
+    ledger_rows.extend_encoded(_encoded(_SEGMENT[:3]))   # smt, smt.arch
+    write([ledger_rows, WriteBatch().put(b"k", b"v", b"smt.lea")])
+    assert db._lib.syncs == 0
+    touching = WriteBatch().put(b"blk2", b"x", b"blk.blocks")
+    touching.extend_encoded(_encoded(_SEGMENT))           # smt.leaf too
+    write([ledger_rows, touching])
+    assert db._lib.syncs == 1
+    write([WriteBatch().put(b"d", b"desc", b"metadata"), ledger_rows])
+    assert db._lib.syncs == 2
     db.close()
 
 

@@ -60,54 +60,55 @@ log = get_logger("durability")
 class PendingStore:
     """Sealed-but-not-yet-applied write overlay.
 
-    Physical key -> (run_no, value-or-None) for every op of every
-    sealed batch the io thread has not applied yet. The blockchain's
-    permanently-installed `_PendingView` consults it on every point get
-    and merges it into every range scan, so readers on ANY thread see
-    sealed state exactly as if the batch had been applied — the only
-    thing deferred is the disk.
+    One dict a sealed run — physical key -> value-or-None, the run's own
+    overlay, adopted whole — for every run the io thread has not applied
+    yet, newest first. The blockchain's permanently-installed
+    `_PendingView` consults it on every point get and merges it into
+    every range scan, so readers on ANY thread see sealed state exactly
+    as if the batch had been applied — the only thing deferred is the
+    disk. A key is answered by the newest run that wrote it (last writer
+    wins, exactly like the applies the runs stand in for), and a run's
+    rows go when ITS apply lands, whatever later runs wrote.
 
     Mutations: `stage` (execution lane, inside the accumulation
     bracket) and `mark_applied` (io thread, or the lane's barrier
-    paths) — both under the store lock. `lookup`/`snapshot_range` are
-    safe from any thread.
+    paths) — both under the store lock, each swapping in a new tuple of
+    runs. `lookup`/`snapshot_range` are safe from any thread. The seal
+    queue bounds the number of runs.
     """
 
     def __init__(self, name: str = "dur") -> None:
         self._mu = make_lock(f"{name}.pending")
         self._cond = threading.Condition(self._mu)
-        self._d: Dict[bytes, Tuple[int, Optional[bytes]]] = {}
+        # (run_no, overlay), newest first; never mutated, only replaced
+        self._runs: Tuple[Tuple[int, Dict[bytes, Optional[bytes]]],
+                          ...] = ()
         self._staged_no = 0
 
     # ---- staging (execution lane) ----
     def stage(self, overlay: Dict[bytes, Optional[bytes]]) -> int:
         """Adopt one sealed run's overlay (physical key -> value-or-
-        None); returns the run's pending ticket number. Later runs
-        overwrite earlier runs' entries for the same key — last writer
-        wins, exactly like the applies they stand in for."""
+        None; the caller writes to it no more); returns the run's
+        pending ticket number."""
         with self._cond:
             self._staged_no += 1
             no = self._staged_no
-            for k, v in overlay.items():
-                self._d[k] = (no, v)
+            self._runs = ((no, overlay),) + self._runs
             return no
 
     # ---- application (io thread / barrier paths) ----
-    def mark_applied(self, run_no: int, batch: WriteBatch) -> None:
-        """The batch for ticket `run_no` reached the base DB: drop its
-        keys from the overlay UNLESS a later run overwrote them (the
-        later value must stay visible until ITS apply lands)."""
+    def mark_applied(self, run_no: int) -> None:
+        """The batch for ticket `run_no` reached the base DB: its rows
+        leave the overlay. A key that a later run wrote too stays
+        answered by that run until ITS apply lands."""
         with self._cond:
-            for k, _v in batch.ops:
-                ent = self._d.get(k)
-                if ent is not None and ent[0] <= run_no:
-                    del self._d[k]
+            self._runs = tuple(r for r in self._runs if r[0] != run_no)
             self._cond.notify_all()
 
     def wait_empty(self, timeout: float) -> bool:
         deadline = time.monotonic() + timeout
         with self._cond:
-            while self._d:
+            while self._runs:
                 remaining = deadline - time.monotonic()
                 if remaining <= 0:
                     return False
@@ -116,20 +117,23 @@ class PendingStore:
 
     @property
     def empty(self) -> bool:
-        return not self._d
+        return not self._runs
 
     @property
     def depth(self) -> int:
-        return len(self._d)
+        return sum(len(d) for _, d in self._runs)
 
     # ---- read side (any thread) ----
     def lookup(self, physical_key: bytes
                ) -> Optional[Tuple[int, Optional[bytes]]]:
         """(run_no, value-or-None) or None when the key is not pending.
-        Lock-free: a dict point read is GIL-atomic and the value tuple
-        is immutable — a racy miss just falls through to the base,
-        which is where the key is headed anyway."""
-        return self._d.get(physical_key)
+        Lock-free: the tuple of runs is read once and a run's dict is
+        never written after `stage` — a racy miss just falls through to
+        the base, which is where the key is headed anyway."""
+        for no, d in self._runs:
+            if physical_key in d:
+                return no, d[physical_key]
+        return None
 
     def snapshot_range(self, lo: bytes, hi: Optional[bytes]
                        ) -> List[Tuple[bytes, Optional[bytes]]]:
@@ -138,11 +142,12 @@ class PendingStore:
         range readers (versioned reads, pages digests, ST summaries)
         see sealed state too. The overlay is bounded by the seal
         queue, so the scan is small."""
-        with self._cond:
-            items = [(k, v[1]) for k, v in self._d.items()
-                     if k >= lo and (hi is None or k < hi)]
-        items.sort()
-        return items
+        found: Dict[bytes, Optional[bytes]] = {}
+        for _no, d in reversed(self._runs):      # oldest first
+            for k, v in d.items():
+                if k >= lo and (hi is None or k < hi):
+                    found[k] = v
+        return sorted(found.items())
 
 
 @dataclass
@@ -463,7 +468,7 @@ class DurabilityPipeline:
         # distinct DB (one concatenated engine record on NativeDB)
         per_db: List[Tuple[object, List[SealedRun]]] = []
         for s in group:
-            if s.batch is None or s.db is None or not s.batch.ops:
+            if s.batch is None or s.db is None or not len(s.batch):
                 continue
             if per_db and per_db[-1][0] is s.db:
                 per_db[-1][1].append(s)
@@ -472,7 +477,7 @@ class DurabilityPipeline:
         for db, seals in per_db:
             db.write_group([s.batch for s in seals])
             for s in seals:
-                self.pending.mark_applied(s.run_no, s.batch)
+                self.pending.mark_applied(s.run_no)
         # 2. the crash seam: group applied (maybe durable, maybe not —
         # the OS owns the buffers), watermark NOT yet published, no
         # reply sent. A kill here must replay the suffix exactly once.

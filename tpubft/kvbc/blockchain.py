@@ -74,9 +74,15 @@ class _MirroredBatch(WriteBatch):
         self._overlay[fkey(family, key)] = None
         return super().delete(key, family)
 
-    def extend(self, ops) -> "WriteBatch":
+    def extend(self, ops, families=None) -> "WriteBatch":
         self._overlay.update(ops)
-        return super().extend(ops)
+        return super().extend(ops, families)
+
+    def extend_encoded(self, rows) -> "WriteBatch":
+        # the one pass over rows that arrive encoded: slices of their
+        # payload, so that the run's later blocks read them
+        self._overlay.update(rows.rows())
+        return super().extend_encoded(rows)
 
 
 class _StagedReadView(IDBClient):
@@ -494,15 +500,12 @@ class BlockStoreMixin:
             raise BlockchainError("defer=True without attach_durability")
         try:
             if extra is not None:
-                acc.master.ops.extend(extra.ops)
-                if store is not None:
-                    # extra ops bypassed the mirrored batch: fold them
-                    # into the overlay so the pending store carries the
-                    # WHOLE run (reply pages included), not just the
-                    # staged ledger rows
-                    for k, v in extra.ops:
-                        acc.master._overlay[k] = v
-            if acc.master.ops:
+                # mirrored like every other row, so that a deferred
+                # run's overlay carries the WHOLE run to the pending
+                # store (reply pages included), not just the staged
+                # ledger rows
+                acc.master.extend(extra.ops, extra.families)
+            if len(acc.master):
                 if store is not None:
                     run_no = store.stage(acc.master._overlay)
                     self._deferred = (run_no, acc.master,
@@ -700,7 +703,7 @@ class BlockStoreMixin:
                    adopted: List[Tuple[int, "cat.BlockUpdates"]]) -> None:
             if bad is not None:
                 wbs.append(WriteBatch().delete(_bid(bad), self._F_ST))
-            group = [wb for wb in wbs if wb.ops]
+            group = [wb for wb in wbs if len(wb)]
             if group:
                 # per-block batches ride the group-commit apply seam
                 # (ISSUE 15): ONE concatenated engine record / CRC /

@@ -30,14 +30,23 @@ depth as the default without asking the engine. This is no cache: the
 depth is found anew on every call through the tree's own read view (so
 a run's staged rows, the pending store and a speculative overlay are
 seen as ever), and nothing is remembered between calls.
+
+Writes: a batch too narrow for the device tier (fewer changed leaves
+than `_DEVICE_THRESHOLD`) is walked by one native call
+(tpubft/native/smtwalk.cpp) that returns its rows already in the
+engine's wire encoding; the write batch carries them to the log as they
+are. The rows, their order and their bytes are the level loop's.
 """
 from __future__ import annotations
 
+import ctypes
 import hashlib
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Sequence, Tuple
 
-from tpubft.storage.interfaces import IDBClient, WriteBatch, fkey
+from tpubft.native.build import load
+from tpubft.storage.interfaces import (EncodedRows, IDBClient, WriteBatch,
+                                       family_prefix)
 from tpubft.utils.metrics import Component
 
 DEPTH = 256
@@ -51,18 +60,46 @@ for _d in range(DEPTH - 1, -1, -1):
     _DEFAULTS[_d] = hashlib.sha256(
         b"\x01" + _DEFAULTS[_d + 1] + _DEFAULTS[_d + 1]).digest()
 
-# below this many nodes in a level, hashlib beats device dispatch
+# below this many nodes in a level, hashlib beats device dispatch: a
+# batch of fewer changed leaves has no level that wide, and its walk is
+# the native one (tpubft/native/smtwalk.cpp)
 _DEVICE_THRESHOLD = 192
 
 # what update_batch asked of the engine, process-wide (every tree of every
-# ledger in the process): leaves changed, node reads issued (the probes
-# for the empty depth and the sibling reads), and sibling lookups the
-# empty depth answered with no read. Totals only — nothing here is read
-# back by the tree.
+# ledger in the process): leaves changed, how many of them the native walk
+# carried, node reads issued (the probes for the empty depth and the
+# sibling reads), and sibling lookups the empty depth answered with no
+# read. Totals only — nothing here is read back by the tree.
 METRICS = Component("kvbc")
 _M_KEYS = METRICS.register_counter("smt_keys_updated")
+_M_NATIVE = METRICS.register_counter("smt_keys_native")
 _M_ENGINE_READS = METRICS.register_counter("smt_engine_reads")
 _M_BOUNDED = METRICS.register_counter("smt_siblings_bounded")
+
+_U8P = ctypes.POINTER(ctypes.c_uint8)
+_U32P = ctypes.POINTER(ctypes.c_uint32)
+
+
+def _lib():
+    lib = load("smtwalk")
+    if getattr(lib, "_smtwalk_typed", False):
+        return lib
+    lib.smt_walk.restype = ctypes.c_int
+    lib.smt_walk.argtypes = [
+        ctypes.c_char_p, ctypes.c_char_p, ctypes.POINTER(ctypes.c_int32),
+        ctypes.c_uint32,                                  # the leaves
+        ctypes.c_char_p, ctypes.c_uint32, ctypes.c_char_p,
+        ctypes.c_uint32,                                  # the siblings
+        ctypes.c_uint64, ctypes.c_char_p, _U32P,          # version, families
+        ctypes.c_char_p, _U32P,                           # root, defaults
+        ctypes.POINTER(_U8P), _U32P, ctypes.POINTER(_U32P), _U32P]
+    # free() is over before another thread could use the interpreter
+    # lock: called through a handle that keeps it
+    lib.free = ctypes.PyDLL(lib._name).smt_free
+    lib.free.argtypes = [ctypes.c_void_p]
+    lib.free.restype = None
+    lib._smtwalk_typed = True
+    return lib
 
 
 def _hash_level(messages: Sequence[bytes], use_device: bool) -> List[bytes]:
@@ -112,9 +149,23 @@ class SparseMerkleTree:
         # physical-key prefixes of the four families: update_batch
         # composes its rows' keys itself and stages them in one call
         self._pre, self._leaf_pre, self._arch_pre, self._leaf_arch_pre = (
-            fkey(f, b"") for f in (self._family, self._leaf_family,
-                                   self._arch_family,
-                                   self._leaf_arch_family))
+            family_prefix(f) for f in (self._family, self._leaf_family,
+                                       self._arch_family,
+                                       self._leaf_arch_family))
+        # the native walk (built here if this checkout has not yet:
+        # NativeBuildError now, not at the first write), and the
+        # prefixes as it takes them
+        self._lib = _lib()
+        prefixes = (self._pre, self._leaf_pre, self._arch_pre,
+                    self._leaf_arch_pre)
+        self._prefix_blob = b"".join(prefixes)
+        self._prefix_lens = (ctypes.c_uint32 * 4)(*map(len, prefixes))
+
+    def _row_families(self, version: int) -> Tuple[bytes, ...]:
+        """Family prefixes of the rows a walk stages at `version`."""
+        live = (self._pre, self._leaf_pre)
+        return live + (self._arch_pre, self._leaf_arch_pre) \
+            if version > 0 else live
 
     # ---- reads ----
     # Reads go straight to the DB (no node cache): staged-but-uncommitted
@@ -139,11 +190,100 @@ class SparseMerkleTree:
         If `batch` is given, node writes are staged into it (caller
         commits atomically with the block); otherwise committed here.
         `version` (the block id) > 0 additionally archives every changed
-        node so `prove_at` can serve this version later."""
+        node so `prove_at` can serve this version later.
+
+        The walk is chosen from the batch alone: fewer changed leaves
+        than the device tier's narrowest level take the native walk,
+        which returns the rows already encoded; a wider batch takes the
+        level loop, whose levels `_hash_level` may send to the device.
+        Both read the same nodes and stage the same rows in the same
+        order."""
         if not updates:
             return self.root()
         own_batch = batch is None
         wb = WriteBatch() if own_batch else batch
+        walk = (self._walk_native if len(updates) < _DEVICE_THRESHOLD
+                else self._walk_levels)
+        root = walk(updates, wb, version)
+        _M_KEYS.inc(len(updates))
+        if own_batch:
+            self._db.write(wb)
+        return root
+
+    def _walk_native(self, updates: Dict[bytes, Optional[bytes]],
+                     wb: WriteBatch, version: int) -> bytes:
+        """The walk for a narrow batch. What only the tree can do stays
+        here: hash each key to its path, find the path's empty depth and
+        read, through the tree's own read view, the siblings at or above
+        it (below it every sibling is the default, unread). One native
+        call then hashes the changed nodes up to the root and returns
+        the walk's rows in the engine's wire encoding."""
+        paths: List[bytes] = []
+        lens: List[int] = []
+        on_path = set()               # (depth, bits) that may be stored
+        reads = 0
+        for key, vh in updates.items():
+            path = hashlib.sha256(key).digest()
+            bits = int.from_bytes(path, "big")
+            empty, probes = self._empty_depth(bits)
+            reads += probes
+            paths.append(path)
+            lens.append(-1 if vh is None else len(vh))
+            for depth in range(1, min(empty, DEPTH) + 1):
+                on_path.add((depth, bits >> (DEPTH - depth)))
+        # a changed node's sibling is read unless it is changed too; two
+        # leaves that share a node share its path from there up, so
+        # their empty depths agree wherever either still decides
+        # anything. Deepest level first, ascending within a level: the
+        # order in which the walk meets them
+        siblings = sorted({(-depth, bits ^ 1) for depth, bits in on_path
+                           if (depth, bits ^ 1) not in on_path})
+        get, family = self._db.get, self._family
+        sib_keys: List[bytes] = []
+        sib_vals: List[bytes] = []
+        for neg_depth, bits in siblings:
+            k = _node_key(-neg_depth, bits)
+            v = get(k, family)
+            sib_keys.append(k)
+            sib_vals.append(v if v is not None else _DEFAULTS[-neg_depth])
+        reads += len(siblings)
+
+        lib = self._lib
+        n = len(paths)
+        keys_blob = b"".join(sib_keys)
+        root = ctypes.create_string_buffer(32)
+        defaulted, payload_len, n_rows = (ctypes.c_uint32(),
+                                          ctypes.c_uint32(),
+                                          ctypes.c_uint32())
+        payload, index = _U8P(), _U32P()
+        rc = lib.smt_walk(
+            b"".join(paths), b"".join(v for v in updates.values() if v),
+            (ctypes.c_int32 * n)(*lens), n,
+            keys_blob, len(keys_blob), b"".join(sib_vals), len(siblings),
+            max(version, 0), self._prefix_blob, self._prefix_lens,
+            root, ctypes.byref(defaulted),
+            ctypes.byref(payload), ctypes.byref(payload_len),
+            ctypes.byref(index), ctypes.byref(n_rows))
+        if rc != 0:
+            raise RuntimeError(f"smt_walk rc={rc}")
+        try:
+            rows = EncodedRows(
+                ctypes.string_at(payload, payload_len.value),
+                ctypes.string_at(index, 16 * n_rows.value),
+                self._row_families(version))
+        finally:
+            lib.free(payload)
+            lib.free(index)
+        wb.extend_encoded(rows)
+        _M_NATIVE.inc(n)
+        _M_ENGINE_READS.inc(reads)
+        _M_BOUNDED.inc(defaulted.value)
+        return root.raw
+
+    def _walk_levels(self, updates: Dict[bytes, Optional[bytes]],
+                     wb: WriteBatch, version: int) -> bytes:
+        """The walk for a batch wide enough for the device tier: one
+        `_hash_level` call a level."""
         ver = version.to_bytes(8, "big") if version > 0 else None
 
         # leaf level. Each leaf's empty depth is taken here, before any
@@ -200,13 +340,9 @@ class SparseMerkleTree:
             changed = dict(zip(parents, hashes))
             bound = up
             self._level_rows(rows, depth - 1, changed, ver)
-        wb.extend(rows)
-
-        _M_KEYS.inc(len(updates))
+        wb.extend(rows, self._row_families(version))
         _M_ENGINE_READS.inc(reads)
         _M_BOUNDED.inc(bounded)
-        if own_batch:
-            self._db.write(wb)
         return changed[0]
 
     def _empty_depth(self, bits: int) -> Tuple[int, int]:

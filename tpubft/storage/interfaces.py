@@ -10,7 +10,9 @@ scans stay contiguous per family.
 from __future__ import annotations
 
 import abc
-from typing import Dict, Iterator, List, Optional, Sequence, Tuple
+import struct
+from typing import (Dict, Iterable, Iterator, List, Optional, Sequence, Set,
+                    Tuple, Union)
 
 DEFAULT_FAMILY = b"default"
 
@@ -19,12 +21,18 @@ class StorageError(Exception):
     pass
 
 
-def fkey(family: bytes, key: bytes) -> bytes:
-    """Compose the physical key. Family names are <=255 bytes, so the
-    1-byte length prefix keeps families disjoint and contiguous."""
+def family_prefix(family: bytes) -> bytes:
+    """What every physical key of `family`, and of no other, starts
+    with. Family names are <=255 bytes, so the 1-byte length prefix
+    keeps families disjoint and contiguous."""
     if len(family) > 255:
         raise StorageError("family name too long")
-    return bytes([len(family)]) + family + key
+    return bytes([len(family)]) + family
+
+
+def fkey(family: bytes, key: bytes) -> bytes:
+    """Compose the physical key."""
+    return family_prefix(family) + key
 
 
 def split_fkey(physical: bytes) -> Tuple[bytes, bytes]:
@@ -35,8 +43,7 @@ def split_fkey(physical: bytes) -> Tuple[bytes, bytes]:
 def family_upper_bound(family: bytes) -> Optional[bytes]:
     """Smallest physical key strictly greater than every key in `family`
     (None = unbounded, i.e. family is the last possible)."""
-    prefix = bytes([len(family)]) + family
-    b = bytearray(prefix)
+    b = bytearray(family_prefix(family))
     for i in reversed(range(len(b))):
         if b[i] != 0xFF:
             b[i] += 1
@@ -44,46 +51,122 @@ def family_upper_bound(family: bytes) -> Optional[bytes]:
     return None
 
 
+def encode_rows(rows: Sequence[Tuple[bytes, Optional[bytes]]]) -> bytes:
+    """Canonical wire encoding shared with the native engine (kvlog.cpp):
+    repeat{ u8 op(1=put,2=del) | u32le klen | key | [u32le vlen | val] }"""
+    out = bytearray()
+    for k, v in rows:
+        if v is None:
+            out += b"\x02" + len(k).to_bytes(4, "little") + k
+        else:
+            out += (b"\x01" + len(k).to_bytes(4, "little") + k
+                    + len(v).to_bytes(4, "little") + v)
+    return bytes(out)
+
+
+class EncodedRows:
+    """A run of rows born in the wire encoding (the native merkle walk's,
+    tpubft/native/smtwalk.cpp): `payload` is `encode_rows` of them,
+    `index` holds four u32le a row — key start, key end, value start,
+    value end, offsets into `payload`, 0 and 0 for a delete — and
+    `families` the family prefixes their keys carry."""
+
+    __slots__ = ("payload", "index", "families")
+    _ROW = struct.Struct("<4I")
+
+    def __init__(self, payload: bytes, index: bytes,
+                 families: Sequence[bytes]) -> None:
+        self.payload = payload
+        self.index = index
+        self.families = families
+
+    def __len__(self) -> int:
+        return len(self.index) // self._ROW.size
+
+    def rows(self) -> List[Tuple[bytes, Optional[bytes]]]:
+        """(physical key, value — None deletes) per row, in order. A
+        value never starts at offset 0: the row's key comes first."""
+        p = self.payload
+        return [(p[k0:k1], p[v0:v1] if v0 else None)
+                for k0, k1, v0, v1 in self._ROW.iter_unpack(self.index)]
+
+
 class WriteBatch:
     """Ordered, atomic batch of put/delete ops across families
-    (reference: ITransaction / rocksdb::WriteBatch)."""
+    (reference: ITransaction / rocksdb::WriteBatch). Rows come one at a
+    time (`put`, `delete`), as a list (`extend`) or already encoded
+    (`extend_encoded`); the batch keeps them in the order they came and
+    never re-encodes what arrived encoded."""
 
     def __init__(self) -> None:
-        # (physical_key, value-or-None)
-        self.ops: List[Tuple[bytes, Optional[bytes]]] = []
+        # in order: lists of (physical_key, value-or-None), EncodedRows
+        self._parts: List[Union[List[Tuple[bytes, Optional[bytes]]],
+                                EncodedRows]] = []
+        # family prefixes (`family_prefix`) of every row so far
+        self.families: Set[bytes] = set()
+
+    def _tail(self) -> List[Tuple[bytes, Optional[bytes]]]:
+        """The list new rows join: the last part, if it is a list."""
+        parts = self._parts
+        if parts and type(parts[-1]) is list:
+            return parts[-1]
+        parts.append([])
+        return parts[-1]
 
     def put(self, key: bytes, value: bytes,
             family: bytes = DEFAULT_FAMILY) -> "WriteBatch":
-        self.ops.append((fkey(family, key), bytes(value)))
+        prefix = family_prefix(family)
+        self.families.add(prefix)
+        self._tail().append((prefix + key, bytes(value)))
         return self
 
     def delete(self, key: bytes,
                family: bytes = DEFAULT_FAMILY) -> "WriteBatch":
-        self.ops.append((fkey(family, key), None))
+        prefix = family_prefix(family)
+        self.families.add(prefix)
+        self._tail().append((prefix + key, None))
         return self
 
-    def extend(self, ops: List[Tuple[bytes, Optional[bytes]]]
-               ) -> "WriteBatch":
+    def extend(self, ops: Sequence[Tuple[bytes, Optional[bytes]]],
+               families: Optional[Iterable[bytes]] = None) -> "WriteBatch":
         """Append `ops` in order: (physical key, value — None deletes),
         the key already composed with `fkey`. For a caller that stages
-        many rows of a few families at once."""
-        self.ops.extend(ops)
+        many rows of a few families at once, and says which (their
+        `family_prefix`es); without `families` they are read off the
+        keys."""
+        self.families.update(families if families is not None
+                             else {k[:1 + k[0]] for k, _ in ops})
+        self._tail().extend(ops)
+        return self
+
+    def extend_encoded(self, rows: EncodedRows) -> "WriteBatch":
+        """Append rows that are already in the wire encoding."""
+        self.families.update(rows.families)
+        self._parts.append(rows)
         return self
 
     def __len__(self) -> int:
-        return len(self.ops)
+        return sum(map(len, self._parts))
 
-    # Canonical wire encoding shared with the native engine (kvlog.cpp):
-    # repeat{ u8 op(1=put,2=del) | u32le klen | key | [u32le vlen | val] }
+    @property
+    def ops(self) -> List[Tuple[bytes, Optional[bytes]]]:
+        """Every row as (physical key, value — None deletes), in order:
+        encoded runs are decoded on demand, so this is for a reader that
+        needs the rows (a store without the wire format, a test) — ask
+        `len()` for whether there are any, `families` for whose."""
+        parts = self._parts
+        if len(parts) == 1 and type(parts[0]) is list:
+            return parts[0]
+        out: List[Tuple[bytes, Optional[bytes]]] = []
+        for part in parts:
+            out.extend(part.rows() if isinstance(part, EncodedRows)
+                       else part)
+        return out
+
     def encode(self) -> bytes:
-        out = bytearray()
-        for k, v in self.ops:
-            if v is None:
-                out += b"\x02" + len(k).to_bytes(4, "little") + k
-            else:
-                out += (b"\x01" + len(k).to_bytes(4, "little") + k
-                        + len(v).to_bytes(4, "little") + v)
-        return bytes(out)
+        """The batch in the wire encoding (`encode_rows`)."""
+        return b"".join(part.payload if isinstance(part, EncodedRows)
+                        else encode_rows(part) for part in self._parts)
 
 
 class IDBClient(abc.ABC):
@@ -123,7 +206,7 @@ class IDBClient(abc.ABC):
         the group into ONE engine record — one apply, one CRC, and (in
         sync_writes mode) one fsync for the whole group."""
         for b in batches:
-            if b.ops:
+            if len(b):
                 self.write(b)
 
     def scan_all(self) -> "Iterator[Tuple[bytes, bytes, bytes]]":

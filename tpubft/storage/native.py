@@ -4,11 +4,12 @@ layer (/root/reference/storage/src/rocksdb_client.cpp), via ctypes."""
 from __future__ import annotations
 
 import ctypes
-from typing import Iterator, List, Optional, Sequence, Tuple
+from typing import FrozenSet, Iterator, List, Optional, Sequence, Tuple
 
 from tpubft.native.build import load
 from tpubft.storage.interfaces import (DEFAULT_FAMILY, IDBClient, StorageError,
-                                       WriteBatch, family_upper_bound, fkey)
+                                       WriteBatch, family_prefix,
+                                       family_upper_bound, fkey)
 
 _U8P = ctypes.POINTER(ctypes.c_uint8)
 
@@ -84,8 +85,8 @@ class NativeDB(IDBClient):
             raise StorageError(f"kvlog_open failed for {path}")
         self._compact_bytes = compact_bytes
         self._sync_writes = sync_writes
-        self._sync_prefixes: Tuple[bytes, ...] = () if sync_writes else \
-            tuple(bytes([len(f)]) + f for f in sync_families)
+        self._sync_prefixes: FrozenSet[bytes] = frozenset(
+            () if sync_writes else map(family_prefix, sync_families))
         # ctypes releases the GIL around C calls, and the execution lane
         # writes ledger/pages batches concurrently with the dispatcher's
         # metadata batches on the SAME handle. The C engine is not
@@ -124,16 +125,18 @@ class NativeDB(IDBClient):
                 self._lib.kvlog_free(val)
 
     def write(self, batch: WriteBatch) -> None:
+        self._apply(batch.encode(), batch.families)
+
+    def _apply(self, payload: bytes, families) -> None:
+        """One engine record of `payload` (rows in the wire encoding);
+        `families`: the family prefixes its rows carry."""
         self._handle()
-        payload = batch.encode()
         with self._write_mu:
             rc = self._lib.kvlog_apply(self._handle(), payload,
                                        len(payload))
             if rc != 0:
                 raise StorageError(f"kvlog_apply rc={rc}")
-            if self._sync_prefixes and any(
-                    k.startswith(self._sync_prefixes)
-                    for k, _ in batch.ops):
+            if not self._sync_prefixes.isdisjoint(families):
                 rc = self._lib.kvlog_sync(self._h)
                 if rc != 0:
                     raise StorageError(f"kvlog_sync rc={rc}")
@@ -149,17 +152,16 @@ class NativeDB(IDBClient):
 
     def write_group(self, batches) -> None:
         """Group-commit apply seam (tpubft/durability/): concatenate the
-        group's batches into ONE kvlog record — one payload encode, one
-        apply under the handle lock, one CRC (so the whole group is
-        atomic under torn-tail recovery), and in sync_writes mode one
-        fsync instead of one per batch. The consensus-metadata carve-out
-        applies to the union of the group's ops, exactly as if they had
-        been one batch."""
-        merged = WriteBatch()
-        for b in batches:
-            merged.ops.extend(b.ops)
-        if merged.ops:
-            self.write(merged)
+        group's batches into ONE kvlog record — one payload (a join of
+        the batches' encodings), one apply under the handle lock, one
+        CRC (so the whole group is atomic under torn-tail recovery), and
+        in sync_writes mode one fsync instead of one per batch. The
+        consensus-metadata carve-out applies to the union of the group's
+        families, exactly as if they had been one batch."""
+        batches = [b for b in batches if len(b)]
+        if batches:
+            self._apply(b"".join(b.encode() for b in batches),
+                        set().union(*(b.families for b in batches)))
 
     @property
     def syncs_on_write(self) -> bool:
