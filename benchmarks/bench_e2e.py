@@ -337,33 +337,18 @@ def run_config_processes(config: int, backend: str, secs: float,
 
 def smoke(secs: float = 2.0, clients: int = 2) -> dict:
     """Tier-1 shape (mirrors bench_st --smoke): order real traffic
-    through config 1 with the execution lane ON (speculative — the
-    default), the lane on with speculation OFF, and the legacy inline
-    path, so the ordering path — including the dispatcher↔executor
-    handoff and the speculative seal protocol — has a collection-time +
-    runtime guard in CI. Run it under TPUBFT_THREADCHECK=1 to arm the
-    lock-order checker across the handoff
-    (tests/test_bench_e2e_smoke.py does)."""
+    through config 1, so the ordering path — including the
+    dispatcher↔executor handoff and the group-commit seal — has a
+    collection-time + runtime guard in CI. Run it under
+    TPUBFT_THREADCHECK=1 to arm the lock-order checker across the
+    handoff (tests/test_bench_e2e_smoke.py does)."""
     from tpubft.utils.racecheck import get_watchdog
-    out = {}
-    for label, overrides in (
-            ("lane", {"execution_lane": True}),
-            ("nospec", {"execution_lane": True,
-                        "speculative_execution": False}),
-            ("nodur", {"execution_lane": True,
-                       "durability_pipeline": False}),
-            ("inline", {"execution_lane": False})):
-        # the optimistic-replies leg lives in smoke_optimistic() (its
-        # own tier-1 test) — not duplicated here
-        row = run_config(1, "cpu", secs, clients,
-                         extra_overrides=overrides)
-        out[label] = {"ok": row["ops"] > 0,
-                      "ops": row["ops"],
-                      "ops_per_sec": row["ops_per_sec"]}
-        if "opt_releases" in row:
-            out[label]["opt_releases"] = row["opt_releases"]
-    out["stall_reports"] = get_watchdog().stall_reports
-    return out
+    # the optimistic-replies leg lives in smoke_optimistic() (its own
+    # tier-1 test) — not duplicated here
+    row = run_config(1, "cpu", secs, clients)
+    return {"lane": {"ok": row["ops"] > 0, "ops": row["ops"],
+                     "ops_per_sec": row["ops_per_sec"]},
+            "stall_reports": get_watchdog().stall_reports}
 
 
 def smoke_optimistic(secs: float = 2.0, clients: int = 2) -> dict:
@@ -375,11 +360,9 @@ def smoke_optimistic(secs: float = 2.0, clients: int = 2) -> dict:
     guessing at throughput on a noisy host."""
     from tpubft.utils.racecheck import get_watchdog
     on = run_config(1, "cpu", secs, clients,
-                    extra_overrides={"execution_lane": True,
-                                     "optimistic_replies": True})
+                    extra_overrides={"optimistic_replies": True})
     off = run_config(1, "cpu", secs, clients,
-                     extra_overrides={"execution_lane": True,
-                                      "optimistic_replies": False})
+                     extra_overrides={"optimistic_replies": False})
     row = {
         "bench": "e2e-optimistic-smoke", "unit": "ops",
         "value": on["ops"],
@@ -424,11 +407,10 @@ def main() -> None:
     ap.add_argument("--override", action="append", default=[],
                     metavar="FIELD=VALUE",
                     help="extra ReplicaConfig override applied to every "
-                         "replica (repeatable) — e.g. execution_lane="
-                         "False or execution_max_accumulation=1 for the "
-                         "lane A/B rows")
+                         "replica (repeatable) — e.g. "
+                         "execution_max_accumulation=1")
     ap.add_argument("--smoke", action="store_true",
-                    help="tiny fixed shape for CI (lane on vs off)")
+                    help="tiny fixed shape for CI")
     ap.add_argument("--smoke-optimistic", action="store_true",
                     help="tiny fixed optimistic-replies A/B shape for "
                          "CI: one JSON row (degraded/probe_error "
@@ -437,13 +419,7 @@ def main() -> None:
                     help="A/B control leg: run with the optimistic "
                          "reply plane OFF (replies certificate-gated). "
                          "Without this flag the bench runs the plane ON "
-                         "— pair alternating on/off invocations like "
-                         "the durability rows")
-    ap.add_argument("--durability-off", action="store_true",
-                    help="A/B control leg: run with the group-commit "
-                         "durability pipeline OFF (per-run apply + "
-                         "immediate completion) — pair alternating "
-                         "on/off invocations like the PR 9 rows")
+                         "— pair alternating on/off invocations")
     ap.add_argument("--profile", action="store_true",
                     help="attach the flight recorder's per-slot stage "
                          "breakdown (adm_wait/dispatch/prepare/commit/"
@@ -462,8 +438,6 @@ def main() -> None:
         return
     from tpubft.utils.config import parse_config_overrides
     extra = parse_config_overrides(args.override)
-    if args.durability_off:
-        extra["durability_pipeline"] = False
     if args.optimistic_off:
         extra["optimistic_replies"] = False
     else:

@@ -1,8 +1,8 @@
 """Tier-1 wiring for benchmarks/bench_e2e.py (--smoke shape), mirroring
 test_bench_st_smoke: the ordering path — including the new
 dispatcher↔executor execution-lane handoff — gets a collection-time
-guard (the bench module must import) and a runtime guard (both the lane
-and the legacy inline path must order real traffic).
+guard (the bench module must import) and a runtime guard (the lane
+must order real traffic).
 
 TPUBFT_THREADCHECK=1 arms utils/racecheck across the run: every
 make_lock in the handoff (execution lane condition, blockchain staging,
@@ -26,14 +26,7 @@ def threadcheck(monkeypatch):
 def test_bench_e2e_smoke(threadcheck):
     from benchmarks.bench_e2e import smoke
     out = smoke(secs=2.0, clients=2)
-    # all four execution modes ordered real traffic: the speculative
-    # lane (default, group-commit durability on), the lane with
-    # speculation off, the lane with the durability pipeline off, and
-    # legacy inline
     assert out["lane"]["ok"], out
-    assert out["nospec"]["ok"], out
-    assert out["nodur"]["ok"], out
-    assert out["inline"]["ok"], out
     # racecheck: no dispatcher/executor stall was reported during the
     # run (lock-order inversions raise inside the run itself)
     assert out["stall_reports"] == 0, out

@@ -174,16 +174,14 @@ def _has_encoded_rows(wb) -> bool:
     return any(isinstance(p, EncodedRows) for p in wb._parts)
 
 
-@pytest.mark.parametrize("speculative", [False, True])
-def test_abort_of_a_run_with_encoded_rows_leaves_nothing_readable(
-        speculative):
+def test_abort_of_a_run_with_encoded_rows_leaves_nothing_readable():
     """A merkle block's walk stages its rows encoded. An aborted run's
     are gone from every view: the ledger's, a fresh tree's, the store's."""
     db = MemoryDB()
     bc = KeyValueBlockchain(db, use_device_hashing=False)
     bc.add_block(_merkle_block(b"kept"))
     before, root = _dump(db), bc.merkle_root("mk")
-    bc.begin_accumulation(speculative=speculative)
+    bc.begin_accumulation()
     for i in range(3):
         bc.add_block(_merkle_block(b"doomed-%d" % i))
     assert _has_encoded_rows(bc._accum.master)
@@ -239,3 +237,92 @@ def test_accumulation_extra_ops_ride_the_same_batch():
     extra.put(b"reply", b"bytes", b"respages")
     bc.end_accumulation(extra=extra)
     assert db.get(b"reply", b"respages") == b"bytes"
+
+
+# ---------------- an open run and the other threads ----------------
+
+def test_a_reader_on_another_thread_never_sees_a_run_torn():
+    """The run's overlay is the process's: a reader on another thread
+    (a read-only query on the dispatcher) may see an open run's blocks
+    ahead of their seal — they are committed slots — but never a torn
+    state. Once it has read a key it reads it ever after: through the
+    overlay, through the pending store the sealed run moves into, and
+    from the engine once the group has landed; and a head it has read
+    always names a block it can read."""
+    import threading
+    from tpubft.durability.pipeline import PendingStore
+    db = MemoryDB()
+    bc = KeyValueBlockchain(db, use_device_hashing=False)
+    store = PendingStore("t")
+    bc.attach_durability(store)
+    bc.add_block(_merkle_block(b"base"))
+    stop, torn = threading.Event(), []
+
+    def reader():
+        seen = False
+        while not stop.is_set():
+            head = bc.last_block_id
+            if head and bc.get_raw_block(head) is None:
+                torn.append(("head names no block", head))
+            hit = bc.get_latest("mk", b"in-the-run", cat_type=BLOCK_MERKLE)
+            if seen and hit is None:
+                torn.append(("a key read once was gone", head))
+            seen = seen or hit is not None
+
+    th = threading.Thread(target=reader, daemon=True)
+    th.start()
+    try:
+        for _round in range(20):
+            bc.begin_accumulation()
+            bc.add_block(_merkle_block(b"in-the-run", b"v%d" % _round))
+            bc.add_block(_merkle_block(b"other-%d" % _round))
+            bc.end_accumulation(defer=True)
+            run_no, batch, base = bc.take_deferred()
+            base.write(batch)                  # the io thread's apply
+            store.mark_applied(run_no)
+    finally:
+        stop.set()
+        th.join(10)
+    assert not th.is_alive() and not torn, torn[:3]
+    assert bc.last_block_id == 41 and store.empty
+    seq = KeyValueBlockchain(MemoryDB(), use_device_hashing=False)
+    seq.add_block(_merkle_block(b"base"))
+    for _round in range(20):
+        seq.add_block(_merkle_block(b"in-the-run", b"v%d" % _round))
+        seq.add_block(_merkle_block(b"other-%d" % _round))
+    assert bc.state_digest() == seq.state_digest()
+    assert _dump(db) == _dump(seq._db)
+
+
+def test_link_st_chain_waits_for_an_open_run_then_links():
+    """State transfer's link and the lane's run share the staged-read
+    redirect: a link that arrives while a run is open waits for the run
+    to end and then adopts what is staged after the new head."""
+    import threading
+    import time
+    source = KeyValueBlockchain(MemoryDB(), use_device_hashing=False)
+    for i in range(4):
+        source.add_block(_merkle_block(b"k%d" % i))
+    bc = KeyValueBlockchain(MemoryDB(), use_device_hashing=False)
+    bc.add_block(_merkle_block(b"k0"))
+    bc.add_raw_st_blocks({b: source.get_raw_block(b) for b in (2, 3, 4)})
+    opened, ended = threading.Event(), []
+
+    def run():
+        bc.begin_accumulation()
+        bc.add_block(_merkle_block(b"k1"))    # the run executes block 2
+        opened.set()
+        time.sleep(0.4)
+        ended.append(time.monotonic())
+        bc.end_accumulation()
+
+    th = threading.Thread(target=run, daemon=True)
+    th.start()
+    assert opened.wait(10)
+    assert bc.link_st_chain() == 4
+    assert ended and time.monotonic() >= ended[0], \
+        "the link did not wait for the open run"
+    th.join(10)
+    assert not th.is_alive()
+    assert bc.state_digest() == source.state_digest()
+    assert bc.merkle_root("mk") == source.merkle_root("mk")

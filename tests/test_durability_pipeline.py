@@ -5,8 +5,8 @@ apply-ordered removal, merged visibility through the blockchain's
 pending view incl. range scans), group formation (group_max cut,
 window expiry, flush), watermark monotonicity and reply gating (a held
 pipeline means NO reply, NO last_executed advance — release unblocks
-both), drain-barrier discipline, seal backpressure, on/off and
-group_max=1 ledger byte-equivalence, the `dur.group_fsync` crash drill
+both), drain-barrier discipline, seal backpressure, ledger
+byte-equivalence across admission shapes, the `dur.group_fsync` crash drill
 (exactly-once replay, `last_executed` monotone across the restart),
 and the autotuner seed write-back round trip (ROADMAP 8d)."""
 import json
@@ -484,7 +484,8 @@ def test_status_and_flight_surface(tmp_path):
 
 
 # ---------------------------------------------------------------------
-# on/off + group_max=1 ledger byte-equivalence
+# ledger byte-equivalence across admission shapes (the pipeline's own
+# shapes are held to a plain sequential apply in the lane's tests)
 # ---------------------------------------------------------------------
 
 def _run_workload(tmp_path, sub, n_writes=6, **overrides):
@@ -502,10 +503,8 @@ def _run_workload(tmp_path, sub, n_writes=6, **overrides):
                      cluster.handlers[0].blockchain.last_block_id
                      == n_writes)
         bc = cluster.handlers[0].blockchain
-        if overrides.get("durability_pipeline", True):
-            assert _wait(lambda: cluster.metric(
-                0, "counters", "dur_groups",
-                component="durability") > 0)
+        assert _wait(lambda: cluster.metric(
+            0, "counters", "dur_groups", component="durability") > 0)
         pages = cluster.replicas[0].res_pages
         ring = sorted((k, v) for k, v in pages.all_pages()
                       if k[2:].startswith((b"clientreplies", b"clients")))
@@ -515,18 +514,6 @@ def _run_workload(tmp_path, sub, n_writes=6, **overrides):
             "blocks": [bc.get_raw_block(b)
                        for b in range(1, n_writes + 1)],
         }
-
-
-def test_pipeline_on_off_ledger_equivalence(tmp_path):
-    """Same sequential workload, pipeline on (default group shape) vs
-    off: byte-identical ledger blocks, state digest, and reply-ring /
-    at-most-once pages — durability batching changes WHEN bytes land,
-    never WHICH bytes."""
-    on = _run_workload(tmp_path, "on", durability_pipeline=True)
-    off = _run_workload(tmp_path, "off", durability_pipeline=False)
-    assert on["state_digest"] == off["state_digest"]
-    assert on["reply_pages"] and on["reply_pages"] == off["reply_pages"]
-    assert on["blocks"] == off["blocks"]
 
 
 def test_sharded_admission_ledger_equivalence(tmp_path):
@@ -540,18 +527,6 @@ def test_sharded_admission_ledger_equivalence(tmp_path):
     assert on["state_digest"] == off["state_digest"]
     assert on["blocks"] == off["blocks"]
     assert on["reply_pages"] and on["reply_pages"] == off["reply_pages"]
-
-
-def test_group_max_one_degenerates_to_per_run_path(tmp_path):
-    """group_max=1 with a zero window = one apply + one fsync per run —
-    the current per-run durable path's shape; ledger bytes identical to
-    the pipeline-off control."""
-    one = _run_workload(tmp_path, "one", durability_pipeline=True,
-                        durability_group_max=1, durability_window_us=0)
-    off = _run_workload(tmp_path, "off2", durability_pipeline=False)
-    assert one["state_digest"] == off["state_digest"]
-    assert one["blocks"] == off["blocks"]
-    assert one["reply_pages"] == off["reply_pages"]
 
 
 # ---------------------------------------------------------------------
@@ -596,8 +571,7 @@ def test_crash_restart_at_group_fsync_exactly_once(tmp_path):
             cp.release_parked()
         # ---- standalone recovery from the victim's durable state ----
         cfg = ReplicaConfig(replica_id=victim, f_val=1,
-                            num_of_client_proxies=2,
-                            execution_lane=False)
+                            num_of_client_proxies=2)
         recovered = Replica(
             cfg, keys.for_node(victim), LoopbackBus().create(victim),
             skvbc.SkvbcHandler(

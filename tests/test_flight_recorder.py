@@ -117,7 +117,7 @@ def test_fold_stage_math():
     stages = SlotTracker.fold(slot)
     assert stages == {"adm_wait": 2.0, "dispatch": 1.0, "prepare": 7.0,
                       "commit": 5.0, "exec": 10.0, "reply": 1.0,
-                      "spec_overlap": 0.0, "cert_lag": 0.0,
+                      "cert_lag": 0.0,
                       # no lane start / queue stamp / group on record:
                       # exec reads as all service, the rest as nothing
                       "order_wait": 0.0, "exec_wait": 0.0,
@@ -130,37 +130,6 @@ def test_fold_stage_math():
     assert stages["adm_wait"] == 0.0 and stages["dispatch"] == 0.0
     assert stages["prepare"] == 0.0 and stages["commit"] == 4.0
     assert stages["exec"] == 1.0 and stages["reply"] == 0.5
-    assert stages["spec_overlap"] == 0.0
-    # speculation: spec_overlap = spec enqueue -> commit quorum, but
-    # ONLY when the run sealed; an unsealed record reclaims nothing
-    spec = dict(fast, spec_enq=t0 + 1_000_000,
-                spec_seal=t0 + 4_500_000)
-    stages = SlotTracker.fold(spec)
-    assert stages["spec_overlap"] == 3.0
-    assert stages["commit"] == 4.0          # overlay, not a partition
-    unsealed = dict(fast, spec_enq=t0 + 1_000_000)
-    assert SlotTracker.fold(unsealed)["spec_overlap"] == 0.0
-
-
-def test_spec_abort_clears_overlap():
-    """EV_SPEC_ABORT wipes the slot's speculative anchors: a slot that
-    speculated, aborted, and re-executed post-commit folds with
-    spec_overlap 0 (the combine window was NOT reclaimed)."""
-    flight.reset()
-    tr = flight.slot_tracker()
-    t0 = 1_000_000_000
-    tr.on_event(7, flight.EV_PP_ACCEPT, 5, 0, 0, t0)
-    tr.on_event(7, flight.EV_SPEC_ENQ, 5, 0, 0, t0 + 1_000_000)
-    tr.on_event(7, flight.EV_SPEC_ABORT, 5, 0, 0, t0 + 2_000_000)
-    tr.on_event(7, flight.EV_COMMITTED, 5, 0, 0, t0 + 8_000_000)
-    tr.on_event(7, flight.EV_EXEC_APPLY, 5, 0, 1, t0 + 9_000_000)
-    tr.on_event(7, flight.EV_REPLY, 5, 0, 0, t0 + 9_500_000)
-    rec = tr.recent(rid=7)[-1]
-    assert rec["seq"] == 5 and rec["spec"] is False
-    assert rec["stages_ms"]["spec_overlap"] == 0.0
-    # an abort for an already-folded (or unknown) slot is ignored
-    tr.on_event(7, flight.EV_SPEC_ABORT, 5, 0, 0, t0 + 10_000_000)
-    assert tr.summary(rid=7)["completed"] == 1
 
 
 def test_slot_tracker_folds_recorded_lifecycle():
@@ -251,21 +220,18 @@ def test_order_wait_folds_from_pp_create_on_the_primary_only():
     # committed at 5, the lane reached it at 45, applied at 50
     ("normal", [(flight.EV_COMMITTED, 0, 5), (flight.EV_EXEC_START, 4, 45),
                 (flight.EV_EXEC_APPLY, 4, 50)], 40.0, 5.0),
-    # speculation ran ahead of the commit: no wait, and the run is what
-    # was left of it after the commit
-    ("speculated_ahead", [(flight.EV_SPEC_ENQ, 0, 1),
-                          (flight.EV_EXEC_START, 1, 2),
-                          (flight.EV_COMMITTED, 0, 5),
-                          (flight.EV_EXEC_APPLY, 1, 6),
-                          (flight.EV_SPEC_SEAL, 1, 6)], 0.0, 1.0),
-    # the staging was discarded: the start that counts is the
-    # re-execution's, after the commit
-    ("aborted_speculation", [(flight.EV_SPEC_ENQ, 0, 1),
-                             (flight.EV_EXEC_START, 1, 2),
-                             (flight.EV_SPEC_ABORT, 0, 3),
-                             (flight.EV_COMMITTED, 0, 5),
-                             (flight.EV_EXEC_START, 2, 25),
-                             (flight.EV_EXEC_APPLY, 2, 30)], 20.0, 5.0),
+    # released on the structural certificate (optimistic replies): the
+    # lane began ahead of the verified commit — no wait, and the run is
+    # what was left of it after the commit
+    ("released_ahead", [(flight.EV_EXEC_START, 1, 2),
+                        (flight.EV_COMMITTED, 0, 5),
+                        (flight.EV_EXEC_APPLY, 1, 6)], 0.0, 1.0),
+    # a run that failed and was retried: the first start stands, so the
+    # failed attempt and the back-off count as the lane's run
+    ("retried_run", [(flight.EV_COMMITTED, 0, 5),
+                     (flight.EV_EXEC_START, 2, 10),
+                     (flight.EV_EXEC_START, 2, 25),
+                     (flight.EV_EXEC_APPLY, 2, 30)], 5.0, 20.0),
     # no lane start on record: all of exec is service
     ("no_start", [(flight.EV_COMMITTED, 0, 5),
                   (flight.EV_EXEC_APPLY, 1, 30)], 0.0, 25.0),

@@ -97,6 +97,54 @@ def test_config():
     assert c3.n_val == 9 and c3.fast_path_threshold_quorum == 8
 
 
+def test_config_declares_each_field_once_and_something_reads_it():
+    """A dataclass takes a second declaration of a name in silence (the
+    last default wins), and a field nothing reads is an option that
+    selects nothing: both were in the tree once."""
+    import ast
+    import dataclasses
+    import os
+    import re
+    from tpubft.utils import config
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    tree = ast.parse(open(config.__file__, encoding="utf-8").read())
+    cls = next(n for n in tree.body
+               if isinstance(n, ast.ClassDef) and n.name == "ReplicaConfig")
+    declared = [n.target.id for n in cls.body
+                if isinstance(n, ast.AnnAssign)]
+    assert sorted(declared) == sorted(set(declared))
+    assert declared == [f.name for f in
+                        dataclasses.fields(config.ReplicaConfig)]
+    sources = []
+    for top in ("tpubft", "cellbench", "tools", "benchmarks"):
+        for d, _dirs, files in os.walk(os.path.join(root, top)):
+            sources += [os.path.join(d, f) for f in files
+                        if f.endswith(".py")]
+    sources.append(os.path.join(root, "chip_smoke.py"))
+    text = "\n".join(open(f, encoding="utf-8").read() for f in sources
+                     if os.path.abspath(f) != os.path.abspath(
+                         config.__file__))
+    words = set(re.findall(r"[A-Za-z_][A-Za-z_0-9]*", text))
+    assert [n for n in declared if n not in words] == []
+
+
+def test_config_overrides_are_coerced_and_unknown_names_refused():
+    from tpubft.utils.config import parse_config_overrides
+    assert parse_config_overrides(
+        ["execution_max_accumulation=1", "optimistic_replies=true",
+         "threshold_scheme=threshold-bls", "autotune_enabled=0"]) == {
+        "execution_max_accumulation": 1, "optimistic_replies": True,
+        "threshold_scheme": "threshold-bls", "autotune_enabled": False}
+    assert parse_config_overrides(None) == {}
+    for bad in ("no_such_field=False",
+                "f_val=2",                    # topology: its own flag
+                "view_change_timer_ms"):      # no value
+        with pytest.raises(SystemExit):
+            parse_config_overrides([bad])
+    with pytest.raises(ValueError):
+        parse_config_overrides(["concurrency_level=many"])
+
+
 def test_i64_range_checked():
     from dataclasses import dataclass
 

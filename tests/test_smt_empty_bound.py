@@ -2,9 +2,10 @@
 not provably empty. The golden model is the walk it replaced, kept here:
 the same ascent with every sibling read. Roots after every block and the
 full set of stored rows (live nodes, leaves, both archive families) must
-be equal — on a bare tree, and through `KeyValueBlockchain` inside plain,
-aborted and speculative accumulations, where the bound is taken through
-the staged view (block N+1 of a run sees block N's path).
+be equal — on a bare tree, and through `KeyValueBlockchain` inside plain
+and aborted accumulations, on the thread that built the ledger and on
+another (the lane's), where the bound is taken through the staged view
+(block N+1 of a run sees block N's path).
 
 A batch of fewer than 192 changed leaves takes the native walk, whose rows
 arrive encoded: its payload is held, byte for byte, to the golden walk's
@@ -376,28 +377,29 @@ def run_aborted_then_accumulated(bc, blocks):
     run_accumulated(bc, blocks)
 
 
-def run_speculative(bc, blocks):
-    def spec():
-        bc.begin_accumulation(speculative=True)
-        run_plain(bc, blocks)
-        bc.end_accumulation()
-    in_thread(spec)
+def run_on_the_lane_s_thread(bc, blocks):
+    """As a replica runs it: the ledger is built on one thread and its
+    runs are staged and sealed on another."""
+    in_thread(lambda: run_accumulated(bc, blocks))
 
 
-def run_speculative_aborted_then_plain(bc, blocks):
-    def spec():
-        bc.begin_accumulation(speculative=True)
+def run_aborted_on_the_lane_then_replayed(bc, blocks):
+    """A run that fails on the lane's thread after staging the blocks
+    in another order, then the same blocks one at a time on this one, as
+    the restore replay appends them."""
+    def doomed():
+        bc.begin_accumulation()
         run_plain(bc, blocks[::-1])
         bc.abort_accumulation()
-    in_thread(spec)
+    in_thread(doomed)
     run_plain(bc, blocks)
 
 
 MODES = {"plain": run_plain, "accumulated": run_accumulated,
          "aborted_then_accumulated": run_aborted_then_accumulated,
-         "speculative": run_speculative,
-         "speculative_aborted_then_plain":
-             run_speculative_aborted_then_plain}
+         "on_the_lane_s_thread": run_on_the_lane_s_thread,
+         "aborted_on_the_lane_then_replayed":
+             run_aborted_on_the_lane_then_replayed}
 
 
 @pytest.mark.parametrize("mode", sorted(MODES))
@@ -445,31 +447,31 @@ def run_deferred_two_pending(bc, blocks, store):
         store.mark_applied(run_no)
 
 
-def run_speculative_deferred(bc, blocks, store):
-    def spec():
-        bc.begin_accumulation(speculative=True)
-        run_plain(bc, blocks)
-        bc.end_accumulation(defer=True)
-    in_thread(spec)
-    run_no, batch, base = bc.take_deferred()
+def run_sealed_on_the_lane_applied_here(bc, blocks, store):
+    """The lane's thread seals the run, another (the io thread's part)
+    hands the batch to the engine."""
+    sealed = []
+    in_thread(lambda: sealed.append(run_deferred(bc, blocks)))
+    run_no, batch, base = sealed[0]
     base.write_group([batch])
     store.mark_applied(run_no)
 
 
 ENGINE_MODES = {
     "accumulated": lambda bc, blocks, store: run_accumulated(bc, blocks),
-    "speculative": lambda bc, blocks, store: run_speculative(bc, blocks),
+    "on_the_lane_s_thread":
+        lambda bc, blocks, store: run_on_the_lane_s_thread(bc, blocks),
     "deferred": run_deferred_applied,
     "deferred_two_pending": run_deferred_two_pending,
-    "speculative_deferred": run_speculative_deferred}
+    "sealed_on_the_lane_applied_here": run_sealed_on_the_lane_applied_here}
 
 
 @pytest.mark.parametrize("mode", sorted(ENGINE_MODES))
 def test_the_engine_receives_the_full_read_walk_s_payload(mode, tmp_path,
                                                           monkeypatch):
     """What `kvlog_apply` is handed for a ledger's runs — under a staged
-    accumulation, a pending store and a speculative overlay — is the
-    payload of the golden ledger's rows, put one at a time."""
+    accumulation and a pending store, on one thread and across two — is
+    the payload of the golden ledger's rows, put one at a time."""
     applied = {}
     real = NativeDB._apply
 

@@ -74,7 +74,7 @@ def fold_slots(dump: Dict) -> Dict[Tuple[int, int], Dict]:
     for _t, rid, (t_ns, code, seq, view, arg) in events:
         if code == flight.EV_DUR_GROUP:
             tracker.stamp_durable(slots.values(), rid, seq, t_ns)
-        elif code in tracker._FIELD or code == flight.EV_SPEC_ABORT:
+        elif code in tracker._FIELD:
             slot = slots.setdefault((rid, seq),
                                     {"rid": rid, "seq": seq, "view": view})
             tracker.stamp(slot, code, arg, t_ns)
@@ -123,9 +123,8 @@ def timeline(dumps: List[Dict], seq_filter: Optional[int] = None,
             t0 = ""
             if ts and base_epoch is not None:
                 t0 = f"{_epoch_of(dump, min(ts)) - base_epoch:+.3f}s"
-            # spec_overlap is an OVERLAY of commit (it ran concurrently)
-            # — summing it would overstate the slot's wall clock and
-            # disagree with the recorded total_ms
+            # the overlays and sub-stages account for time inside the
+            # pipeline stages: summing them would overstate the slot
             total = sum(stages[s] for s in flight.PIPELINE_STAGES)
             out.append(
                 f"{seq:>6} {label:<28} {t0:>10} "

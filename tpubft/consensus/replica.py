@@ -496,17 +496,6 @@ class Replica(IReceiver):
         self.m_exec_runs = self.metrics.register_counter("exec_runs")
         self.m_exec_run_slots = self.metrics.register_counter(
             "exec_run_slots")
-        # speculative execution: sealed runs (executed ahead of their
-        # commit certificate and made durable at commit), abort events
-        # (view change / barrier / digest surprise — the overlay was
-        # discarded and the slots re-executed post-commit), and the last
-        # sealed run's reclaimed combine-window overlap
-        self.m_exec_spec_runs = self.metrics.register_counter(
-            "exec_spec_runs")
-        self.m_exec_spec_aborts = self.metrics.register_counter(
-            "exec_spec_aborts")
-        self.m_exec_spec_overlap = self.metrics.register_gauge(
-            "exec_spec_overlap_ms")
         # optimistic reply plane: slots released to the client-visible
         # path on a structurally-valid commit cert before its pairing
         # verify landed, and deferred verifies that came back BAD on a
@@ -638,9 +627,6 @@ class Replica(IReceiver):
             f"replica{self.id}.exec_run_len")
         self._h_exec_commit_ms = self._diag.histogram(
             f"replica{self.id}.exec_commit_ms")
-        # per-sealed-run reclaimed overlap (ms → recorded in µs)
-        self._h_spec_overlap = self._diag.histogram(
-            f"replica{self.id}.exec_spec_overlap_ms")
         # slots per fused combine flush (1 = no cross-slot amortization)
         self._h_combine_batch = self._diag.histogram(
             f"replica{self.id}.combine_batch_size", unit="slots")
@@ -662,15 +648,9 @@ class Replica(IReceiver):
         from tpubft.testing.slowdown import get_slowdown_manager
         self._slowdown = get_slowdown_manager()
 
-        # --- execution lane (post-commit pipelining off the dispatcher;
-        # reference: post-execution separation + block accumulation) ---
-        self.exec_lane = None
         # highest seq handed to the lane (or executed inline via the
         # lane's barrier path); dispatcher-thread only
         self._exec_enqueued = self.last_executed
-        # speculatively-submitted slots whose commit certificate has not
-        # confirmed yet, in seq order; dispatcher-thread only
-        self._spec_inflight: List[int] = []
         # --- optimistic reply plane (ISSUE 18 / ROADMAP item 4) ---
         # replies go out on a STRUCTURALLY-valid commit cert while the
         # pairing verify runs behind; requires async verification (the
@@ -686,31 +666,21 @@ class Replica(IReceiver):
         # the persisted last_executed watermark is clamped to this, so a
         # restart never resumes past evidence that was still in flight
         self._verified_upto = self.last_executed
-        # speculation needs a rollback substrate: the lane, an
-        # accumulation-capable ledger behind the handler (handlers
-        # without one — e.g. the counter app — apply irreversibly during
-        # execution), and the time service off (its agreed-time page
-        # writes bypass the staged pages batch)
-        _bc = getattr(handler, "blockchain", None)
-        self._spec_enabled = bool(
-            cfg.speculative_execution and cfg.execution_lane
-            and not cfg.time_service_enabled
-            and _bc is not None and hasattr(_bc, "begin_accumulation"))
-        self.durability = None
-        if cfg.execution_lane:
-            from tpubft.consensus.execution import ExecutionLane
-            self.exec_lane = ExecutionLane(
-                self, cfg.execution_max_accumulation,
-                cfg.checkpoint_window_size)
-            self.dispatcher.register_internal("exec_done",
-                                              self._apply_exec_runs)
-            # stall threshold = the drain barrier's budget: a lane that
-            # would time out a view-change/ST drain is reported by the
-            # watchdog with stacks + depths, not discovered by a human
-            self.health.register_probe(
-                "exec_lane", cfg.execution_drain_timeout_ms / 1e3,
-                busy_fn=lambda: not self.exec_lane.idle(),
-                detail_fn=lambda: {"depth": self.exec_lane.depth})
+        # --- execution lane (post-commit pipelining off the dispatcher;
+        # reference: post-execution separation + block accumulation) ---
+        from tpubft.consensus.execution import ExecutionLane
+        self.exec_lane = ExecutionLane(
+            self, cfg.execution_max_accumulation,
+            cfg.checkpoint_window_size)
+        self.dispatcher.register_internal("exec_done",
+                                          self._apply_exec_runs)
+        # stall threshold = the drain barrier's budget: a lane that
+        # would time out a view-change/ST drain is reported by the
+        # watchdog with stacks + depths, not discovered by a human
+        self.health.register_probe(
+            "exec_lane", cfg.execution_drain_timeout_ms / 1e3,
+            busy_fn=lambda: not self.exec_lane.idle(),
+            detail_fn=lambda: {"depth": self.exec_lane.depth})
         # --- group-commit durability pipeline (tpubft/durability/):
         # the lane seals runs, a dedicated io thread group-commits
         # them across runs (one concatenated apply + one fsync per
@@ -720,30 +690,29 @@ class Replica(IReceiver):
         # the pending-read overlay so sealed-but-unapplied runs stay
         # observable process-wide; reserved pages sharing the ledger
         # DB rebind onto the same view so folded reply pages are too.
-        if cfg.execution_lane and cfg.durability_pipeline:
-            from tpubft.durability import DurabilityPipeline
-            self.durability = DurabilityPipeline(
-                self, group_max=cfg.durability_group_max,
-                window_us=cfg.durability_window_us)
-            _bc = getattr(handler, "blockchain", None)
-            if _bc is not None and hasattr(_bc, "attach_durability"):
-                view = _bc.attach_durability(
-                    self.durability.pending,
-                    drain_fn=self.durability.drain)
-                if self.res_pages.shares_db(view.base):
-                    self.res_pages.rebind(view)
-            # watermark-lag stall probe: busy while sealed runs await
-            # their group fsync; a disk that stops landing groups is
-            # reported with the same budget as the lane's drain barrier
-            self.health.register_probe(
-                "durability", cfg.execution_drain_timeout_ms / 1e3,
-                busy_fn=lambda: self.durability.lag > 0,
-                detail_fn=lambda: {"lag": self.durability.lag,
-                                   "wm": self.durability.watermark})
-            self._diag.register_status(f"replica{self.id}.durability",
-                                       self.durability.render)
-            self._diag.register_status("durability",
-                                       self.durability.render)
+        from tpubft.durability import DurabilityPipeline
+        self.durability = DurabilityPipeline(
+            self, group_max=cfg.durability_group_max,
+            window_us=cfg.durability_window_us)
+        _bc = getattr(handler, "blockchain", None)
+        if _bc is not None and hasattr(_bc, "attach_durability"):
+            view = _bc.attach_durability(
+                self.durability.pending,
+                drain_fn=self.durability.drain)
+            if self.res_pages.shares_db(view.base):
+                self.res_pages.rebind(view)
+        # watermark-lag stall probe: busy while sealed runs await
+        # their group fsync; a disk that stops landing groups is
+        # reported with the same budget as the lane's drain barrier
+        self.health.register_probe(
+            "durability", cfg.execution_drain_timeout_ms / 1e3,
+            busy_fn=lambda: self.durability.lag > 0,
+            detail_fn=lambda: {"lag": self.durability.lag,
+                               "wm": self.durability.watermark})
+        self._diag.register_status(f"replica{self.id}.durability",
+                                   self.durability.render)
+        self._diag.register_status("durability",
+                                   self.durability.render)
 
         # --- closed-loop autotuner (tpubft/tuning/): drives the perf
         # knobs above (flush windows, batch caps, accumulation depth,
@@ -974,10 +943,8 @@ class Replica(IReceiver):
                                           self._resume_view_change)
         if self.in_view_change and (self.pending_view or 0) > self.view:
             self.incoming.push_internal("resume_vc", None)
-        if self.durability is not None:
-            self.durability.start()     # before the lane: seals flow in
-        if self.exec_lane is not None:
-            self.exec_lane.start()
+        self.durability.start()         # before the lane: seals flow in
+        self.exec_lane.start()
         if self.admission is not None:
             self.admission.start()
         if self.thin_replica is not None:
@@ -1000,15 +967,13 @@ class Replica(IReceiver):
         with mdc_scope(r=self.id):
             log.info("replica stopping: last_executed=%d last_stable=%d",
                      self.last_executed, self.last_stable)
-        if self.exec_lane is not None:
-            # no drain: pending slots are committed state that recovery
-            # replays — stop is crash-equivalent for the lane
-            self.exec_lane.stop()
-        if self.durability is not None:
-            # after the lane (its last seal must be accepted): a clean
-            # stop flushes sealed runs to disk — whatever a wedged disk
-            # leaves behind is the crash case recovery already replays
-            self.durability.stop()
+        # no drain: pending slots are committed state that recovery
+        # replays — stop is crash-equivalent for the lane
+        self.exec_lane.stop()
+        # after the lane (its last seal must be accepted): a clean
+        # stop flushes sealed runs to disk — whatever a wedged disk
+        # leaves behind is the crash case recovery already replays
+        self.durability.stop()
         if self.admission is not None:
             self.admission.stop()
         if self.thin_replica is not None:
@@ -1744,12 +1709,6 @@ class Replica(IReceiver):
             self._send_partial_commit_proof(info)
         self._drain_early_shares(info)
         self._drain_early_certs(info)
-        # speculation starts HERE on every path (ISSUE 18a): the
-        # combine window opens at acceptance and the overlay covers the
-        # whole prepare+commit round. After the early-evidence drains: a
-        # slot that just committed from buffered certs takes the normal
-        # path instead.
-        self._pump_speculation()
 
     # ------------------------------------------------------------------
     # slow path: shares → collectors (ReplicaImp.cpp:1373,1399)
@@ -2391,10 +2350,6 @@ class Replica(IReceiver):
         with self._tran() as st:
             st.seq(msg.seq_num).prepare_full = msg.pack()
         self._send_commit_partial(info)
-        # speculation normally started at PP acceptance (ISSUE 18a);
-        # this re-pump catches slots that could not speculate then
-        # (e.g. ordered behind a barrier batch that has since drained)
-        self._pump_speculation()
 
     def _on_commit_full(self, msg: m.CommitFullMsg) -> None:
         self._handle_full_cert(msg, "commit")
@@ -2505,17 +2460,16 @@ class Replica(IReceiver):
     # execution (ReplicaImp.cpp:5720,5364 + the execution lane)
     # ------------------------------------------------------------------
     def _execute_committed(self) -> None:
-        """Committed slots became executable. With the execution lane the
-        dispatcher only ENQUEUES them (execution + the coalesced commit
-        happen on the lane thread); the legacy inline path runs when the
-        lane is off — and during __init__'s restore replay, which happens
-        before any thread besides the caller exists."""
-        if self.exec_lane is not None and self._running:
-            self._pump_execution_lane()
+        """Committed slots became executable: the dispatcher only
+        ENQUEUES them (execution + the coalesced commit happen on the
+        lane thread). Before start() — __init__'s restore replay, when
+        no thread besides the caller exists — they execute here."""
+        if self._running:
+            self._pump_exec_lane()
         else:
-            self._execute_committed_inline()
+            self._replay_committed()
 
-    def _execute_committed_inline(self) -> None:
+    def _replay_committed(self) -> None:
         while True:
             nxt = self.last_executed + 1
             if not self.window.in_window(nxt):
@@ -2531,9 +2485,9 @@ class Replica(IReceiver):
             self._execute_one_slot(nxt, info)
 
     def _execute_one_slot(self, nxt: int, info: SeqNumInfo) -> None:
-        """Inline per-slot execution + apply (the pre-lane path, kept for
-        execution_lane=off, restore replay, and lane barrier batches —
-        INTERNAL/RECONFIG requests mutate dispatcher-owned subsystems)."""
+        """Per-slot execution + apply on the calling thread: the restore
+        replay, and the lane's barrier batches (INTERNAL/RECONFIG
+        requests mutate dispatcher-owned subsystems)."""
         flight.record(flight.EV_EXEC_START, seq=nxt, arg=1)
         for req in info.pre_prepare.client_requests():
             # at-most-once: a request already executed for this client
@@ -2565,8 +2519,8 @@ class Replica(IReceiver):
         self._last_progress = time.monotonic()
         with self._tran() as st:
             st.last_executed_seq = nxt
-        # inline path: apply and reply complete together on the
-        # dispatcher — both slot-stage anchors land here
+        # apply and reply complete together on this thread — both
+        # slot-stage anchors land here
         flight.record(flight.EV_EXEC_APPLY, seq=nxt, arg=1)
         flight.record(flight.EV_REPLY, seq=nxt)
         if nxt % self.cfg.checkpoint_window_size == 0:
@@ -2635,35 +2589,9 @@ class Replica(IReceiver):
         return any(r.flags & (m.RequestFlag.INTERNAL
                               | m.RequestFlag.RECONFIG) for r in reqs)
 
-    def _pump_execution_lane(self) -> None:
+    def _pump_exec_lane(self) -> None:
         """Hand every next consecutive committed slot to the lane (or
-        execute barrier batches inline after draining it). Speculatively
-        submitted slots whose commit just landed are CONFIRMED instead
-        of resubmitted — the lane seals their already-executed run."""
-        # phase 0: confirm commits for speculative submissions, strictly
-        # in seq order (the lane's seal requires the whole run)
-        while self._spec_inflight:
-            nxt = self._spec_inflight[0]
-            info = self.window.peek(nxt)
-            if info is None or info.pre_prepare is None:
-                # the slot vanished without a view-change abort —
-                # defensive: discard the speculation and fall through to
-                # the committed path
-                self._abort_speculation("window-moved")
-                break
-            if not info.committed \
-                    and not (self._opt_replies and info.opt_committed):
-                break
-            if self.exec_lane.confirm(nxt, info.pre_prepare.digest()):
-                self._spec_inflight.pop(0)
-                info.spec_submitted = False
-                info.exec_submitted = True    # now normal lane work
-            else:
-                # speculated digest is not the committed one (or the
-                # lane lost the slot): discard everything speculative;
-                # the loop below resubmits the committed slots in order
-                self._abort_speculation("digest-mismatch")
-                break
+        execute barrier batches inline after draining it)."""
         while True:
             nxt = max(self._exec_enqueued, self.last_executed) + 1
             if not self.window.in_window(nxt):
@@ -2675,8 +2603,7 @@ class Replica(IReceiver):
                 self._maybe_announce_restart_ready()
                 break
             info = self.window.peek(nxt)
-            if info is None or info.executed \
-                    or info.exec_submitted or info.spec_submitted:
+            if info is None or info.executed or info.exec_submitted:
                 break
             if not info.committed \
                     and not (self._opt_replies and info.opt_committed):
@@ -2686,12 +2613,6 @@ class Replica(IReceiver):
                 # dispatcher-owned subsystems irreversibly: they wait
                 # for the VERIFIED commit even under optimistic replies
                 if not info.committed:
-                    break
-                if self._spec_inflight:
-                    # speculative slots ahead of the barrier are still
-                    # awaiting their commits: the barrier cannot run yet
-                    # anyway (last_executed lags) — draining now would
-                    # only waste their speculation
                     break
                 if not self._drain_exec_lane():
                     break               # lane stuck; retried on next event
@@ -2710,75 +2631,6 @@ class Replica(IReceiver):
                 info.exec_submitted = False
                 raise
             self._exec_enqueued = nxt
-        # newly-consecutive prepared/accepted slots may speculate now
-        self._pump_speculation()
-
-    def _pump_speculation(self) -> None:
-        """Hand every next consecutive NOT-yet-committed slot to the
-        lane as SPECULATIVE at PrePrepare ACCEPTANCE — on every path
-        (ISSUE 18a; previously the slow path waited for its
-        prepare-quorum). The overlay now covers the whole
-        prepare+commit window; abort safety is unchanged (the overlay
-        is never durable and the seal still requires the committed
-        digest to confirm). Replies and last_executed stay strictly
-        post-commit — post-release under optimistic replies, where the
-        structural cert + verified prepare quorum stand in. Barrier
-        batches (INTERNAL/RECONFIG) never speculate."""
-        if not self._spec_enabled or self.exec_lane is None \
-                or not self._running or self.in_view_change:
-            return
-        while True:
-            nxt = max(self._exec_enqueued, self.last_executed) + 1
-            if not self.window.in_window(nxt) \
-                    or self.control.blocks_ordering(nxt):
-                return
-            info = self.window.peek(nxt)
-            if info is None or info.pre_prepare is None or info.executed \
-                    or info.committed or info.exec_submitted \
-                    or info.spec_submitted:
-                return
-            pp = info.pre_prepare
-            if not info.prepared \
-                    and pp.first_path == int(m.CommitPath.SLOW):
-                return              # slow path: wait for prepare-quorum
-            if self._batch_needs_dispatcher(pp):
-                return
-            info.spec_submitted = True
-            flight.record(flight.EV_SPEC_ENQ, seq=nxt, view=self.view)
-            try:
-                self.exec_lane.submit(nxt, pp, speculative=True)
-            except BaseException:
-                info.spec_submitted = False
-                raise
-            self._spec_inflight.append(nxt)
-            self._exec_enqueued = nxt
-
-    def _abort_speculation(self, cause: str) -> None:
-        """Discard all speculative work (dispatcher thread): the lane
-        aborts its open overlay, pending speculative entries (and any
-        committed entries queued BEHIND them — order must hold) come
-        back, and the submission bookkeeping rolls back so the normal
-        committed path re-executes each slot from its committed
-        PrePrepare once the certificate is in hand."""
-        if self.exec_lane is None:
-            return
-        if not self._spec_inflight and not self.exec_lane.speculating:
-            return
-        removed = set(self.exec_lane.abort_speculation())
-        removed.update(self._spec_inflight)
-        self._spec_inflight = []
-        if not removed:
-            return
-        self.m_exec_spec_aborts.inc()
-        log.info("speculation aborted (%s): slots %s re-execute from "
-                 "their committed bodies", cause, sorted(removed))
-        for seq in sorted(removed):
-            flight.record(flight.EV_SPEC_ABORT, seq=seq)
-            info = self.window.peek(seq)
-            if info is not None and not info.executed:
-                info.exec_submitted = False
-                info.spec_submitted = False
-        self._exec_enqueued = min(self._exec_enqueued, min(removed) - 1)
 
     def _drain_exec_lane(self, timeout: Optional[float] = None) -> bool:
         """Dispatcher-side barrier: wait until the lane applied every
@@ -2789,13 +2641,6 @@ class Replica(IReceiver):
         ReplicaConfig.execution_drain_timeout_ms — the same threshold
         the health watchdog holds the lane's progress to, so a drain
         that would time out is independently reported as a stall."""
-        if self.exec_lane is None:
-            return True
-        # speculative work cannot drain (it waits on commit certificates
-        # only this thread can confirm, and the barrier callers are
-        # about to invalidate it anyway): abort it first — the slots
-        # re-execute from their committed bodies through the normal path
-        self._abort_speculation("drain")
         if timeout is None:
             timeout = self.cfg.execution_drain_timeout_ms / 1e3
         deadline = time.monotonic() + timeout
@@ -2803,7 +2648,7 @@ class Replica(IReceiver):
         if not ok:
             log.warning("execution lane failed to drain in %.0fs "
                         "(depth=%d)", timeout, self.exec_lane.depth)
-        if ok and self.durability is not None:
+        if ok:
             # the lane drained = every run SEALED; the barrier callers
             # need them DURABLE and integrated (last_executed current,
             # pending overlay empty) before wiping the window / writing
@@ -2820,8 +2665,7 @@ class Replica(IReceiver):
         # window / adopt transferred state); newly-unblocked slots are
         # picked up by the next commit/apply event
         self._apply_exec_runs(repump=False)
-        return ok and self.exec_lane.idle() \
-            and (self.durability is None or self.durability.idle())
+        return ok and self.exec_lane.idle() and self.durability.idle()
 
     def record_exec_run(self, run_len: int, commit_ms: float) -> None:
         """Lane-thread metrics hook (Counter/Gauge/histograms are
@@ -2832,22 +2676,12 @@ class Replica(IReceiver):
         self._h_exec_run_len.record(run_len)
         self._h_exec_commit_ms.record(commit_ms)
 
-    def record_spec_seal(self, run_len: int, overlap_ms: float) -> None:
-        """Lane-thread metrics hook: one SPECULATIVE run of `run_len`
-        slots sealed at commit after overlapping `overlap_ms` of its
-        threshold-combine window with execution."""
-        self.m_exec_spec_runs.inc()
-        self.m_exec_spec_overlap.set(int(overlap_ms))
-        self._h_spec_overlap.record(overlap_ms)
-
     def _apply_exec_runs(self, _payload=None, repump: bool = True) -> None:
         """Integrate durably-applied runs (dispatcher thread): advance
         last_executed (only now — after the durable apply), persist the
         watermark, send the run's replies (riding the transport batcher
         via the dispatcher post-hook), finish spans, fire checkpoints
         computed at the run boundary, and re-arm the proposal pipeline."""
-        if self.exec_lane is None:
-            return
         runs = self.exec_lane.pop_completed()
         if not runs:
             return
@@ -2858,7 +2692,6 @@ class Replica(IReceiver):
                     continue
                 info.executed = True
                 info.exec_submitted = False
-                info.spec_submitted = False
                 if getattr(info, "span", None) is not None:
                     info.span.set_tag("committed_path", info.commit_path)
                     info.span.finish()
@@ -2912,7 +2745,7 @@ class Replica(IReceiver):
         self._try_send_pre_prepare()
         if repump:
             # a barrier batch may have been waiting behind these runs
-            self._pump_execution_lane()
+            self._pump_exec_lane()
 
     def _execute_internal_request(self, req: m.ClientRequestMsg,
                                   seq: int = 0) -> bytes:

@@ -44,10 +44,6 @@ class SeqNumInfo:
     # dispatcher's guard against double-submitting a slot whose
     # committed certificate is re-accepted while the lane still owns it
     exec_submitted: bool = False
-    # slot handed to the lane SPECULATIVELY (prepare-quorum / fast-path
-    # acceptance, commit certificate still combining): cleared when the
-    # commit confirms (→ exec_submitted) or the speculation aborts
-    spec_submitted: bool = False
     received_at: float = 0.0                   # monotonic, for path timeout
     # shares that arrived before our PrePrepare did (reference keeps them
     # in the collectors keyed by digest; we buffer until digest is known)

@@ -36,8 +36,7 @@ fsync — the widest crash window the exactly-once replay drills must
 cover (group maybe-applied, never acknowledged).
 
 `group_max=1` degenerates to the per-run durable apply (one batch, one
-fsync per run) — the A/B control `bench_e2e --durability-off` pairs
-against.
+fsync per run).
 """
 from __future__ import annotations
 

@@ -12,8 +12,6 @@ out of order and link them with integrity checks.
 from __future__ import annotations
 
 import hashlib
-import threading
-import time
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional, Tuple
 
@@ -116,36 +114,6 @@ class _StagedReadView(IDBClient):
         pass
 
 
-class _SpecOverlayView(IDBClient):
-    """Thread-routed staged-read view for SPECULATIVE accumulations: the
-    executor thread that owns the speculation reads its own staged
-    writes through the overlay (read-your-writes), while every OTHER
-    thread — read-only queries on the dispatcher, proof serving, status
-    handlers — keeps reading the committed base. A speculative run may
-    abort; its overlay must never be observable outside the thread that
-    can roll it back."""
-
-    def __init__(self, base: IDBClient, view: "_StagedReadView",
-                 owner_ident: int) -> None:
-        self._base = base
-        self._view = view
-        self._owner = owner_ident
-
-    def get(self, key: bytes, family: bytes = b"default"):
-        if threading.get_ident() == self._owner:
-            return self._view.get(key, family)
-        return self._base.get(key, family)
-
-    def write(self, batch: WriteBatch) -> None:
-        raise BlockchainError("staged read view is read-only")
-
-    def range_iter(self, family: bytes = b"default", start=None, end=None):
-        return self._base.range_iter(family, start, end)
-
-    def close(self) -> None:  # pragma: no cover - never owned
-        pass
-
-
 def raw_base(db):
     """Unwrap a durability `_PendingView` to the raw backing store —
     THE one idiom for 'give me the db the io thread writes/fsyncs'
@@ -238,13 +206,6 @@ class _Accumulation:
     base_last: int
     notifications: List[Tuple[int, "cat.BlockUpdates"]] = field(
         default_factory=list)
-    # speculative accumulations stay open across the commit-combine
-    # window: their staged reads are visible only to `owner` (the
-    # executor thread), and link_st_chain DEFERS instead of blocking on
-    # the staging lock they hold (the dispatcher must stay free to
-    # seal or abort them)
-    speculative: bool = False
-    owner: int = 0
 
 
 class BlockStoreMixin:
@@ -359,20 +320,7 @@ class BlockStoreMixin:
     # ---- properties ----
     @property
     def last_block_id(self) -> int:
-        # a SPECULATIVE accumulation's head bump is private to its
-        # executor thread, exactly like its staged reads: every other
-        # thread sees the committed head (a non-owner observing the
-        # speculative head would try to read blocks that may abort)
-        acc = self._accum
-        if acc is not None and acc.speculative \
-                and threading.get_ident() != acc.owner:
-            return acc.base_last
         return self._last
-
-    @property
-    def speculation_open(self) -> bool:
-        acc = self._accum
-        return acc is not None and acc.speculative
 
     @property
     def genesis_block_id(self) -> int:
@@ -435,35 +383,22 @@ class BlockStoreMixin:
         return block_id
 
     # ---- block accumulation (execution-lane run commit) ----
-    def begin_accumulation(self, speculative: bool = False) -> None:
+    def begin_accumulation(self) -> None:
         """Enter accumulation mode: subsequent add_block calls stage into
         ONE shared WriteBatch (committed by end_accumulation) instead of
         one DB write per block. Reads issued while accumulating — the
         handler's read-your-writes during execution, read-only queries —
         observe the staged blocks through the overlay view. Takes the
-        staging lock; the caller MUST reach end/abort_accumulation.
-
-        `speculative=True` (the execution lane's pre-commit runs): the
-        overlay + head bump are visible ONLY to the calling thread — a
-        speculative run may abort, so other threads (read-only queries,
-        proof serving) keep reading the committed base until
-        end_accumulation makes the run durable; link_st_chain defers
-        instead of blocking while the speculation holds the lock."""
+        staging lock; the caller MUST reach end/abort_accumulation."""
         self._staging_mu.acquire()
         try:
             if self._accum is not None:
                 raise BlockchainError("accumulation already active")
             overlay: Dict[bytes, Optional[bytes]] = {}
-            view = _StagedReadView(self._db, overlay)
-            install = view
-            if speculative:
-                install = _SpecOverlayView(self._db, view,
-                                           threading.get_ident())
             self._accum = _Accumulation(master=_MirroredBatch(overlay),
-                                        base_last=self._last,
-                                        speculative=speculative,
-                                        owner=threading.get_ident())
-            self._begin_staged_reads_locked(install)
+                                        base_last=self._last)
+            self._begin_staged_reads_locked(
+                _StagedReadView(self._db, overlay))
         except BaseException:
             self._accum = None
             self._staging_mu.release()
@@ -579,10 +514,8 @@ class BlockStoreMixin:
 
     def state_digest(self) -> bytes:
         """Digest of the whole chain head — what checkpoint certificates
-        sign (reference: kv_blockchain state hash). Routed head: a
-        non-owner thread asking during an open speculation digests the
-        committed chain, not the private overlay."""
-        last = self.last_block_id
+        sign (reference: kv_blockchain state hash)."""
+        last = self._last
         return self.block_digest(last) if last else b"\x00" * 32
 
     # ---- pruning (reference: deleteBlocksUntil / pruning_handler) ----
@@ -604,10 +537,6 @@ class BlockStoreMixin:
         return self._genesis
 
     # ---- state-transfer staging (reference v4 st_chain) ----
-    # comparisons use the routed `last_block_id`, not `self._last`: the
-    # ST plane runs on the dispatcher, which must not observe a
-    # speculative head bump (it would silently skip staging real blocks
-    # in the speculated range)
     def _durable_db(self) -> IDBClient:
         """The writable committed-base DB. While an accumulation is open
         `self._db` is a read-only staged view; direct writes that are
@@ -615,7 +544,7 @@ class BlockStoreMixin:
         keyspace) must target the base. Racy read of `_db` is safe:
         both branches point at a valid writable base."""
         db = self._db
-        if isinstance(db, (_StagedReadView, _SpecOverlayView)):
+        if isinstance(db, _StagedReadView):
             return self._base_db
         return db
 
@@ -655,22 +584,6 @@ class BlockStoreMixin:
     def _end_staged_reads_locked(self) -> None:
         self._db = self._base_db
 
-    def _acquire_staging_for_link(self, timeout: float = 5.0) -> bool:
-        """Take the staging lock for a link segment — or DEFER when the
-        current holder is a speculative accumulation (only the caller's
-        own thread can resolve it; see link_st_chain docstring). A
-        non-speculative holder (a normal execution run mid-commit) is
-        brief: wait it out within `timeout`."""
-        deadline = time.monotonic() + timeout
-        while True:
-            if self._staging_mu.acquire(timeout=0.05):
-                return True
-            acc = self._accum       # racy read; deferring is always safe
-            if acc is not None and acc.speculative:
-                return False
-            if time.monotonic() >= deadline:
-                return False
-
     def link_st_chain(self) -> int:
         """Adopt ALL contiguous staged blocks after the head as one
         write_group of per-block batches (one engine record per segment
@@ -685,15 +598,7 @@ class BlockStoreMixin:
         suffixes). On a bad staged block the verified prefix before it
         still commits, the bad row is dropped (so retries can re-fetch
         from another source instead of wedging on the same bytes), and
-        the error propagates. Returns the new head.
-
-        SPECULATION COMPOSITION: a speculative accumulation holds the
-        staging lock for the whole commit-combine window, and only the
-        dispatcher — the thread calling THIS function — can seal or
-        abort it. Blocking here would deadlock, so the lock acquisition
-        defers (returns the current head, nothing linked) whenever the
-        holder is speculative; the ST manager retries on its next
-        tick/window, after the speculation resolved."""
+        the error propagates. Returns the new head."""
         nxt: Optional[int] = None
         prev_digest = b""
         bad: Optional[int] = None
@@ -723,9 +628,9 @@ class BlockStoreMixin:
             # execution lane's accumulation shares the staged-read
             # redirect and must never interleave with linking. The head
             # snapshot happens under the lock too — an accumulation in
-            # another thread moves self._db and self._last.
-            if not self._acquire_staging_for_link():
-                break                 # speculation open: defer, no link
+            # another thread moves self._db and self._last. A run holds
+            # the lock for its own length and waits on nothing.
+            self._staging_mu.acquire()
             try:
                 # the segment commit writes the base directly: sealed
                 # runs must land before it (ST adoption drained the
@@ -784,8 +689,7 @@ class BlockStoreMixin:
                 break               # ran out of staged blocks (or hit bad)
         if error is not None:
             raise error
-        return self.last_block_id   # routed: a deferred link must not
-        # leak the speculation's private head bump to the ST caller
+        return self._last
 
 
 class KeyValueBlockchain(BlockStoreMixin):

@@ -28,8 +28,8 @@ stored, so where a changed leaf's path has no stored node the subtree
 under it is empty and `update_batch` takes every sibling below that
 depth as the default without asking the engine. This is no cache: the
 depth is found anew on every call through the tree's own read view (so
-a run's staged rows, the pending store and a speculative overlay are
-seen as ever), and nothing is remembered between calls.
+a run's staged rows and the pending store are seen as ever), and
+nothing is remembered between calls.
 
 Writes: a batch too narrow for the device tier (fewer changed leaves
 than `_DEVICE_THRESHOLD`) is walked by one native call

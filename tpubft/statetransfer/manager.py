@@ -381,15 +381,6 @@ class StateTransferManager:
             for rng in stalled:
                 if rng.msg_id in self._ranges:      # not dropped meanwhile
                     self._punish_range(rng, "stalled")
-            # a link deferred by an open speculative accumulation
-            # (link_st_chain returns without adopting while the exec
-            # lane holds the staging lock) leaves a contiguous staged
-            # block waiting — retry it here once the speculation
-            # resolved, or the transfer would wedge on already-verified
-            # blocks
-            if self._staged_src \
-                    and self.bc.has_st_block(self.bc.last_block_id + 1):
-                self._try_link()
             self._refill_ranges()
             self._update_rates()
             return

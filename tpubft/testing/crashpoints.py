@@ -52,14 +52,6 @@ REGISTRY: Dict[str, str] = {
         "and at-most-once markers are durable — recovery's replay must "
         "deduplicate against them (no double execution, no duplicate "
         "blocks, no ledger divergence)"),
-    "exec.spec_seal": (
-        "execution lane, speculative run fully commit-confirmed, BEFORE "
-        "its durable apply (the seal's end_accumulation): nothing of "
-        "the speculated run is durable — the staged overlay dies with "
-        "the process, recovery replays the committed suffix from "
-        "consensus metadata and re-executes it exactly once; a crash "
-        "EARLIER (mid-speculation, commits not yet in) must leave no "
-        "trace at all"),
     "vc.persist": (
         "view change, after persisting in_view_change/pending_view/"
         "evidence but BEFORE broadcasting the ViewChangeMsg: the restart "

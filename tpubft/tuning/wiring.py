@@ -140,28 +140,26 @@ def build_replica_tuning(replica, cfg) -> TuningController:
     controller.track("combine_batch_max")
 
     # --- execution lane: coalescing depth from the exec stage share ---
-    if replica.exec_lane is not None:
-        K("execution_max_accumulation", cfg.execution_max_accumulation,
-          1, MAX_ACCUMULATION, replica.exec_lane.set_max_accumulation,
-          "exec p50 share of the slot breakdown + lane depth", "slots")
-        controller.add_policy("execution_max_accumulation",
-                              exec_accumulation_policy())
+    K("execution_max_accumulation", cfg.execution_max_accumulation,
+      1, MAX_ACCUMULATION, replica.exec_lane.set_max_accumulation,
+      "exec p50 share of the slot breakdown + lane depth", "slots")
+    controller.add_policy("execution_max_accumulation",
+                          exec_accumulation_policy())
 
     # --- durability pipeline (ISSUE 15): group-commit window + size
     # from the measured per-run fsync cost vs the reply-stage share
     # (the group-fsync wait is accounted to `reply` in the slot
     # breakdown) ---
-    if getattr(replica, "durability", None) is not None:
-        K("durability_group_max", cfg.durability_group_max, 1, 64,
-          replica.durability.set_group_max,
-          "fsync us/run falling vs reply p50 share", "runs")
-        controller.add_policy("durability_group_max",
-                              durability_amortize_policy())
-        K("durability_window_us", cfg.durability_window_us, 0,
-          MAX_FLUSH_US, replica.durability.set_window_us,
-          "fsync us/run falling vs reply p50 share", "us")
-        controller.add_policy("durability_window_us",
-                              durability_amortize_policy())
+    K("durability_group_max", cfg.durability_group_max, 1, 64,
+      replica.durability.set_group_max,
+      "fsync us/run falling vs reply p50 share", "runs")
+    controller.add_policy("durability_group_max",
+                          durability_amortize_policy())
+    K("durability_window_us", cfg.durability_window_us, 0,
+      MAX_FLUSH_US, replica.durability.set_window_us,
+      "fsync us/run falling vs reply p50 share", "us")
+    controller.add_policy("durability_window_us",
+                          durability_amortize_policy())
 
     # --- admission backpressure: shed watermark (low follows at
     # high/3, preserving the construction-time hysteresis shape) ---
@@ -295,13 +293,10 @@ def build_replica_tuning(replica, cfg) -> TuningController:
 
 
 def _depths(replica) -> dict:
-    d = {}
-    if replica.exec_lane is not None:
-        d["exec_lane"] = replica.exec_lane.depth
+    d = {"exec_lane": replica.exec_lane.depth,
+         "dur_lag": replica.durability.lag}
     if replica.admission is not None:
         d["admission"] = replica.admission.depth
-    if getattr(replica, "durability", None) is not None:
-        d["dur_lag"] = replica.durability.lag
     if getattr(replica, "clients", None) is not None:
         d["client_table"] = replica.clients.resident_count
     return d
@@ -312,8 +307,7 @@ def _counters(replica) -> dict:
          "ecdsa_host_us": replica.sig.ecdsa_host_us.value}
     if replica.admission is not None:
         c["adm_shedding"] = 1 if replica.admission.shedding else 0
-    if getattr(replica, "durability", None) is not None:
-        c.update(replica.durability.stats())
+    c.update(replica.durability.stats())
     st = getattr(replica, "state_transfer", None)
     if st is not None:
         # late-bound like the knob itself (kvbc attaches ST after

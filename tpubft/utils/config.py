@@ -18,7 +18,7 @@ from typing import Any, Dict, Optional
 # through the generic escape hatch would silently desync those
 _TOPOLOGY_FIELDS = frozenset({
     "replica_id", "f_val", "c_val", "num_ro_replicas",
-    "num_of_client_proxies", "is_read_only"})
+    "num_of_client_proxies"})
 
 
 def parse_config_overrides(pairs) -> Dict[str, Any]:
@@ -64,11 +64,9 @@ class ReplicaConfig:
     c_val: int = 0                  # max slow/crashed replicas for fast path
     num_of_client_proxies: int = 1
     num_ro_replicas: int = 0
-    is_read_only: bool = False
 
     # batching (RequestsBatchingLogic equivalents)
     max_num_of_requests_in_batch: int = 100
-    max_batch_size_bytes: int = 33_554_432
     batch_flush_period_ms: int = 7
 
     # protocol windows/timers
@@ -81,7 +79,6 @@ class ReplicaConfig:
     status_report_timer_ms: int = 1000
     checkpoint_window_size: int = 150   # seqnums between protocol checkpoints
     work_window_size: int = 300         # in-flight seqnum window (2 checkpoints)
-    max_reply_size_bytes: int = 1_048_576
 
     # state transfer
     st_stall_timeout_ms: int = 5000     # certified checkpoint ahead + no
@@ -89,8 +86,6 @@ class ReplicaConfig:
 
     # commit paths
     fast_path_timeout_ms: int = 300     # demote in-flight seq to slow path
-    auto_primary_rotation_enabled: bool = False
-    view_change_protocol_enabled: bool = True
     pre_execution_enabled: bool = False
     # backup-side pre-execution reply cache (preprocessor/preprocessor.py
     # _reply_cache): bounded LRU of packed PreProcessReplyMsg so a
@@ -119,7 +114,6 @@ class ReplicaConfig:
     thin_replica_sub_buffer: int = 1024
     time_service_enabled: bool = False
     time_max_skew_ms: int = 1000
-    key_exchange_on_start: bool = False
 
     # crypto
     # "auto" resolves to "tpu" when a real accelerator is reachable
@@ -153,7 +147,6 @@ class ReplicaConfig:
     # cluster must configure the same value — the resolved scheme is
     # part of the cluster key material
     threshold_scheme_crossover_n: int = 0
-    client_transaction_signing_enabled: bool = True
 
     # crypto batch dispatch (TPU seam)
     verify_batch_size: int = 256
@@ -329,13 +322,10 @@ class ReplicaConfig:
     autotune_seed_file: str = ""
 
     # execution pipelining (reference: post-execution separation +
-    # block accumulation). True = committed slots are executed by a
-    # dedicated in-order executor thread that accumulates runs of
-    # consecutive slots into ONE ledger commit + ONE reserved-pages
-    # batch per run, keeping the dispatcher free to order the next
-    # slots; False = the legacy inline path (execution on the
-    # dispatcher, one commit per slot).
-    execution_lane: bool = True
+    # block accumulation): committed slots are executed by a dedicated
+    # in-order executor thread that accumulates runs of consecutive
+    # slots into ONE ledger commit + ONE reserved-pages batch per run,
+    # keeping the dispatcher free to order the next slots.
     # max committed slots coalesced into one execution run / ledger
     # commit. Runs always break at checkpoint-window boundaries so
     # state digests stay comparable cluster-wide. 1 degenerates to
@@ -355,31 +345,13 @@ class ReplicaConfig:
     # monotone durability watermark; replies, last_executed and the
     # at-most-once reply cache advance only behind it. The consensus-
     # metadata carve-out (db_sync_metadata) stays synchronous on the
-    # dispatcher. Requires the execution lane; False = the legacy
-    # per-run apply with immediate completion.
-    durability_pipeline: bool = True
-    # max runs fsynced per group (1 degenerates to the per-run durable
-    # apply — the bench_e2e --durability-off A/B control's shape)
+    # dispatcher.
+    # max runs fsynced per group (1 = one apply + one fsync a run)
     durability_group_max: int = 8
     # how long the io thread holds a partial group open for more runs,
     # measured from the group's FIRST sealed run (bounds the extra
     # reply latency durability batching can add; autotuned live)
     durability_window_us: int = 1000
-    # speculative execution ahead of the threshold combine: the
-    # dispatcher hands a slot to the execution lane as SPECULATIVE at
-    # prepare-quorum (slow path) or PrePrepare acceptance (fast paths,
-    # which have no prepare round), so the lane executes it inside an
-    # open, never-durable accumulation while the commit shares are
-    # still combining; the run is sealed (one durable apply) only when
-    # the commit certificate lands with the same digest, and replies +
-    # last_executed stay strictly post-commit. View change, barrier
-    # batches, and state-transfer adoption abort the overlay and the
-    # slot re-executes from its committed body. Requires the execution
-    # lane, an accumulation-capable ledger handler, and the time
-    # service off (its page writes bypass the rollback substrate) —
-    # silently inactive otherwise. False = legacy strictly-post-commit
-    # execution.
-    speculative_execution: bool = True
     # optimistic reply plane (arXiv 2407.12172): serve clients from f+1
     # matching INDIVIDUALLY-SIGNED replies instead of waiting for the
     # threshold certificate. With this on, a backup releases a slot to
@@ -394,9 +366,9 @@ class ReplicaConfig:
     # PERSISTENCE stays gated on verified commits (the optimistic
     # window is reply-visibility only). A certificate that fails its
     # deferred check poisons the optimistic plane for the rest of the
-    # view (certificate-gated replies resume). Requires the execution
-    # lane + speculation substrate to pay off; without them replies
-    # simply stay certificate-gated.
+    # view (certificate-gated replies resume). Requires
+    # async_verification (the deferred check IS the async job); without
+    # it replies simply stay certificate-gated.
     optimistic_replies: bool = False
 
     # retransmissions

@@ -89,9 +89,7 @@ EV_REPLY = 12           # slot integrated + replies sent (dispatcher)
 EV_DEV_ENTER = 13       # device_section entry (view=kind id, arg=batch)
 EV_DEV_EXIT = 14        # device_section exit (view=kind id, arg=us)
 EV_HEALTH = 15          # health verdict transition (arg=verdict id)
-EV_SPEC_ENQ = 16        # slot handed to the lane SPECULATIVELY
-EV_SPEC_SEAL = 17       # speculative run sealed at commit (arg=run len)
-EV_SPEC_ABORT = 18      # speculation aborted; slot re-executes committed
+# 16-18 are not reused: an old dump must not read as something else
 EV_COMBINE_FLUSH = 19   # fused combine flush (batcher; arg=slots drained)
 # thin-replica read tier (serving-plane events; seq carries a BLOCK id,
 # not a consensus seqnum — the read path has no slot)
@@ -145,9 +143,8 @@ EV_OFF_EVICT = 37       # helper evicted (arg: 0=sick/timeout,
 EV_PP_CREATE = 38       # primary cut a batch into a PrePrepare
 #                         (dispatcher; arg=µs its OLDEST request waited
 #                         in pending_requests — the order_wait stage)
-EV_EXEC_START = 39      # lane began executing a slot: normal run,
-#                         speculative staging or the inline path
-#                         (arg=run length)
+EV_EXEC_START = 39      # a slot began executing: a lane run, the restore
+#                         replay or a barrier batch (arg=run length)
 EV_SPAN = 40            # flight.span() closed (view=span-name id,
 #                         arg=µs; the id → name table rides every
 #                         snapshot as `span_names`)
@@ -160,9 +157,7 @@ EV_NAMES = {
     EV_COMMITTED: "committed", EV_EXEC_ENQ: "exec_enq",
     EV_EXEC_APPLY: "exec_apply", EV_REPLY: "reply",
     EV_DEV_ENTER: "dev_enter", EV_DEV_EXIT: "dev_exit",
-    EV_HEALTH: "health", EV_SPEC_ENQ: "spec_enqueue",
-    EV_SPEC_SEAL: "spec_seal", EV_SPEC_ABORT: "spec_abort",
-    EV_COMBINE_FLUSH: "combine_flush",
+    EV_HEALTH: "health", EV_COMBINE_FLUSH: "combine_flush",
     EV_TRS_SUBSCRIBE: "trs_subscribe", EV_TRS_PUSH: "trs_push",
     EV_TRS_PROOF: "trs_proof", EV_PREEXEC_LAUNCH: "preexec_launch",
     EV_PREEXEC_AGREE: "preexec_agree",
@@ -180,16 +175,12 @@ EV_NAMES = {
 # events the slot tracker folds inline (everything else is ring-only)
 _SLOT_CODES = frozenset((EV_ADM_ADMIT, EV_PP_DISPATCH, EV_PP_ACCEPT,
                          EV_PREPARED, EV_COMMITTED, EV_EXEC_ENQ,
-                         EV_EXEC_APPLY, EV_REPLY, EV_SPEC_ENQ,
-                         EV_SPEC_SEAL, EV_SPEC_ABORT,
+                         EV_EXEC_APPLY, EV_REPLY,
                          EV_CERT_ASYNC_LAG, EV_PP_CREATE,
                          EV_EXEC_START, EV_DUR_GROUP))
 
 # the six PIPELINE stages partition a slot's lifetime (they sum to the
-# slot total); spec_overlap is an OVERLAY — the slice of the commit
-# window reclaimed by speculative execution — and is excluded from the
-# total (it runs concurrently with `commit`, > 0 only on slots whose
-# speculative run actually sealed). cert_lag is the second overlay:
+# slot total). cert_lag is an OVERLAY, excluded from the total:
 # optimistic release -> verified certificate, the deferred-combine tail
 # that runs AFTER the client already has its reply (> 0 only under
 # ReplicaConfig.optimistic_replies; fed by EV_CERT_ASYNC_LAG samples,
@@ -202,8 +193,8 @@ _SLOT_CODES = frozenset((EV_ADM_ADMIT, EV_PP_DISPATCH, EV_PP_ACCEPT,
 # dur_wait is the slice of `reply` spent waiting for the group fsync.
 PIPELINE_STAGES = ("adm_wait", "dispatch", "prepare", "commit", "exec",
                    "reply")
-STAGES = PIPELINE_STAGES + ("spec_overlap", "cert_lag", "order_wait",
-                            "exec_wait", "exec_run", "dur_wait")
+STAGES = PIPELINE_STAGES + ("cert_lag", "order_wait", "exec_wait",
+                            "exec_run", "dur_wait")
 
 RING_SIZE = max(64, int(os.environ.get("TPUBFT_FLIGHT_RING", "4096")
                         or 4096))
@@ -479,22 +470,15 @@ class SlotTracker:
                   concurrency_level / work-window gate; EV_PP_CREATE's
                   arg, so 0 on every backup's row)
         exec_wait   commit -> the lane began the slot (EV_EXEC_START):
-                  queueing behind earlier runs; 0 for a slot whose
-                  speculation ran ahead of its commit
+                  queueing behind earlier runs; 0 for a slot the
+                  lane began ahead of its verified commit (released
+                  on the structural certificate, optimistic replies)
         exec_run    max(lane start, commit) -> durable apply: the lane's
                   own work; exec_wait + exec_run == exec, always
         dur_wait    durable apply -> the durability group that covers
                   the slot committed (EV_DUR_GROUP, io thread); a slice
-                  of `reply`, 0 without the pipeline
-
-    Plus one OVERLAY stage that runs concurrently with ``commit`` and
-    is excluded from the slot total:
-
-        spec_overlap  speculative enqueue -> commit quorum: the slice
-                  of the combine window the execution lane reclaimed
-                  by running the slot ahead of its commit certificate
-                  (> 0 only when the speculative run sealed; aborted
-                  speculations fold to 0)
+                  of `reply`, 0 for a slot no group covers (a barrier
+                  batch, the restore replay)
 
     A slot finalizes on EV_REPLY (the dispatcher records it for every
     integrated slot, replies or not): its stage durations feed the
@@ -543,7 +527,6 @@ class SlotTracker:
               EV_PP_ACCEPT: "accept", EV_PREPARED: "prepared",
               EV_COMMITTED: "committed", EV_EXEC_ENQ: "enqueued",
               EV_EXEC_APPLY: "applied", EV_REPLY: "replied",
-              EV_SPEC_ENQ: "spec_enq", EV_SPEC_SEAL: "spec_seal",
               EV_PP_CREATE: "created", EV_EXEC_START: "started"}
 
     @classmethod
@@ -552,14 +535,6 @@ class SlotTracker:
         with tools/tpuprof.py, which replays dumped rings through it.
         First sighting wins (a retransmitted PrePrepare or a retried
         run must not move an anchor)."""
-        if code == EV_SPEC_ABORT:
-            # the speculation was discarded: this slot re-executes
-            # from its committed body, so no combine window was
-            # reclaimed — spec_overlap must fold to 0, and the lane's
-            # start is the re-execution's, not the discarded staging's
-            for field in ("spec_enq", "spec_seal", "started"):
-                slot.pop(field, None)
-            return
         slot.setdefault(cls._FIELD[code], t_ns)
         if code == EV_COMMITTED:
             slot.setdefault("path", "fast" if arg else "slow")
@@ -597,8 +572,7 @@ class SlotTracker:
         with self._mu:
             slot = self._live.get(key)
             if slot is None:
-                if (code in (EV_REPLY, EV_SPEC_ABORT)
-                        or key in self._folded_set):
+                if code == EV_REPLY or key in self._folded_set:
                     return              # replay / late event on a
                     #                     slot that already folded
                 if len(self._live) >= self.MAX_LIVE:
@@ -632,7 +606,7 @@ class SlotTracker:
         # the split is clamped into `exec` so the two parts always sum
         # to it: a slot with no lane start on record (an event lost to a
         # reset) reads as all service, one that started before its
-        # commit (speculation ran ahead) as no wait
+        # verified commit (optimistic release) as no wait
         exec_wait = min(ms(committed, slot.get("started")), exec_ms)
         reply_ms = ms(applied, slot.get("replied"))
         return {
@@ -643,13 +617,6 @@ class SlotTracker:
                          slot.get("committed")),
             "exec": exec_ms,
             "reply": reply_ms,
-            # combine-window slice reclaimed by speculation: counted
-            # only when the speculative run actually SEALED (an aborted
-            # or commit-first speculation reclaimed nothing)
-            "spec_overlap": (ms(slot.get("spec_enq"),
-                                slot.get("committed"))
-                             if slot.get("spec_seal") is not None
-                             else 0.0),
             # per-slot placeholder: the deferred-combine tail lands
             # AFTER the slot finalizes, so cert_lag is folded from the
             # EV_CERT_ASYNC_LAG sample stream (see summary()), never
@@ -666,7 +633,6 @@ class SlotTracker:
         rec = {"rid": slot["rid"], "seq": slot["seq"],
                "view": slot.get("view", 0),
                "path": slot.get("path", "?"),
-               "spec": slot.get("spec_seal") is not None,
                "reqs": slot.get("reqs", 0),
                "primary": "created" in slot,
                "total_ms": round(sum(stages[s]
