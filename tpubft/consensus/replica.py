@@ -476,6 +476,10 @@ class Replica(IReceiver):
 
         # --- metrics (names mirror the reference's replica component) ---
         self.metrics = Component("replica", self.aggregator)
+        # the threshold plane's decode totals are process-wide
+        # (`threshold` component): served with this replica's own
+        from tpubft.crypto import systems
+        self.aggregator.register(systems.METRICS)
         self.m_executed = self.metrics.register_counter("executed_requests")
         self.m_preprepares = self.metrics.register_counter("sent_preprepares")
         self.m_fast_commits = self.metrics.register_counter("fast_path_commits")

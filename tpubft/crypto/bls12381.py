@@ -573,6 +573,45 @@ def g1_decompress(b: bytes, check_subgroup: bool = True):
     return pt
 
 
+def g1_decompress_many(encoded: Sequence[bytes]) -> List:
+    """`g1_decompress` over a whole set of shares, element for element:
+    the point, None for the canonical infinity, or — in the place of the
+    raise — the ValueError an invalid encoding or an off-subgroup point
+    earns. One native call decodes the set and runs the deterministic
+    membership test on every point (bls_native.g1_decompress_batch);
+    without the native library, the loop over `g1_decompress`."""
+    from tpubft.crypto import bls_native
+    if not bls_native.available():
+        out: List = []
+        for b in encoded:
+            try:
+                out.append(g1_decompress(b))
+            except ValueError as e:
+                out.append(e)
+        return out
+    sized = [b for b in encoded if len(b) == G1_LEN]
+    raw, verdicts = bls_native.g1_decompress_batch(b"".join(sized),
+                                                   len(sized))
+    out = []
+    i = 0
+    for b in encoded:
+        if len(b) != G1_LEN:
+            out.append(ValueError("bad G1 encoding length"))
+            continue
+        v, off = verdicts[i], 96 * i
+        i += 1
+        if v == 1:
+            out.append((int.from_bytes(raw[off:off + 48], "big"),
+                        int.from_bytes(raw[off + 48:off + 96], "big")))
+        elif v == 2:
+            out.append(None)
+        elif v == 3:
+            out.append(ValueError("G1 point not in order-R subgroup"))
+        else:
+            out.append(ValueError("invalid G1 encoding"))
+    return out
+
+
 # GLV endomorphism subgroup test (the blst/Scott fast check): on the
 # order-R subgroup the endomorphism phi(x,y) = (beta*x, y) acts as
 # multiplication by lambda = x_param^2 - 1 (a root of T^2+T+1 mod R);
@@ -698,31 +737,15 @@ def threshold_keygen(k: int, n: int, seed: Optional[bytes] = None):
     return master_pk, share_pks, shares
 
 
-def lagrange_coeffs_at_zero(ids: Sequence[int]) -> List[int]:
-    """L_i(0) mod R for the signer-id set (reference:
-    threshsign/src/bls/relic/BlsThresholdAccumulator.cpp:42
-    computeLagrangeCoeff).
-
-    Optimized for large signer sets (n=1000 scale): the shared numerator
-    Π(-j) is computed once; per-i denominators accumulate the SMALL
-    integer differences (i-j) in machine-size chunks before each modular
-    reduction; and all k inversions collapse into ONE modexp via
-    Montgomery batch inversion. ~10x over the naive per-i modexp loop at
-    k=667."""
-    k = len(ids)
-    if k == 0:
-        return []
-    # fail loud on degenerate id sets: an id ≡ 0 mod R zeroes the
-    # batched products (silently-infinite combined signature), and
-    # duplicates make the interpolation meaningless
-    if len(set(i % R for i in ids)) != k or any(i % R == 0 for i in ids):
-        raise ValueError("signer ids must be distinct and nonzero mod R")
-    num_total = 1
-    for j in ids:
-        num_total = num_total * (R - j) % R          # Π (0 - j)
-    # den_i = Π_{j != i} (i - j); |i - j| is small, so bundle ~5 factors
-    # per big-int modmul
-    terms = []
+def _lagrange_dens(ids: Sequence[int]) -> List[int]:
+    """Π_{j != i} (i - j) mod R for every i: k² small factors. Native
+    where the library is there and the ids are machine-size (share ids
+    always are); else |i - j| is small all the same, so ~5 factors are
+    bundled per big-int modmul."""
+    from tpubft.crypto import bls_native
+    if bls_native.available() and all(-2**62 < i < 2**62 for i in ids):
+        return bls_native.lagrange_dens(ids)
+    dens = []
     for i in ids:
         den = 1
         small = 1
@@ -737,8 +760,35 @@ def lagrange_coeffs_at_zero(ids: Sequence[int]) -> List[int]:
                 small, nsmall = 1, 0
         if nsmall:
             den = den * small % R
-        # fold the numerator's surplus (0 - i) factor into the inversion
-        terms.append(den * (R - i) % R)
+        dens.append(den)
+    return dens
+
+
+def lagrange_coeffs_at_zero(ids: Sequence[int]) -> List[int]:
+    """L_i(0) mod R for the signer-id set (reference:
+    threshsign/src/bls/relic/BlsThresholdAccumulator.cpp:42
+    computeLagrangeCoeff).
+
+    Optimized for large signer sets (n=1000 scale): the shared numerator
+    Π(-j) is computed once; the per-i denominators' k² small factors
+    (i-j) are multiplied up in one native call (_lagrange_dens); and all
+    k inversions collapse into ONE modexp via Montgomery batch
+    inversion."""
+    k = len(ids)
+    if k == 0:
+        return []
+    # fail loud on degenerate id sets: an id ≡ 0 mod R zeroes the
+    # batched products (silently-infinite combined signature), and
+    # duplicates make the interpolation meaningless
+    if len(set(i % R for i in ids)) != k or any(i % R == 0 for i in ids):
+        raise ValueError("signer ids must be distinct and nonzero mod R")
+    num_total = 1
+    for j in ids:
+        num_total = num_total * (R - j) % R          # Π (0 - j)
+    # den_i = Π_{j != i} (i - j), with the numerator's surplus (0 - i)
+    # factor folded into the inversion
+    terms = [den * (R - i) % R
+             for den, i in zip(_lagrange_dens(ids), ids)]
     # batch inversion: one modexp total
     prefix = [1] * (k + 1)
     for t in range(k):

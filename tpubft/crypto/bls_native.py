@@ -31,6 +31,13 @@ def available() -> bool:
         lib.bls381_g2_msm.restype = ctypes.c_int
         lib.bls381_g1_decompress.restype = ctypes.c_int
         lib.bls381_fp_sqrt.restype = ctypes.c_int
+        lib.bls381_lagrange_dens.restype = None
+        lib.bls381_lagrange_dens.argtypes = [
+            ctypes.POINTER(ctypes.c_uint64), ctypes.POINTER(ctypes.c_int64),
+            ctypes.c_int]
+        lib.bls381_g1_decompress_batch.restype = None
+        lib.bls381_g1_decompress_batch.argtypes = [
+            ctypes.c_char_p, ctypes.c_char_p, ctypes.c_char_p, ctypes.c_int]
         _lib = lib
     except Exception:  # noqa: BLE001 — no toolchain: pure-Python fallback
         _lib = None
@@ -119,6 +126,32 @@ def g1_decompress(b: bytes):
         raise ValueError("invalid G1 encoding")
     raw = out.raw
     return (int.from_bytes(raw[:48], "big"), int.from_bytes(raw[48:], "big"))
+
+
+def g1_decompress_batch(encoded: bytes, n: int) -> Tuple[bytes, bytes]:
+    """n compressed G1 points (n * 48 bytes) through ONE native call:
+    decode AND the order-R membership test, per point. Returns (n * 96
+    bytes of big-endian affine pairs, n verdict bytes): 1 point, 2
+    canonical infinity, 0 invalid encoding, 3 outside the subgroup; a
+    pair is meaningful only under verdict 1."""
+    if len(encoded) != 48 * n:
+        raise ValueError("encoded G1 batch is not n * 48 bytes")
+    out = ctypes.create_string_buffer(96 * n)
+    verdicts = ctypes.create_string_buffer(n)
+    _lib.bls381_g1_decompress_batch(out, verdicts, encoded, n)
+    return out.raw, verdicts.raw
+
+
+def lagrange_dens(ids: Sequence[int]) -> List[int]:
+    """den_i = prod_{j != i} (ids[i] - ids[j]) mod R for every i, the k^2
+    small products in native code; ids are ints of magnitude < 2^62."""
+    n = len(ids)
+    out = (ctypes.c_uint64 * (4 * n))()
+    _lib.bls381_lagrange_dens(out, (ctypes.c_int64 * n)(*ids), n)
+    raw = bytes(out)
+    scale = pow(2, 64 * (n - 1), _R)    # the rounds' 2^-64 each, undone
+    return [int.from_bytes(raw[32 * i:32 * i + 32], "little") * scale % _R
+            for i in range(n)]
 
 
 def g1_mul_nonorder(point, k: int):

@@ -66,7 +66,10 @@ class IThresholdAccumulator(abc.ABC):
 
     @abc.abstractmethod
     def add(self, share_id: int, share: bytes) -> int:
-        """Add a share; returns number of shares accumulated."""
+        """Add a share; returns the number of shares held so far. An
+        accumulator that decodes late (threshold-bls) counts a share it
+        has kept and not yet looked at: `has_threshold` is the exact
+        answer, this is not."""
 
     @abc.abstractmethod
     def has_threshold(self) -> bool: ...

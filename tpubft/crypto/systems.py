@@ -29,6 +29,7 @@ from tpubft.crypto.interfaces import (Cryptosystem, IThresholdAccumulator,
                                       IThresholdFactory, IThresholdSigner,
                                       IThresholdVerifier)
 from tpubft.utils import flight
+from tpubft.utils.metrics import Component
 
 
 # ---------------- multisig-ed25519 ----------------
@@ -166,52 +167,96 @@ class BlsThresholdSigner(IThresholdSigner):
         return self._id
 
 
+# what the threshold plane decoded in batches, process-wide (every
+# accumulator and fused flush of every replica in the process): shares
+# handed to `bls.g1_decompress_many` and the calls that carried them —
+# their ratio is the mean batch. A share decoded alone by
+# `bls.g1_decompress` (a certificate, `verify_share`) is not counted.
+# Totals only — nothing here is read back by the plane.
+METRICS = Component("threshold")
+_M_DECODED = METRICS.register_counter("bls_shares_batch_decoded")
+_M_BATCHES = METRICS.register_counter("bls_decode_batches")
+
+
+def decode_shares(encoded: Sequence[bytes]) -> List:
+    """THE definition of a share that decodes, for the accumulator and
+    the fused paths alike: `bls.g1_decompress_many` (canonical encoding,
+    on the curve, in the order-R subgroup — every share), with an
+    undecodable share and the infinity both read as None."""
+    if not encoded:
+        return []
+    _M_DECODED.inc(len(encoded))
+    _M_BATCHES.inc()
+    return [None if isinstance(pt, ValueError) else pt
+            for pt in bls.g1_decompress_many(encoded)]
+
+
 class BlsThresholdAccumulator(IThresholdAccumulator):
-    """Accumulate G1 shares; combine = Lagrange + MSM (the TPU-sharded op)."""
+    """Accumulate G1 shares; combine = Lagrange + MSM (the TPU-sharded op).
+
+    `add` keeps a share's bytes; whatever first needs the points —
+    `has_threshold`, the combine, `identify_bad_shares` — decodes
+    everything pending in ONE `decode_shares` call and applies `add`'s
+    rules in arrival order."""
 
     def __init__(self, verifier: "BlsThresholdVerifier", share_verification: bool):
         self._verifier = verifier
         self._share_verification = share_verification
         self._digest: Optional[bytes] = None
-        self._shares: Dict[int, object] = {}
-        self._decompress_ns = 0       # g1_decompress time over add()
+        # (id, share bytes, the digest to verify the share against or
+        # None), in arrival order, not decoded yet
+        self._pending: List[Tuple[int, bytes, Optional[bytes]]] = []
+        self._decoded: Dict[int, object] = {}
+        self._decompress_ns = 0       # decode time since the last span
 
     def set_expected_digest(self, digest: bytes) -> None:
         self._digest = digest
 
     def add(self, share_id: int, share: bytes) -> int:
-        if not 1 <= share_id <= self._verifier.total_signers:
-            return len(self._shares)
-        t0 = time.monotonic_ns()
-        try:
-            pt = bls.g1_decompress(share)
-        except ValueError:
-            return len(self._shares)
-        finally:
+        if 1 <= share_id <= self._verifier.total_signers:
+            self._pending.append(
+                (share_id, share,
+                 self._digest if self._share_verification else None))
+        return len(self._decoded) + len(self._pending)
+
+    @property
+    def _shares(self) -> Dict[int, object]:
+        """id -> point of every valid share added so far: an undecodable,
+        off-subgroup or infinity share is dropped, as is one that fails
+        the share verification asked for; a later valid share for an id
+        replaces an earlier one, an invalid one replaces nothing. A
+        property, so that every reader of `_shares` (the TPU subclass,
+        the benchmark's control plant) meets decoded shares only."""
+        if self._pending:
+            pending, self._pending = self._pending, []
+            t0 = time.monotonic_ns()
+            pts = decode_shares([share for _, share, _ in pending])
             self._decompress_ns += time.monotonic_ns() - t0
-        if pt is None:
-            return len(self._shares)
-        if self._share_verification and self._digest is not None:
-            if not self._verifier.verify_share(share_id, self._digest, share):
-                return len(self._shares)
-        self._shares[share_id] = pt
-        return len(self._shares)
+            for (share_id, share, digest), pt in zip(pending, pts):
+                if pt is None:
+                    continue
+                if digest is not None and not self._verifier.verify_share(
+                        share_id, digest, share):
+                    continue
+                self._decoded[share_id] = pt
+        return self._decoded
 
     def has_threshold(self) -> bool:
         return len(self._shares) >= self._verifier.threshold
 
     def _flush_decompress_span(self) -> None:
-        """The shares' decompression, summed over `add`, as ONE flight
-        span at the combine — never a span per share."""
+        """The shares' batch decode as ONE flight span at the combine —
+        never a span per share, nor one per `has_threshold`."""
         if self._decompress_ns:
             flight.record_span("bls_share_decompress",
                                self._decompress_ns // 1000)
             self._decompress_ns = 0
 
     def get_full_signed_data(self) -> bytes:
+        shares = self._shares
         self._flush_decompress_span()
-        ids = sorted(self._shares)[: self._verifier.threshold]
-        combined = bls.combine_shares(ids, [self._shares[i] for i in ids])
+        ids = sorted(shares)[: self._verifier.threshold]
+        combined = bls.combine_shares(ids, [shares[i] for i in ids])
         return bls.g1_compress(combined)
 
     def identify_bad_shares(self) -> List[int]:
@@ -274,15 +319,12 @@ class BlsThresholdVerifier(IThresholdVerifier):
     def _verify_batch_certs(self, items) -> List[bool]:
         out = [False] * len(items)
         pts, hs, idxs = [], [], []
-        for i, (d, s) in enumerate(items):
-            try:
-                pt = bls.g1_decompress(s)
-            except ValueError:
-                continue
-            if pt is None:
+        # certificates, not shares: the same decode, outside the counters
+        for i, pt in enumerate(bls.g1_decompress_many([s for _, s in items])):
+            if pt is None or isinstance(pt, ValueError):
                 continue
             pts.append(pt)
-            hs.append(bls.hash_to_g1(d))
+            hs.append(bls.hash_to_g1(items[i][0]))
             idxs.append(i)
         if not pts:
             return out
@@ -317,18 +359,9 @@ class BlsThresholdVerifier(IThresholdVerifier):
         """Accumulator `add` semantics over a raw share dict: out-of-range
         ids and undecodable/infinity points are silently dropped — the
         job combines over what remains, exactly as the per-slot path."""
-        pts: Dict[int, object] = {}
-        for sid, share in shares.items():
-            if not 1 <= sid <= self._total:
-                continue
-            try:
-                pt = bls.g1_decompress(share)
-            except ValueError:
-                continue
-            if pt is None:
-                continue
-            pts[sid] = pt
-        return pts
+        sids = [sid for sid in shares if 1 <= sid <= self._total]
+        pts = decode_shares([shares[sid] for sid in sids])
+        return {sid: pt for sid, pt in zip(sids, pts) if pt is not None}
 
     def _combine_segments(self, segments, digests=None) -> List:
         """[(ids, [share points])] -> one combined G1 point per segment.

@@ -372,17 +372,18 @@ class TpuBlsThresholdAccumulator(BlsThresholdAccumulator):
         import os
         k = self._verifier.threshold
         crossover = int(os.environ.get("TPUBFT_MSM_CROSSOVER_K", "128"))
-        if len(self._shares) < crossover and k < crossover:
+        shares = self._shares           # decodes what `add` kept
+        if len(shares) < crossover and k < crossover:
             return super().get_full_signed_data()
         self._flush_decompress_span()
         try:
             with device_tier("bls_msm"):
                 from tpubft.ops import bls12_381 as dev
-                ids = sorted(self._shares)[:k]
+                ids = sorted(shares)[:k]
                 # shares are affine (x, y) int tuples — the device MSM's
                 # native input
                 combined = dev.combine_shares(
-                    ids, [self._shares[i] for i in ids])
+                    ids, [shares[i] for i in ids])
                 return bls.g1_compress(combined)
         except Exception:  # noqa: BLE001 — device loss: the host
             # Pippenger combine produces the identical signature

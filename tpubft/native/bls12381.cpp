@@ -19,6 +19,9 @@
 
 #include <cstdint>
 #include <cstring>
+#include <system_error>
+#include <thread>
+#include <vector>
 
 using u64 = uint64_t;
 using u128 = unsigned __int128;
@@ -44,6 +47,8 @@ static const uint64_t G2C3_0[6] = {0x43f5fffffffcaaaeULL, 0x32b7fff2ed47fffdULL,
 static const uint64_t G2C4_0[6] = {0xcd03c9e48671f071ULL, 0x5dab22461fcda5d2ULL, 0x587042afd3851b95ULL, 0x8eb60ebe01bacb9eULL, 0x03f97d6e83d050d2ULL, 0x18f0206554638741ULL};
 static const uint64_t G2C5_0[6] = {0x890dc9e4867545c3ULL, 0x2af322533285a5d5ULL, 0x50880866309b7e2cULL, 0xa20d1b8c7e881024ULL, 0x14e4f04fe2db9068ULL, 0x14e56d3f1564853aULL};
 
+// beta: the cube root of unity with phi(x, y) = (beta*x, y) = [x^2 - 1](x, y) on G1 (Montgomery form)
+static const uint64_t G1_BETA_L[6] = {0xcd03c9e48671f071ULL, 0x5dab22461fcda5d2ULL, 0x587042afd3851b95ULL, 0x8eb60ebe01bacb9eULL, 0x03f97d6e83d050d2ULL, 0x18f0206554638741ULL};
 static const u64 X_ABS = 0xd201000000010000ULL;  // |x|, x negative
 static u64 SQRT_EXP[6];                          // (p+1)/4, set in ensure_init
 static uint8_t P_BE[48], P_HALF_BE[48];          // p and (p-1)/2, big-endian
@@ -118,32 +123,32 @@ static void fp_neg(Fp& r, const Fp& a) {
     }
 }
 
-// CIOS Montgomery multiplication: r = a*b*R^-1 mod p
+// CIOS Montgomery multiplication: r = a*b*R^-1 mod p, one word of b a
+// round with the multiply and the reduction interleaved. p < 2^381
+// leaves the top limb three spare bits, so for a < p (b any six limbs)
+// the running value stays under 2p and needs no seventh limb: the two
+// carry words of a round add without overflow.
 static void fp_mul(Fp& r, const Fp& a, const Fp& b) {
-    u64 t[8] = {0, 0, 0, 0, 0, 0, 0, 0};
+    u64 t[6] = {0, 0, 0, 0, 0, 0};
+#pragma GCC unroll 6
     for (int i = 0; i < 6; i++) {
-        u128 c = 0;
-        for (int j = 0; j < 6; j++) {
-            c += (u128)t[j] + (u128)a.l[j] * b.l[i];
-            t[j] = (u64)c;
-            c >>= 64;
-        }
-        c += t[6];
-        t[6] = (u64)c;
-        t[7] = (u64)(c >> 64);
-        u64 m = t[0] * N0INV;
-        c = (u128)t[0] + (u128)m * P_LIMBS[0];
-        c >>= 64;
+        const u64 bi = b.l[i];
+        u128 c = (u128)a.l[0] * bi + t[0];
+        u64 hi_a = (u64)(c >> 64);
+        const u64 m = (u64)c * N0INV;
+        c = (u128)m * P_LIMBS[0] + (u64)c;
+        u64 hi_m = (u64)(c >> 64);
+#pragma GCC unroll 5
         for (int j = 1; j < 6; j++) {
-            c += (u128)t[j] + (u128)m * P_LIMBS[j];
+            c = (u128)a.l[j] * bi + t[j] + hi_a;
+            hi_a = (u64)(c >> 64);
+            c = (u128)m * P_LIMBS[j] + (u64)c + hi_m;
             t[j - 1] = (u64)c;
-            c >>= 64;
+            hi_m = (u64)(c >> 64);
         }
-        c += t[6];
-        t[5] = (u64)c;
-        t[6] = t[7] + (u64)(c >> 64);
+        t[5] = hi_a + hi_m;
     }
-    if (t[6] || fp_cmp_p(t) >= 0) fp_sub_p(t);
+    if (fp_cmp_p(t) >= 0) fp_sub_p(t);
     memcpy(r.l, t, 48);
 }
 
@@ -182,7 +187,7 @@ static void fp_from_be(Fp& r, const uint8_t* be48) {
     }
     Fp r2;
     memcpy(r2.l, R2C, 48);
-    fp_mul(r, raw, r2);               // to Montgomery form
+    fp_mul(r, r2, raw);               // to Montgomery form (raw may be >= p)
 }
 
 static void fp_to_be(uint8_t* be48, const Fp& a) {
@@ -197,7 +202,7 @@ static void fp_to_be(uint8_t* be48, const Fp& a) {
     }
 }
 
-static Fp FP_ZERO_C, FP_ONE_C;
+static Fp FP_ZERO_C, FP_ONE_C, G1_BETA_M;
 
 // ---------------- Fp2 = Fp[u]/(u^2+1) ----------------
 
@@ -757,11 +762,10 @@ struct G2J { Fp2 x, y, z; bool inf; };
 static void g1j_dbl(G1J& r, const G1J& in) {
     const G1J a = in;                  // r may alias in
     if (a.inf || fp_is_zero(a.y)) { r.inf = true; return; }
-    Fp xx, yy, yyyy, zz, s, mm, t;
+    Fp xx, yy, yyyy, s, mm, t;
     fp_sqr(xx, a.x);
     fp_sqr(yy, a.y);
     fp_sqr(yyyy, yy);
-    fp_sqr(zz, a.z);
     fp_add(s, a.x, yy);
     fp_sqr(s, s);
     fp_sub(s, s, xx);
@@ -1044,6 +1048,7 @@ static void ensure_init() {
     }
     memset(&FP_ZERO_C, 0, sizeof(FP_ZERO_C));
     memcpy(FP_ONE_C.l, ONE_M, 48);
+    memcpy(G1_BETA_M.l, G1_BETA_L, 48);
     FP2_ZERO_C.c0 = FP_ZERO_C; FP2_ZERO_C.c1 = FP_ZERO_C;
     FP2_ONE_C.c0 = FP_ONE_C; FP2_ONE_C.c1 = FP_ZERO_C;
     memset(&FP6_ZERO_C, 0, sizeof(FP6_ZERO_C));
@@ -1176,6 +1181,128 @@ static void msm_pippenger(Jac& acc, const Aff* aff, const uint8_t* ks,
 }
 
 
+// ---------------- G1 decode + subgroup membership ----------------
+
+// One compressed G1 point: canonical-encoding and on-curve checks, the
+// square root by one fp_pow, the sign bit. Returns 1 (p and the
+// big-endian affine pair in xy96 are set), 2 canonical infinity,
+// 0 invalid.
+static int g1_decode(G1A& p, uint8_t* xy96, const uint8_t* in48) {
+    uint8_t flags = in48[0];
+    if (!(flags & 0x80)) return 0;
+    if (flags & 0x40) {                 // infinity: canonical form only
+        if (flags != 0xC0) return 0;
+        for (int i = 1; i < 48; i++) {
+            if (in48[i]) return 0;
+        }
+        return 2;
+    }
+    uint8_t* xbe = xy96;
+    uint8_t* ybe = xy96 + 48;
+    memcpy(xbe, in48, 48);
+    xbe[0] &= 0x1F;
+    // canonical: x < p (big-endian compare; P_BE set in ensure_init)
+    if (memcmp(xbe, P_BE, 48) >= 0) return 0;
+    Fp x3, y2, b4;
+    fp_from_be(p.x, xbe);
+    fp_sqr(x3, p.x);
+    fp_mul(x3, x3, p.x);
+    fp_add(b4, FP_ONE_C, FP_ONE_C);     // b = 4 in Montgomery form
+    fp_add(b4, b4, b4);
+    fp_add(y2, x3, b4);
+    // sqrt: y = y2^((p+1)/4)  (p ≡ 3 mod 4); SQRT_EXP set in ensure_init
+    fp_pow(p.y, y2, SQRT_EXP, 6);
+    Fp chk;
+    fp_sqr(chk, p.y);
+    if (!fp_eq(chk, y2)) return 0;      // not a QR: off curve
+    // sign selection: flag 0x20 = y greater than (p-1)/2
+    fp_to_be(ybe, p.y);
+    bool greater = memcmp(ybe, P_HALF_BE, 48) > 0;
+    if (greater != !!(flags & 0x20)) {
+        fp_neg(p.y, p.y);
+        fp_to_be(ybe, p.y);
+    }
+    p.inf = false;
+    return 1;
+}
+
+// [|x|]P for the curve parameter |x| = 0xd201000000010000 (weight 6):
+// 63 doublings and 5 additions. r may alias base.
+static void g1j_mul_x_abs(G1J& r, const G1J& base_in) {
+    const G1J base = base_in;
+    G1J acc = base;                     // bit 63
+    for (int b = 62; b >= 0; b--) {
+        g1j_dbl(acc, acc);
+        if ((X_ABS >> b) & 1) g1j_add(acc, acc, base);
+    }
+    r = acc;
+}
+
+// Deterministic order-r membership of an on-curve affine point — the
+// GLV test of crypto/bls12381.py g1_in_subgroup, phi(P) == [lambda]P
+// with phi(x, y) = (beta*x, y) and lambda = x^2 - 1, computed through
+// the parameter's sparse form [lambda]P = [|x|]([|x|]P) - P and
+// compared projectively (X == beta*x*Z^2, Y == y*Z^3: no inversion).
+static bool g1_in_subgroup(const G1A& p) {
+    G1J q;
+    q.x = p.x; q.y = p.y; q.z = FP_ONE_C; q.inf = false;
+    g1j_mul_x_abs(q, q);
+    g1j_mul_x_abs(q, q);
+    G1A neg = p;
+    fp_neg(neg.y, p.y);
+    g1j_add_affine(q, q, neg);
+    if (q.inf) return false;
+    Fp z2, z3, want;
+    fp_sqr(z2, q.z);
+    fp_mul(z3, z2, q.z);
+    fp_mul(want, p.x, G1_BETA_M);
+    fp_mul(want, want, z2);
+    if (!fp_eq(want, q.x)) return false;
+    fp_mul(want, p.y, z3);
+    return fp_eq(want, q.y);
+}
+
+
+// ---------------- Lagrange denominators mod r ----------------
+
+// r, the order of G1, and -r^-1 mod 2^64
+static const u64 R_LIMBS[4] = {0xffffffff00000001ULL, 0x53bda402fffe5bfeULL, 0x3339d80809a1d805ULL, 0x73eda753299d7d48ULL};
+static const u64 R_N0INV = 0xfffffffeffffffffULL;
+
+// acc = acc * c / 2^64 mod r for acc < r: one Montgomery round with a
+// one-word multiplier (acc*c + m*r < 2r * 2^64, so four limbs hold it)
+static inline void fr_mul_word(u64* acc, u64 c) {
+    u64 lo[5];
+    u128 t = 0;
+    for (int j = 0; j < 4; j++) {
+        t += (u128)acc[j] * c;
+        lo[j] = (u64)t;
+        t >>= 64;
+    }
+    lo[4] = (u64)t;
+    const u64 m = lo[0] * R_N0INV;
+    t = ((u128)m * R_LIMBS[0] + lo[0]) >> 64;
+    for (int j = 1; j < 4; j++) {
+        t += (u128)m * R_LIMBS[j] + lo[j];
+        acc[j - 1] = (u64)t;
+        t >>= 64;
+    }
+    acc[3] = (u64)t + lo[4];
+    bool ge = true;                     // acc >= r ?
+    for (int j = 3; j >= 0; j--) {
+        if (acc[j] != R_LIMBS[j]) { ge = acc[j] > R_LIMBS[j]; break; }
+    }
+    if (ge) {
+        u128 borrow = 0;
+        for (int j = 0; j < 4; j++) {
+            u128 d = (u128)acc[j] - R_LIMBS[j] - borrow;
+            acc[j] = (u64)d;
+            borrow = (d >> 64) & 1;
+        }
+    }
+}
+
+
 extern "C" {
 
 // prod_i e(P_i, Q_i) == 1 ?  (multi-pairing: miller loops multiplied,
@@ -1218,50 +1345,79 @@ int bls381_pairing_check(const uint8_t* g1s, const uint8_t* g2s,
 // prime factors).
 int bls381_g1_decompress(uint8_t* out96, const uint8_t* in48) {
     ensure_init();
-    uint8_t flags = in48[0];
-    if (!(flags & 0x80)) return 0;
-    if (flags & 0x40) {                 // infinity: canonical form only
-        if (flags != 0xC0) return 0;
-        for (int i = 1; i < 48; i++) {
-            if (in48[i]) return 0;
+    G1A p;
+    return g1_decode(p, out96, in48);
+}
+
+// A combine's shares in ONE call: n compressed points in, n affine
+// points and n verdicts out. Per share exactly bls381_g1_decompress's
+// checks in its order, then the deterministic membership test
+// g1_in_subgroup — every share, never a sample or a random linear
+// combination. Verdicts: 1 point, 2 canonical infinity, 0 invalid
+// encoding, 3 on the curve but outside the order-r subgroup.
+// Shares are independent, so a large set is cut into equal runs, one a
+// hardware thread (the caller's thread takes the first): at least
+// G1_SHARES_PER_THREAD a run, which leaves a replica's flush of a few
+// shares on the caller's thread alone.
+static const int G1_SHARES_PER_THREAD = 32;
+
+static void g1_decompress_run(uint8_t* out96, uint8_t* verdicts,
+                              const uint8_t* in48, int lo, int hi) {
+    for (int i = lo; i < hi; i++) {
+        G1A p;
+        int v = g1_decode(p, out96 + (size_t)i * 96, in48 + (size_t)i * 48);
+        if (v == 1 && !g1_in_subgroup(p)) v = 3;
+        verdicts[i] = (uint8_t)v;
+    }
+}
+
+void bls381_g1_decompress_batch(uint8_t* out96, uint8_t* verdicts,
+                                const uint8_t* in48, int n) {
+    ensure_init();
+    int runs = (int)std::thread::hardware_concurrency();
+    if (runs > n / G1_SHARES_PER_THREAD) runs = n / G1_SHARES_PER_THREAD;
+    if (runs < 1) runs = 1;
+    const int per = (n + runs - 1) / runs;
+    std::vector<std::thread> others;
+    others.reserve(runs);
+    int next = per;                     // the first share no thread took
+    try {
+        for (; next < n; next += per) {
+            others.emplace_back(g1_decompress_run, out96, verdicts, in48,
+                                next, next + per < n ? next + per : n);
         }
-        return 2;
+    } catch (const std::system_error&) {
+        // the host gave no further thread: the caller's takes the rest
     }
-    uint8_t xbe[48];
-    memcpy(xbe, in48, 48);
-    xbe[0] &= 0x1F;
-    // canonical: x < p (big-endian compare; P_BE set in ensure_init)
-    int cmp = memcmp(xbe, P_BE, 48);
-    if (cmp >= 0) return 0;
-    Fp x, x3, y2, y;
-    fp_from_be(x, xbe);
-    fp_sqr(x3, x);
-    fp_mul(x3, x3, x);
-    Fp b4;
-    {   // b = 4 in Montgomery form: 4 * ONE_M
-        Fp one;
-        memcpy(one.l, ONE_M, 48);
-        fp_add(b4, one, one);
-        fp_add(b4, b4, b4);
+    g1_decompress_run(out96, verdicts, in48, 0, per < n ? per : n);
+    if (next < n) g1_decompress_run(out96, verdicts, in48, next, n);
+    for (auto& t : others) t.join();
+}
+
+// The k^2 small products of a Lagrange interpolation at zero, out of
+// Python: out[i] = prod_{j != i} (ids[i] - ids[j]) * 2^(-64(n-1)) mod r
+// as four little-endian limbs — one fr_mul_word a factor, the caller
+// scales the power of two back. |ids[i]| < 2^62, so a difference fits.
+void bls381_lagrange_dens(uint64_t* out, const int64_t* ids, int n) {
+    for (int i = 0; i < n; i++) {
+        u64* acc = out + (size_t)i * 4;
+        acc[0] = 1; acc[1] = acc[2] = acc[3] = 0;
+        bool neg = false;
+        for (int j = 0; j < n; j++) {
+            if (j == i) continue;
+            int64_t d = ids[i] - ids[j];
+            if (d < 0) { d = -d; neg = !neg; }
+            fr_mul_word(acc, (u64)d);
+        }
+        if (neg && (acc[0] | acc[1] | acc[2] | acc[3])) {
+            u128 borrow = 0;            // acc = r - acc
+            for (int j = 0; j < 4; j++) {
+                u128 d = (u128)R_LIMBS[j] - acc[j] - borrow;
+                acc[j] = (u64)d;
+                borrow = (d >> 64) & 1;
+            }
+        }
     }
-    fp_add(y2, x3, b4);
-    // sqrt: y = y2^((p+1)/4)  (p ≡ 3 mod 4); SQRT_EXP set in ensure_init
-    fp_pow(y, y2, SQRT_EXP, 6);
-    Fp chk;
-    fp_sqr(chk, y);
-    if (!fp_eq(chk, y2)) return 0;      // not a QR: off curve
-    // sign selection: flag 0x20 = y lexicographically greater than p/2
-    uint8_t ybe[48];
-    fp_to_be(ybe, y);
-    // greater iff 2y > p  <=>  y > (p-1)/2: compare 2*y vs p in plain ints
-    bool greater = memcmp(ybe, P_HALF_BE, 48) > 0;
-    if (greater != !!(flags & 0x20)) {
-        fp_neg(y, y);
-        fp_to_be(ybe, y);
-    }
-    memcpy(out96, xbe, 48);
-    memcpy(out96 + 48, ybe, 48);
-    return 1;
 }
 
 // Square root in Fp via one fp_pow (p ≡ 3 mod 4): the Python-side modexp

@@ -29,7 +29,7 @@ class NativeBuildError(RuntimeError):
     pass
 
 
-_FLAGS = ["-O3", "-std=c++17", "-shared", "-fPIC", "-fno-plt"]
+_FLAGS = ["-O3", "-std=c++17", "-shared", "-fPIC", "-fno-plt", "-pthread"]
 
 
 def load(name: str) -> ctypes.CDLL:

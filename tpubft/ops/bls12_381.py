@@ -33,14 +33,14 @@ def g1_curve() -> Curve:
 
 
 SCALAR_BITS = 255
+_SCALAR_MASK = (1 << SCALAR_BITS) - 1
 
 
 def _bits_msb_batch(scalars: Sequence[int]) -> np.ndarray:
-    out = np.zeros((SCALAR_BITS, len(scalars)), np.int32)
-    for j, k in enumerate(scalars):
-        for i in range(SCALAR_BITS):
-            out[i, j] = (k >> (SCALAR_BITS - 1 - i)) & 1
-    return out
+    """(255, B) int32; row i holds bit 254 - i of every scalar."""
+    raw = b"".join((k & _SCALAR_MASK).to_bytes(32, "big") for k in scalars)
+    bits = np.unpackbits(np.frombuffer(raw, np.uint8).reshape(-1, 32), axis=1)
+    return np.ascontiguousarray(bits[:, 1:].T, dtype=np.int32)
 
 
 @functools.partial(jax.jit, static_argnums=())
@@ -58,22 +58,22 @@ def msm_kernel(bits: jnp.ndarray, px: jnp.ndarray, py: jnp.ndarray,
 
 
 def _prep_msm(points: Sequence, scalars: Sequence[int], m: int):
-    """Pad an n-point MSM to m slots (identity padding) -> device arrays."""
+    """Pad an n-point MSM to m slots (identity padding) -> device arrays.
+    Only the real points are converted; a padding slot (and a None
+    point) is zero limbs, zero bits and `infinity` set. (This function
+    keeps its length: the kernels below stay on their source lines.)"""
     cv = g1_curve()
-    n = len(points)
-    infinity = np.zeros(m, bool)
-    pts: List[Tuple[int, int]] = []
-    ks: List[int] = []
-    for i in range(m):
-        if i < n and points[i] is not None:
-            pts.append(points[i])
-            ks.append(scalars[i] % ref.R)
-        else:
-            pts.append((0, 0))
-            ks.append(0)
-            infinity[i] = True
-    px, py = cv.affine_to_device(pts)
-    bits = _bits_msb_batch(ks)
+    live = [i for i, p in enumerate(points) if p is not None]
+    infinity = np.ones(m, bool)
+    infinity[live] = False
+    # the arrays are born zero, so the padding costs nothing
+    px = np.zeros((cv.f.nl, m), np.int32)
+    py = np.zeros((cv.f.nl, m), np.int32)
+    px[:, live], py[:, live] = cv.affine_to_device(
+        [points[i] for i in live])
+    bits = np.zeros((SCALAR_BITS, m), np.int32)
+    bits[:, live] = _bits_msb_batch(
+        [scalars[i] % ref.R for i in live])
     return bits, px, py, infinity
 
 

@@ -267,6 +267,21 @@ class Field:
         """cond: (batch,) bool; a,b: (NL, batch)."""
         return jnp.where(cond[None, :], a, b)
 
+    # ---------- host: a batch of ints at once ----------
+    # (below the traced methods: their source lines stay where they were)
+    def from_ints(self, xs) -> np.ndarray:
+        """Host: python ints -> Montgomery limb array (NL, B), column j
+        equal to from_int(xs[j]); built as one array, not limb by limb."""
+        nbytes = (self.nl * LIMB_BITS + 7) // 8
+        raw = b"".join((x * self.R % self.p).to_bytes(nbytes, "little")
+                       for x in xs)
+        bits = np.unpackbits(
+            np.frombuffer(raw, np.uint8).reshape(-1, nbytes),
+            axis=1, bitorder="little")[:, :self.nl * LIMB_BITS]
+        weights = 1 << np.arange(LIMB_BITS, dtype=np.int32)
+        limbs = bits.reshape(-1, self.nl, LIMB_BITS).astype(np.int32) @ weights
+        return np.ascontiguousarray(limbs.T)
+
 
 @functools.lru_cache(maxsize=None)
 def get_field(p: int, n_limbs: Optional[int] = None) -> Field:

@@ -150,6 +150,6 @@ class Curve:
     # ---- host helpers ----
     def affine_to_device(self, pts) -> Tuple[np.ndarray, np.ndarray]:
         """Host: list of (x, y) ints -> Montgomery limb arrays (NL, B)."""
-        xs = np.stack([self.f.from_int(x) for x, _ in pts], axis=-1)
-        ys = np.stack([self.f.from_int(y) for _, y in pts], axis=-1)
+        xs = self.f.from_ints([x for x, _ in pts])
+        ys = self.f.from_ints([y for _, y in pts])
         return xs, ys
