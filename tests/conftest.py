@@ -36,3 +36,15 @@ def scalar_engine():
     finally:
         del os.environ["TPUBFT_NO_OPENSSL"]
         cpu._openssl.cache_clear()
+
+
+@pytest.fixture(autouse=True, scope="session")
+def few_ecdsa_lanes():
+    """ops/ecdsa pads every device launch to DEVICE_LANES = 128, one
+    program on the chip; XLA-CPU takes 5 s to run that many lanes of
+    the ladder. The tests' launches have 16 (larger batches split, as
+    above 128 on the chip); test_ecdsa_batch pins the constant."""
+    from tpubft.ops import ecdsa
+    real, ecdsa.DEVICE_LANES = ecdsa.DEVICE_LANES, 16
+    yield real
+    ecdsa.DEVICE_LANES = real

@@ -459,3 +459,23 @@ def test_ecdsa_verifier_batch_seam():
     got = v.verify_batch(items)
     assert got == [v.verify(m, sg) for m, sg in items]
     assert got == [i != 3 for i in range(8)]
+
+
+def test_every_launch_has_one_lane_count(few_ecdsa_lanes, monkeypatch):
+    """One program whatever the batch: a launch is padded to
+    DEVICE_LANES exactly and a larger batch split, each chunk its own
+    aggregate, a forged item failing alone."""
+    assert few_ecdsa_lanes == 128        # the chip's, outside the tests
+    seen = []
+    real = ops_ecdsa.rlc_kernel("secp256k1")
+    monkeypatch.setattr(ops_ecdsa, "DEVICE_LANES", 4)
+    monkeypatch.setattr(
+        ops_ecdsa, "rlc_kernel",
+        lambda _c: lambda *a: seen.append(a[2].shape[1]) or real(*a))
+    signer = cpu.make_signer("ecdsa-secp256k1", seed=b"lanes")
+    items = [(b"m%d" % i, signer.sign(b"m%d" % i), signer.public_bytes())
+             for i in range(10)]
+    items[6] = (b"forged", items[6][1], items[6][2])
+    got = ops_ecdsa.rlc_verify_batch("secp256k1", items).tolist()
+    assert got == [i != 6 for i in range(10)]
+    assert set(seen) == {4} and len(seen) >= 3 + 2

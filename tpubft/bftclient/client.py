@@ -23,6 +23,7 @@ from tpubft.comm.interfaces import ICommunication, IReceiver
 from tpubft.consensus import messages as m
 from tpubft.consensus.keys import ClusterKeys
 from tpubft.consensus.replicas_info import ReplicasInfo
+from tpubft.utils.metrics import Component
 from tpubft.utils.racecheck import make_lock
 
 
@@ -47,6 +48,17 @@ class ClientConfig:
     # degenerates to the old fixed cadence.
     retry_timeout_ms: int = 250
     retry_max_ms: int = 2000
+    # the retry timer FOLLOWS the client's own measured reply latency
+    # (upstream SimpleClientImp's DynamicUpperLimitWithSimpleFilter in
+    # spirit): once a write has completed, the first retry — which is
+    # the broadcast — waits for mean + this many deviations of the
+    # send->quorum time (upstream: numberOfStandardDeviationsToTolerate),
+    # never less than retry_timeout_ms and never more than a third of
+    # the request's own budget (a broadcast always fits the deadline),
+    # so a cluster that answers in seconds is not sent every write n
+    # times over at 250 ms, and a dead primary is still passed one
+    # typical reply time later.
+    retry_latency_deviations: float = 2.0
     request_timeout_ms: int = 10000
     # optimistic-reply contract (ISSUE 18): a SIGNED reply is verified
     # against the sender's ed25519 key and dropped on mismatch, always.
@@ -64,6 +76,32 @@ def decorrelated_backoff(base_s: float, cap_s: float, prev_s: float,
     state, tests call it directly)."""
     r = (rng or random).uniform(base_s, max(base_s, prev_s * 3))
     return min(max(cap_s, base_s), r)
+
+
+class ReplyLatency:
+    """A client's moving estimate of send -> reply quorum for its
+    writes: smoothed mean and mean deviation (RFC 6298's arithmetic,
+    gains 1/8 and 1/4), read as mean + k deviations."""
+
+    __slots__ = ("mean_s", "dev_s", "_k")
+
+    def __init__(self, deviations: float) -> None:
+        self.mean_s: Optional[float] = None
+        self.dev_s = 0.0
+        self._k = deviations
+
+    def note(self, seconds: float) -> None:
+        if self.mean_s is None:
+            self.mean_s, self.dev_s = seconds, seconds / 2
+        else:
+            self.dev_s += (abs(seconds - self.mean_s) - self.dev_s) / 4
+            self.mean_s += (seconds - self.mean_s) / 8
+
+    def upper_s(self) -> Optional[float]:
+        """None until a write has completed."""
+        if self.mean_s is None:
+            return None
+        return self.mean_s + self._k * self.dev_s
 
 
 class TimeoutError_(Exception):
@@ -92,6 +130,15 @@ class BftClient(IReceiver):
         # f+1 MATCHING SIGNED replies is the acceptance rule — each
         # signature must check out before the reply may count)
         self._verifiers: Dict[int, object] = {}
+        self._latency = ReplyLatency(cfg.retry_latency_deviations)
+        # writes only (a read is broadcast by design): messages sent,
+        # retry ticks, and messages that went to more than one replica
+        self.metrics = Component(f"bftclient{cfg.client_id}")
+        self._m_sends = self.metrics.register_counter("client_sends")
+        self._m_retransmissions = self.metrics.register_counter(
+            "client_retransmissions")
+        self._m_broadcasts = self.metrics.register_counter(
+            "client_broadcasts")
 
     def start(self) -> None:
         if not self._started:
@@ -303,18 +350,26 @@ class BftClient(IReceiver):
         always broadcast: each replica answers from local state and the
         client needs f+1 matching replies from DISTINCT replicas.
 
+        The first retry of a write waits for the client's own measured
+        reply latency (ClientConfig.retry_latency_deviations), floor
+        retry_timeout_ms, at most a third of this request's budget.
         Retries back off exponentially with decorrelated jitter (see
         ClientConfig.retry_timeout_ms/retry_max_ms); write retries
         additionally target only the replicas that have not yet replied
         for the still-pending seqs — under overload a client's pressure
         on the cluster falls with every tick instead of compounding at
         a fixed broadcast cadence."""
-        deadline = time.monotonic() + (timeout_ms
-                                       or self.cfg.request_timeout_ms) / 1e3
+        t_sent = time.monotonic()
+        budget_s = (timeout_ms or self.cfg.request_timeout_ms) / 1e3
+        deadline = t_sent + budget_s
         base_s = self.cfg.retry_timeout_ms / 1e3
+        measured_s = None if read_only else self._latency.upper_s()
+        if measured_s is not None:
+            base_s = max(base_s, min(measured_s, budget_s / 3))
         cap_s = max(self.cfg.retry_max_ms / 1e3, base_s)
         delay_s = base_s
         first = True
+        spread = False          # has this message gone to > 1 replica?
         pending = set(seqs)
         while time.monotonic() < deadline and pending:
             if (first and not read_only
@@ -330,6 +385,12 @@ class BftClient(IReceiver):
                 targets = self._retry_targets(pending)
             for r in targets:
                 self.comm.send(r, raw)
+            if not read_only:
+                (self._m_sends if first
+                 else self._m_retransmissions).inc()
+                if len(targets) > 1 and not spread:
+                    spread = True
+                    self._m_broadcasts.inc()
             if not first:
                 delay_s = decorrelated_backoff(base_s, cap_s, delay_s)
             wait_until = min(deadline, time.monotonic()
@@ -341,6 +402,8 @@ class BftClient(IReceiver):
                     break
             pending = {rs for rs in pending
                        if not self._done[rs].is_set()}
+        if not pending and not read_only:
+            self._latency.note(time.monotonic() - t_sent)
         return pending
 
     def _forget(self, seqs: List[int]) -> None:

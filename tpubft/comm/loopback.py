@@ -31,7 +31,12 @@ class LoopbackBus:
     def __init__(self) -> None:
         self._endpoints: Dict[NodeNum, "LoopbackCommunication"] = {}
         self._hooks: list[Hook] = []
-        self._q: "queue.Queue" = queue.Queue()
+        # SimpleQueue, not Queue: put() is one C call under the
+        # interpreter lock. Queue.put takes a Python-level mutex, and
+        # with every thread of a large in-process cluster posting, a
+        # holder that lost the interpreter lock parked all the others
+        # behind it (31 replicas: a third of the threads stood there)
+        self._q: "queue.SimpleQueue" = queue.SimpleQueue()
         self._thread: Optional[threading.Thread] = None
         self._lock = make_lock("loopback_bus")
         self._closed = False

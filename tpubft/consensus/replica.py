@@ -468,6 +468,11 @@ class Replica(IReceiver):
                 self._check_missing_data)
         # ReqMissingData bookkeeping: seq -> (first_noticed, asks_sent)
         self._missing_since: Dict[int, list] = {}
+        # gap resends: peer -> (first seq resent, when), see
+        # _on_replica_status (b)
+        self._gap_resent: Dict[int, tuple] = {}
+        # and gaps noticed: peer -> (first seq missing, when first seen)
+        self._gap_seen: Dict[int, tuple] = {}
         # restart-ready votes per wedge point (ReplicaRestartReadyMsg);
         # keyed by point so a later re-wedge starts a fresh election
         self._restart_announced: Optional[int] = None
@@ -481,6 +486,7 @@ class Replica(IReceiver):
         from tpubft.crypto import systems
         self.aggregator.register(systems.METRICS)
         self.m_executed = self.metrics.register_counter("executed_requests")
+        self.m_gap_resends = self.metrics.register_counter("gap_resends")
         self.m_preprepares = self.metrics.register_counter("sent_preprepares")
         self.m_fast_commits = self.metrics.register_counter("fast_path_commits")
         self.m_slow_commits = self.metrics.register_counter("slow_path_commits")
@@ -2877,6 +2883,7 @@ class Replica(IReceiver):
             self._broadcast(self._my_restart_vote)
 
     MAX_GAP_RESEND = 8
+    GAP_EVERYONE_AFTER = 4      # beacon periods, _on_replica_status (b)
 
     def _my_capabilities(self) -> int:
         """CAP_* bitmap this replica advertises on status beacons.
@@ -2914,8 +2921,36 @@ class Replica(IReceiver):
         # PrePrepare + commit certificate from persisted state
         if msg.last_executed_seq >= self.last_executed:
             return
-        st = self.storage.load()
+        # a peer one slot behind is, in a healthy cluster, a peer that
+        # has not executed YET: were every peer ahead of it to answer
+        # every beacon, n replicas would push each other whole
+        # PrePrepares n^2 times a second, each verified again on
+        # arrival (at n=31 that was the interpreter's main work). A
+        # fresh gap is answered by the primary and by the f+1 peers that
+        # follow the lagging one in ring order — at least one of those
+        # is honest; if that one lags too it is served by ITS followers
+        # first, and so round the ring. A gap that has stood for
+        # GAP_EVERYONE_AFTER beacon periods is answered by everyone, so
+        # catching up never rests on the ring alone (followers down, or
+        # behind the same gap). Nobody answers the same gap twice
+        # within two beacon periods.
         first = msg.last_executed_seq + 1
+        now = time.monotonic()
+        period = self.cfg.status_report_timer_ms / 1000.0
+        seen = self._gap_seen.get(peer)
+        if seen is None or seen[0] != first:
+            seen = self._gap_seen[peer] = (first, now)
+        if not (self.is_primary
+                or 1 <= (self.id - peer) % self.info.n <= self.info.f + 1
+                or now - seen[1] >= self.GAP_EVERYONE_AFTER * period):
+            return
+        last = self._gap_resent.get(peer)
+        if last is not None and last[0] == first \
+                and now - last[1] < 2 * period:
+            return
+        self._gap_resent[peer] = (first, now)
+        self.m_gap_resends.inc()
+        st = self.storage.load()
         for seq in range(first, min(self.last_executed,
                                     first + self.MAX_GAP_RESEND - 1) + 1):
             entry = st.seq_states.get(seq)

@@ -147,6 +147,18 @@ class SigManager:
         # key, not per retransmitted verify)
         self.ecdsa_batched_host = self.metrics.register_counter(
             "ecdsa_batched_host")
+        # every ECDSA signature this manager verified afresh (memo
+        # misses), by the tier that answered: the device kernel, or a
+        # host engine (the per-principal verifiers, and the batched
+        # engine below the crossover — `ecdsa_batched_host` is that
+        # part alone). Their ratio is the device's share of the work.
+        self.ecdsa_device_items = self.metrics.register_counter(
+            "ecdsa_device_items")
+        self.ecdsa_host_items = self.metrics.register_counter(
+            "ecdsa_host_items")
+        self._counts_ecdsa = any(
+            "ecdsa" in str(getattr(keys, name, ""))
+            for name in ("client_sig_scheme", "replica_sig_scheme"))
         self.pubkey_memo_hits = self.metrics.register_counter(
             "pubkey_memo_hits")
         # bounded-LRU evictions in the scalar engine's per-principal
@@ -411,19 +423,31 @@ class SigManager:
             # several in-process replicas share the engine's caches
             sink = scalar_engine.new_stats_sink()
             with scalar_engine.attribute_stats(sink):
-                self._verify_pending(items, pending, out, keys, aliased,
-                                     pks, seq, view_scoped)
-            self._fold_ecdsa_stats(sink)
+                on_device = self._verify_pending(
+                    items, pending, out, keys, aliased, pks, seq,
+                    view_scoped)
+            batched_host = self._fold_ecdsa_stats(sink)
+            if self._counts_ecdsa:
+                ecdsa = sum("ecdsa" in self._scheme_of(aliased[i])
+                            for i in pending)
+                # a cross-principal batch rides the device but for the
+                # groups verify_batch_mixed kept below its crossover
+                device = max(0, ecdsa - batched_host) if on_device else 0
+                if device:
+                    self.ecdsa_device_items.inc(device)
+                if ecdsa - device:
+                    self.ecdsa_host_items.inc(ecdsa - device)
         for ok in out:
             (self.sigs_verified if ok else self.sig_failures).inc()
         return out
 
     def _verify_pending(self, items, pending: List[int], out: List[bool],
                         keys: List[Optional[Tuple]], aliased, pks,
-                        seq: Optional[int], view_scoped: bool) -> None:
+                        seq: Optional[int], view_scoped: bool) -> bool:
         """Memo-miss residue: one cross-principal device dispatch when
         configured and the sub-batch is big enough, else the grouped
-        host path. Successful current-key verdicts are memoized."""
+        host path. Successful current-key verdicts are memoized.
+        Returns whether the device batch answered."""
         sub = [items[i] for i in pending]
         verdicts = None
         use_device = (self._batch_fn is not None
@@ -454,7 +478,8 @@ class SigManager:
                             "rerouting to scalar engines",
                             len(sub), exc_info=True)
                 self.degraded_verifies.inc(len(sub))
-        if verdicts is None:
+        on_device = verdicts is not None
+        if not on_device:
             verdicts, via_grace = self._verify_batch_grouped(
                 sub, seq, view_scoped)
             self.scalar_fallbacks.inc(len(sub))
@@ -464,18 +489,21 @@ class SigManager:
             # the memo must never outlive the grace window
             if ok and not grace and keys[i] is not None:
                 self._memo_add(keys[i])
+        return on_device
 
-    def _fold_ecdsa_stats(self, sink) -> None:
+    def _fold_ecdsa_stats(self, sink) -> int:
         """Fold this manager's attributed scalar-engine events into its
         metrics component + batch-shape histogram (covers BOTH host
         routes — the grouped fallback and verify_batch_mixed's
         below-crossover ride, the default on a cpu backend). The drain
         is atomic per sink (StatsSink.drain swaps under the sink lock),
         so concurrent drains — two replicas' managers, or a drain
-        racing a straggler increment — never lose or double-count."""
+        racing a straggler increment — never lose or double-count.
+        Returns the items the batched host engine verified."""
         stats = sink.drain()
-        if stats["host_items"]:
-            self.ecdsa_batched_host.inc(stats["host_items"])
+        host_items = stats["host_items"]
+        if host_items:
+            self.ecdsa_batched_host.inc(host_items)
         if stats["host_ns"]:
             self.ecdsa_host_us.inc(stats["host_ns"] // 1000)
         if stats["hits"]:
@@ -486,6 +514,7 @@ class SigManager:
             self.ecdsa_comb_evictions.inc(stats["comb_evictions"])
         for size in stats["host_sizes"]:
             self._h_ecdsa_host_batch.record(size)
+        return host_items
 
     def _verify_batch_grouped(self, items: Sequence[Tuple[int, bytes, bytes]],
                               seq: Optional[int], view_scoped: bool

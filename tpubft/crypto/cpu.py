@@ -200,19 +200,23 @@ class EcdsaVerifier(IVerifier):
             raise ValueError(f"unknown curve {curve}")
         self.curve_name = curve
         self.public_key_bytes = public_key_bytes
+        # SEC1 uncompressed only, whatever backs the verifier: OpenSSL
+        # would decode a compressed point too, the batched host engine
+        # and the device tier (crypto/scalar._pk_entry) would not, and a
+        # verdict must not depend on the tier that gave it
+        if len(public_key_bytes) != 65 or public_key_bytes[0] != 0x04:
+            raise ValueError("invalid SEC1 uncompressed public key")
         ossl = _openssl()
         if ossl is not None:
-            # raises ValueError on a malformed/off-curve point, matching
-            # the scalar-path checks below
+            # raises ValueError on an off-curve point, matching the
+            # scalar-path check below
             self._pk = ossl.ec.EllipticCurvePublicKey.from_encoded_point(
                 _ossl_curve(ossl, curve), public_key_bytes)
         else:
             self._pk = None
-            if (len(public_key_bytes) != 65 or public_key_bytes[0] != 0x04
-                    or not scalar.ecdsa_on_curve(
-                        int.from_bytes(public_key_bytes[1:33], "big"),
-                        int.from_bytes(public_key_bytes[33:], "big"),
-                        curve)):
+            if not scalar.ecdsa_on_curve(
+                    int.from_bytes(public_key_bytes[1:33], "big"),
+                    int.from_bytes(public_key_bytes[33:], "big"), curve):
                 raise ValueError("invalid SEC1 uncompressed public key")
 
     def verify(self, data: bytes, sig: bytes) -> bool:

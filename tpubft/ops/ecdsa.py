@@ -47,8 +47,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from tpubft.crypto import scalar as _scalar
-from tpubft.ops.field import (get_field, int_to_limbs,
-                              pad_pow2 as _pad_pow2)
+from tpubft.ops.field import get_field, int_to_limbs
 from tpubft.ops.weierstrass import Curve
 
 CURVES = {
@@ -70,7 +69,8 @@ CURVES = {
 @functools.lru_cache(maxsize=None)
 def get_curve(name: str) -> Curve:
     c = CURVES[name]
-    return Curve(get_field(c["p"]), c["a"], c["b"], c["gx"], c["gy"], c["n"])
+    return Curve(get_field(c["p"]), c["a"], c["b"], c["gx"], c["gy"], c["n"],
+                 fused=True)
 
 
 class PreparedEcdsaBatch(NamedTuple):
@@ -301,7 +301,17 @@ def rlc_fold_body(cv: Curve):
 
 
 def make_rlc_kernel(curve_name: str):
-    return jax.jit(rlc_fold_body(get_curve(curve_name)))
+    """The fold as ONE named program, `ecdsa_rlc_kernel` for every
+    curve and shape: a trace or a compile log finds it by that name
+    (cellbench/kernels/ecdsa.json matches it)."""
+    body = rlc_fold_body(get_curve(curve_name))
+
+    def ecdsa_rlc_kernel(u1_bits, u2_bits, qx, qy, xr_m, xrpn_m, wrap_ok,
+                         active, a_m):
+        return body(u1_bits, u2_bits, qx, qy, xr_m, xrpn_m, wrap_ok,
+                    active, a_m)
+
+    return jax.jit(ecdsa_rlc_kernel)
 
 
 @functools.lru_cache(maxsize=None)
@@ -311,22 +321,34 @@ def rlc_kernel(curve_name: str):
     return make_rlc_kernel(curve_name)
 
 
+# lanes of EVERY single-device launch: a batch is padded to exactly this
+# many and a larger one split, so batches of any size — admission's, a
+# PrePrepare's, a bisection's halves, whatever the autotuner does to the
+# floor and the crossover — run ONE compiled program (a shape first met
+# under traffic is a compile under traffic). A launch costs the same at
+# 32, 64 and 128 lanes (PERF.md §6, PR 33); a power of two, because the
+# fold halves the lanes, and whole vector registers for ops/field_pallas.
+DEVICE_LANES = 128
+
+
 def _rlc_launch(curve_name: str, prep: PreparedRlcBatch,
                 idxs: Sequence[int]) -> bool:
     """One aggregate device launch over a subset of prepared columns,
-    padded to a power of two (inactive padding lanes contribute zero)."""
-    m = _pad_pow2(max(1, len(idxs)))
+    padded to DEVICE_LANES; inactive padding lanes contribute zero."""
+    m = DEVICE_LANES
     sel = list(idxs) + [idxs[0]] * (m - len(idxs))
     active = np.zeros(m, bool)
     active[:len(idxs)] = prep.host_valid[list(idxs)]
-    from tpubft.ops.dispatch import device_section
-    with device_section("ecdsa", batch=len(idxs)):
-        ok = rlc_kernel(curve_name)(
-            prep.u1_bits[:, sel], prep.u2_bits[:, sel],
+    # the columns are gathered BEFORE the gate: host work under the
+    # gate is a queue of replicas waiting for it (2-33 ms passed there
+    # between the gate and the kernel's first step, PR 33)
+    args = (prep.u1_bits[:, sel], prep.u2_bits[:, sel],
             prep.qx[:, sel], prep.qy[:, sel],
             prep.xr_m[:, sel], prep.xrpn_m[:, sel],
-            prep.wrap_ok[sel], jnp.asarray(active), prep.a_m[:, sel])
-        return bool(np.asarray(ok))
+            prep.wrap_ok[sel], active, prep.a_m[:, sel])
+    from tpubft.ops.dispatch import device_section
+    with device_section("ecdsa", batch=len(idxs)):
+        return bool(np.asarray(rlc_kernel(curve_name)(*args)))
 
 
 # the RLC aggregate rides the mesh only past this per-shard lane
@@ -397,6 +419,13 @@ def rlc_verify_batch(curve_name: str,
     def descend(idxs: List[int]) -> None:
         live = [i for i in idxs if prep.host_valid[i]]
         if not live:
+            return
+        if len(live) > DEVICE_LANES:
+            # more than one launch holds: each chunk is its own
+            # aggregate (the coefficients bind the whole transcript, so
+            # any subset folds soundly — bisection relies on the same)
+            for at in range(0, len(live), DEVICE_LANES):
+                descend(live[at:at + DEVICE_LANES])
             return
         if _rlc_launch(curve_name, prep, live):
             return

@@ -29,8 +29,11 @@ class WPoint(NamedTuple):
 
 class Curve:
     def __init__(self, field: Field, a: int, b: int,
-                 gx: int, gy: int, order: int):
+                 gx: int, gy: int, order: int, fused: bool = False):
         self.f = field
+        # a = 0 only: on a TPU, a double-scalar ladder over whole-register
+        # batches runs as ops/field_pallas's one kernel
+        self.fused = fused and a % field.p == 0
         self.a = a % field.p
         self.b = b % field.p
         self.order = order
@@ -117,6 +120,11 @@ class Curve:
 
     def double_scalar_mul_bits(self, bits1, p1: WPoint, bits2, p2: WPoint) -> WPoint:
         """[k1]P1 + [k2]P2 with shared doublings (Shamir's trick)."""
+        if self.fused:
+            from tpubft.ops import field_pallas
+            if field_pallas.usable(p1.x):
+                return WPoint(*field_pallas.double_scalar_mul_bits(
+                    self, bits1, p1, bits2, p2))
         def step(acc, bb):
             b1, b2 = bb
             acc = self.add(acc, acc)
