@@ -1,7 +1,11 @@
 """Storage layer tests: IDBClient semantics across backends, native engine
 crash recovery, metadata transactions (reference test model:
 storage/test/, kvbc memorydb-backed unit tests)."""
+import ctypes
 import os
+import random
+import threading
+import time
 
 import pytest
 
@@ -294,6 +298,190 @@ def test_native_auto_compaction_waits_for_garbage(tmp_path, monkeypatch,
     assert db._lib.kvlog_live_bytes(db._h) == live
     assert {k: db.get(k) for k in model} == model
     assert db.count() == len(model)
+    db.close()
+
+
+# the binding rule of `tpubft/storage/native.py`: a call keeps the
+# interpreter lock iff its work is one lookup of the in-memory index
+_ONE_LOOKUP = ("kvlog_get", "kvlog_free", "kvlog_count", "kvlog_wal_bytes",
+               "kvlog_live_bytes")
+_GROWS_OR_DISK = ("kvlog_open", "kvlog_close", "kvlog_apply", "kvlog_sync",
+                  "kvlog_scan", "kvlog_compact", "kvlog_checkpoint")
+
+
+@pytest.mark.parametrize("name,keeps", [(n, True) for n in _ONE_LOOKUP]
+                         + [(n, False) for n in _GROWS_OR_DISK])
+def test_an_engine_call_keeps_the_interpreter_lock_iff_it_is_one_lookup(
+        name, keeps):
+    from tpubft.storage.native import _lib
+    fn = getattr(_lib(), name)
+    assert bool(fn._flags_ & ctypes._FUNCFLAG_PYTHONAPI) == keeps
+
+
+def _join_all(threads, timeout: float = 30.0) -> None:
+    """Join each thread within `timeout`: a deadlock fails, never hangs."""
+    deadline = time.monotonic() + timeout
+    for t in threads:
+        t.join(max(0.0, deadline - time.monotonic()))
+    assert not [t.name for t in threads if t.is_alive()]
+
+
+def test_eight_threads_on_one_handle_end_as_a_memory_store_fed_the_same(
+        tmp_path):
+    """Gets, writes, group writes and syncs from eight threads at once on
+    one handle; each thread owns its keys, so the order the handle took
+    the threads in cannot change what a store fed the same batches
+    holds."""
+    path = str(tmp_path / "db.kvlog")
+    db = NativeDB(path, sync_writes=False, sync_families=(b"meta",))
+    fed = {t: [] for t in range(8)}          # each thread's batches
+    errors = []
+    stop_at = time.monotonic() + 2.0
+
+    def run(t):
+        rng = random.Random(t)
+        mine = {}                            # (family, key) -> value
+        i = 0
+
+        def row(batch):
+            fam = rng.choice((b"blk", b"meta"))
+            key = b"t%d-%d" % (t, rng.randrange(48))
+            if rng.random() < 0.25:
+                batch.delete(key, fam)
+                mine[fam, key] = None
+            else:
+                mine[fam, key] = b"v%d-%d" % (t, i)
+                batch.put(key, mine[fam, key], fam)
+            return batch
+        try:
+            while time.monotonic() < stop_at:
+                i += 1
+                op = i % 4
+                if op == 0:
+                    for (fam, key), value in rng.sample(
+                            sorted(mine.items()), min(4, len(mine))):
+                        assert db.get(key, fam) == value
+                elif op == 1:
+                    fed[t].append(row(row(WriteBatch())))
+                    db.write(fed[t][-1])
+                elif op == 2:
+                    group = [row(WriteBatch()) for _ in range(3)]
+                    fed[t].extend(group)
+                    db.write_group(group)
+                else:
+                    db.sync()
+        except BaseException as e:  # noqa: BLE001 — reported below
+            errors.append(e)
+
+    threads = [threading.Thread(target=run, args=(t,), name=f"t{t}",
+                                daemon=True) for t in range(8)]
+    for t in threads:
+        t.start()
+    _join_all(threads)
+    assert not errors, errors
+    assert all(fed.values())
+    memory = MemoryDB()
+    for batches in fed.values():
+        for b in batches:
+            memory.write(b)
+    want = sorted(memory.scan_all())
+    assert sorted(db.scan_all()) == want
+    db.close()
+    db = NativeDB(path)
+    assert sorted(db.scan_all()) == want
+    db.close()
+
+
+class _HeldApply:
+    """The engine's library, its apply holding the handle lock until
+    `release` is set."""
+
+    def __init__(self, lib, entered, release):
+        self._lib, self._entered, self._release = lib, entered, release
+
+    def __getattr__(self, name):
+        return getattr(self._lib, name)
+
+    def kvlog_apply(self, handle, payload, n):
+        self._entered.set()
+        rc = self._lib.kvlog_apply(handle, payload, n)
+        self._release.wait(10)
+        return rc
+
+
+def test_a_read_queued_behind_a_slow_apply_leaves_the_interpreter_free(
+        tmp_path):
+    db = NativeDB(str(tmp_path / "db.kvlog"), sync_writes=False)
+    db.put(b"k", b"before")
+    big = WriteBatch()
+    for i in range(50_000):
+        big.put(b"row-%07d" % i, b"x" * 200, b"blk")
+    entered, release, stop = (threading.Event(), threading.Event(),
+                              threading.Event())
+    db._lib = _HeldApply(db._lib, entered, release)
+    got, ticks = [], [0]
+
+    def spin():
+        while not stop.is_set():
+            ticks[0] += 1
+    writer = threading.Thread(target=db.write, args=(big,), daemon=True)
+    reader = threading.Thread(target=lambda: got.append(db.get(b"k")),
+                              daemon=True)
+    spinner = threading.Thread(target=spin, daemon=True)
+    try:
+        writer.start()
+        assert entered.wait(10)
+        reader.start()
+        spinner.start()
+        time.sleep(0.2)
+        assert reader.is_alive() and not got      # the read waits ...
+        t0 = ticks[0]
+        time.sleep(0.2)
+        assert reader.is_alive() and ticks[0] > t0  # ... the rest runs
+    finally:
+        release.set()
+        stop.set()
+        _join_all([writer, reader, spinner])
+    assert got == [b"before"]
+    assert db.get(b"row-0049999", b"blk") == b"x" * 200
+    db.close()
+
+
+@pytest.mark.parametrize("store", ["memory", "native", "native_staged",
+                                   "native_pending"])
+def test_reads_counted_lock_kept_are_the_native_walks_reads(tmp_path, store):
+    """One narrow walk (native) and one wide walk (levels) a block, on the
+    store itself and through the read views that wrap it."""
+    import hashlib
+
+    from tpubft.durability import PendingStore
+    from tpubft.kvbc import sparse_merkle
+    from tpubft.kvbc.blockchain import _PendingView, _StagedReadView
+    from tpubft.kvbc.sparse_merkle import SparseMerkleTree
+    db = (MemoryDB() if store == "memory"
+          else NativeDB(str(tmp_path / "db.kvlog")))
+    view = {"native_staged": lambda: _StagedReadView(db, {}),
+            "native_pending": lambda: _PendingView(db, PendingStore("t"))
+            }.get(store, lambda: db)()
+    assert view.point_reads_keep_lock == (store != "memory")
+    tree = SparseMerkleTree(view, use_device=False)
+
+    def counters():
+        return dict(sparse_merkle.METRICS.snapshot()["counters"])
+    c0 = counters()
+    for block, width in enumerate((5, 200, 3)):
+        wb = WriteBatch()
+        tree.update_batch({b"k%d-%d" % (block, i):
+                           hashlib.sha256(b"%d" % i).digest()
+                           for i in range(width)}, batch=wb,
+                          version=block + 1)
+        db.write(wb)
+    c1 = counters()
+    reads = c1["smt_engine_reads"] - c0["smt_engine_reads"]
+    kept = (c1["smt_engine_reads_lock_kept"]
+            - c0["smt_engine_reads_lock_kept"])
+    assert reads > 0
+    assert kept == (0 if store == "memory" else reads)
     db.close()
 
 

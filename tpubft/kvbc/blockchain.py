@@ -96,6 +96,10 @@ class _StagedReadView(IDBClient):
         self._base = base
         self._overlay = overlay
 
+    @property
+    def point_reads_keep_lock(self) -> bool:
+        return self._base.point_reads_keep_lock
+
     def get(self, key: bytes, family: bytes = b"default"):
         pk = fkey(family, key)
         if pk in self._overlay:
@@ -145,6 +149,10 @@ class _PendingView(IDBClient):
     @property
     def base(self) -> IDBClient:
         return self._base
+
+    @property
+    def point_reads_keep_lock(self) -> bool:
+        return self._base.point_reads_keep_lock
 
     def get(self, key: bytes, family: bytes = b"default"):
         ent = self._store.lookup(fkey(family, key))

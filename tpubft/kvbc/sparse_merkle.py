@@ -68,12 +68,15 @@ _DEVICE_THRESHOLD = 192
 # what update_batch asked of the engine, process-wide (every tree of every
 # ledger in the process): leaves changed, how many of them the native walk
 # carried, node reads issued (the probes for the empty depth and the
-# sibling reads), and sibling lookups the empty depth answered with no
-# read. Totals only — nothing here is read back by the tree.
+# sibling reads), how many of those went to a store whose point reads
+# keep the interpreter lock (`IDBClient.point_reads_keep_lock`), and
+# sibling lookups the empty depth answered with no read. Totals only —
+# nothing here is read back by the tree.
 METRICS = Component("kvbc")
 _M_KEYS = METRICS.register_counter("smt_keys_updated")
 _M_NATIVE = METRICS.register_counter("smt_keys_native")
 _M_ENGINE_READS = METRICS.register_counter("smt_engine_reads")
+_M_READS_LOCK_KEPT = METRICS.register_counter("smt_engine_reads_lock_kept")
 _M_BOUNDED = METRICS.register_counter("smt_siblings_bounded")
 
 _U8P = ctypes.POINTER(ctypes.c_uint8)
@@ -276,7 +279,7 @@ class SparseMerkleTree:
             lib.free(index)
         wb.extend_encoded(rows)
         _M_NATIVE.inc(n)
-        _M_ENGINE_READS.inc(reads)
+        self._count_reads(reads)
         _M_BOUNDED.inc(defaulted.value)
         return root.raw
 
@@ -341,9 +344,15 @@ class SparseMerkleTree:
             bound = up
             self._level_rows(rows, depth - 1, changed, ver)
         wb.extend(rows, self._row_families(version))
-        _M_ENGINE_READS.inc(reads)
+        self._count_reads(reads)
         _M_BOUNDED.inc(bounded)
         return changed[0]
+
+    def _count_reads(self, reads: int) -> None:
+        """One walk's node reads, once a walk."""
+        _M_ENGINE_READS.inc(reads)
+        if self._db.point_reads_keep_lock:
+            _M_READS_LOCK_KEPT.inc(reads)
 
     def _empty_depth(self, bits: int) -> Tuple[int, int]:
         """-> (the least depth at which the path of leaf `bits` has no

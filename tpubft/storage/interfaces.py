@@ -172,6 +172,11 @@ class WriteBatch:
 class IDBClient(abc.ABC):
     """Abstract ordered KV store (db_interface.h:55)."""
 
+    # True where `get` never releases the interpreter lock (NativeDB's
+    # engine calls; memory stores run no C call at all and say False):
+    # what the `kvbc` counter `smt_engine_reads_lock_kept` reads
+    point_reads_keep_lock = False
+
     @abc.abstractmethod
     def get(self, key: bytes,
             family: bytes = DEFAULT_FAMILY) -> Optional[bytes]: ...

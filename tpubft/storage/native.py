@@ -14,10 +14,25 @@ from tpubft.storage.interfaces import (DEFAULT_FAMILY, IDBClient, StorageError,
 _U8P = ctypes.POINTER(ctypes.c_uint8)
 
 
+# The binding rule: a call keeps the interpreter lock iff its work is
+# bounded by one lookup of the in-memory index. Those go through a
+# `PyDLL` handle, which keeps the lock: releasing and retaking it costs
+# more than the call, and every retake queues behind whichever thread
+# took it meanwhile (a merkle walk reads ≈ 21 nodes a key). Every other
+# call (open, close, apply, sync, scan, compact, checkpoint) grows with
+# the data or touches the disk, and goes through the `CDLL` handle,
+# which releases the lock around it.
+_KEEP_LOCK = ("kvlog_get", "kvlog_free", "kvlog_count", "kvlog_wal_bytes",
+              "kvlog_live_bytes")
+
+
 def _lib():
     lib = load("kvlog")
     if getattr(lib, "_kvlog_typed", False):
         return lib
+    keep = ctypes.PyDLL(lib._name)
+    for name in _KEEP_LOCK:
+        setattr(lib, name, getattr(keep, name))
     lib.kvlog_open.restype = ctypes.c_void_p
     lib.kvlog_open.argtypes = [ctypes.c_char_p, ctypes.c_int]
     lib.kvlog_close.argtypes = [ctypes.c_void_p]
@@ -76,6 +91,8 @@ class NativeDB(IDBClient):
     re-derivable from the quorum). Ignored when sync_writes=True (every
     batch already syncs)."""
 
+    point_reads_keep_lock = True    # `get` is `_KEEP_LOCK` calls alone
+
     def __init__(self, path: str, sync_writes: bool = True,
                  compact_bytes: int = 64 << 20,
                  sync_families: Sequence[bytes] = ()) -> None:
@@ -87,16 +104,20 @@ class NativeDB(IDBClient):
         self._sync_writes = sync_writes
         self._sync_prefixes: FrozenSet[bytes] = frozenset(
             () if sync_writes else map(family_prefix, sync_families))
-        # ctypes releases the GIL around C calls, and the execution lane
-        # writes ledger/pages batches concurrently with the dispatcher's
-        # metadata batches on the SAME handle. The C engine is not
+        # The lane writes ledger/pages batches concurrently with the
+        # dispatcher's metadata batches on the SAME handle, and the
+        # engine calls that work on the log or scan the index release
+        # the interpreter lock (all but `_KEEP_LOCK`). The C engine is not
         # audited for lock-free concurrent access, so EVERY handle
         # operation — reads and scans included — serializes here. This
         # is a deliberate latency trade: a dispatcher point read can
         # block behind the lane's run commit (one buffered batch apply;
         # fsync only for sync-family batches, which originate on the
-        # dispatcher itself). Relaxing reads requires a C-side
-        # concurrency audit first.
+        # dispatcher itself). Waiting here releases the interpreter
+        # lock; a point read that holds this lock never waits on the
+        # engine's own mutex, so keeping the interpreter lock through
+        # it (`_KEEP_LOCK`) stalls no other thread on another call.
+        # Relaxing reads requires a C-side concurrency audit first.
         import threading
         self._write_mu = threading.Lock()
 
