@@ -18,6 +18,7 @@ from typing import Dict, List, Optional, Tuple
 from tpubft.consensus.replica import IRequestsHandler
 from tpubft.kvbc import (BLOCK_MERKLE, VERSIONED_KV, BlockUpdates,
                          KeyValueBlockchain)
+from tpubft.utils import flight
 from tpubft.utils import serialize as ser
 from tpubft.utils.racecheck import make_lock
 
@@ -196,7 +197,12 @@ class SkvbcHandler(IRequestsHandler):
             msg = unpack(request)
         except ser.SerializeError:
             return b""
+        # one ring span a read-only request: its wait for the lock the
+        # execution lane holds through every write it applies
+        t0 = time.monotonic_ns()
         with self._lock:
+            flight.record_span("ro_read_wait",
+                               (time.monotonic_ns() - t0) // 1000)
             return self._execute_read(msg)
 
     # ---- pre-execution (reference InternalCommandsHandler PRE_PROCESS) --

@@ -1424,7 +1424,12 @@ class Replica(IReceiver):
                                                 self.last_executed,
                                                 direct=True)
             else:
+                # one ring span a read: the handler's whole answer, its
+                # wait for the application's lock included
+                t0 = time.monotonic_ns()
                 payload = self.handler.read(client, req.request)
+                flight.record_span("ro_read",
+                                   (time.monotonic_ns() - t0) // 1000)
             reply = m.ClientReplyMsg(
                 sender_id=self.id, req_seq_num=req.req_seq_num,
                 current_primary=self.primary, reply=payload,

@@ -67,14 +67,17 @@ _DEVICE_THRESHOLD = 192
 
 # what update_batch asked of the engine, process-wide (every tree of every
 # ledger in the process): leaves changed, how many of them the native walk
-# carried, node reads issued (the probes for the empty depth and the
-# sibling reads), how many of those went to a store whose point reads
-# keep the interpreter lock (`IDBClient.point_reads_keep_lock`), and
-# sibling lookups the empty depth answered with no read. Totals only —
-# nothing here is read back by the tree.
+# carried, how many of them were already stored (an overwrite or delete:
+# the empty depth is DEPTH + 1 and every sibling is read), node reads
+# issued (the probes for the empty depth and the sibling reads), how many
+# of those went to a store whose point reads keep the interpreter lock
+# (`IDBClient.point_reads_keep_lock`), and sibling lookups the empty
+# depth answered with no read. Totals only — nothing here is read back
+# by the tree.
 METRICS = Component("kvbc")
 _M_KEYS = METRICS.register_counter("smt_keys_updated")
 _M_NATIVE = METRICS.register_counter("smt_keys_native")
+_M_OVERWRITTEN = METRICS.register_counter("smt_keys_overwritten")
 _M_ENGINE_READS = METRICS.register_counter("smt_engine_reads")
 _M_READS_LOCK_KEPT = METRICS.register_counter("smt_engine_reads_lock_kept")
 _M_BOUNDED = METRICS.register_counter("smt_siblings_bounded")
@@ -224,12 +227,13 @@ class SparseMerkleTree:
         paths: List[bytes] = []
         lens: List[int] = []
         on_path = set()               # (depth, bits) that may be stored
-        reads = 0
+        reads = stored = 0
         for key, vh in updates.items():
             path = hashlib.sha256(key).digest()
             bits = int.from_bytes(path, "big")
             empty, probes = self._empty_depth(bits)
             reads += probes
+            stored += empty > DEPTH
             paths.append(path)
             lens.append(-1 if vh is None else len(vh))
             for depth in range(1, min(empty, DEPTH) + 1):
@@ -279,6 +283,7 @@ class SparseMerkleTree:
             lib.free(index)
         wb.extend_encoded(rows)
         _M_NATIVE.inc(n)
+        _M_OVERWRITTEN.inc(stored)
         self._count_reads(reads)
         _M_BOUNDED.inc(defaulted.value)
         return root.raw
@@ -294,7 +299,7 @@ class SparseMerkleTree:
         # the walk stages a row for every node of the path
         changed: Dict[int, bytes] = {}
         bound: Dict[int, int] = {}
-        reads = bounded = 0
+        reads = bounded = stored = 0
         # every row of the walk, in the order the engine gets them
         rows: List[Tuple[bytes, Optional[bytes]]] = []
         for key, vh in updates.items():
@@ -302,6 +307,7 @@ class SparseMerkleTree:
             bits = int.from_bytes(path, "big")
             bound[bits], probes = self._empty_depth(bits)
             reads += probes
+            stored += bound[bits] > DEPTH
             changed[bits] = _EMPTY if vh is None else _leaf_hash(path, vh)
             rows.append((self._leaf_pre + path, vh))
             if ver is not None:
@@ -344,6 +350,7 @@ class SparseMerkleTree:
             bound = up
             self._level_rows(rows, depth - 1, changed, ver)
         wb.extend(rows, self._row_families(version))
+        _M_OVERWRITTEN.inc(stored)
         self._count_reads(reads)
         _M_BOUNDED.inc(bounded)
         return changed[0]
