@@ -884,3 +884,10 @@ def resolve_threshold_scheme(scheme: str, n: int,
         return "multisig-bls"
     cx = crossover_n or ADAPTIVE_SCHEME_CROSSOVER_N
     return "multisig-ed25519" if n < cx else "threshold-bls"
+
+
+# `bls_msm` launches whose program runs the G1 ladder as ops/g1_pallas's
+# Pallas kernel, one a launch, process-wide (decided where
+# ops/bls12_381._msm_launch picks its kernel). Read at exit beside the
+# kernel profiler's `bls_msm` calls; nothing here is read back.
+FUSED_MSM_LAUNCHES = METRICS.register_counter("bls_msm_fused_launches")

@@ -29,7 +29,7 @@ from tpubft.ops.weierstrass import Curve, WPoint
 
 @functools.lru_cache(maxsize=None)
 def g1_curve() -> Curve:
-    return Curve(get_field(ref.P), 0, ref.B1, ref.G1_GEN[0], ref.G1_GEN[1], ref.R)
+    return Curve(get_field(ref.P), 0, ref.B1, *ref.G1_GEN, ref.R, fused=True)
 
 
 SCALAR_BITS = 255
@@ -52,8 +52,8 @@ def msm_kernel(bits: jnp.ndarray, px: jnp.ndarray, py: jnp.ndarray,
     pts = cv.from_affine(px, py)
     # padding slots become the identity regardless of their (px,py) content
     pts = cv.select(infinity, cv.identity(px.shape[1:]), pts)
-    acc = cv.scalar_mul_bits(bits, pts)
-    out = cv.msm_reduce(acc)
+    # ladder + tree reduce; on a TPU one Pallas kernel (ops/g1_pallas)
+    out = cv.msm_bits(bits, pts)
     return out.x, out.y, out.z
 
 
@@ -78,9 +78,8 @@ def _prep_msm(points: Sequence, scalars: Sequence[int], m: int):
 
 
 def _msm_launch(plan, points: Sequence, scalars: Sequence[int]):
-    """One MSM launch under a MeshPlan (None / meshless plan = the
-    single-device kernel — also the post-eviction landing spot when
-    the retry loop hands us a one-chip plan)."""
+    """One MSM launch under a MeshPlan (None / meshless plan = the single-
+    device kernel, also where the retry loop lands after an eviction)."""
     from tpubft.ops.dispatch import device_section
     n = len(points)
     if plan is not None and plan.mesh is not None:
@@ -93,6 +92,7 @@ def _msm_launch(plan, points: Sequence, scalars: Sequence[int]):
         shards, m = 1, _pad_pow2(n)
         kern = msm_kernel
     bits, px, py, infinity = _prep_msm(points, scalars, m)
+    _count_launch(m // shards, reduce=shards == 1)
     with device_section("bls_msm", batch=m, shards=shards):
         x, y, z = kern(jnp.asarray(bits), jnp.asarray(px),
                        jnp.asarray(py), jnp.asarray(infinity))
@@ -251,3 +251,13 @@ def batch_scalar_mul(points: Sequence, scalars: Sequence[int]) -> List:
                        jnp.asarray(infinity))
         x, y, z = np.asarray(x), np.asarray(y), np.asarray(z)
     return [_to_affine_host(x[:, i], y[:, i], z[:, i]) for i in range(n)]
+
+
+def _count_launch(lanes: int, reduce: bool) -> None:
+    """A `bls_msm` launch whose program runs the ladder (and, where
+    `reduce`, the tree reduce) as ops/g1_pallas's kernel, `lanes` a
+    device: the `threshold` counter `bls_msm_fused_launches`."""
+    from tpubft.ops import g1_pallas
+    if g1_pallas.usable(jax.ShapeDtypeStruct((1, lanes), jnp.int32), reduce):
+        from tpubft.crypto.systems import FUSED_MSM_LAUNCHES
+        FUSED_MSM_LAUNCHES.inc()

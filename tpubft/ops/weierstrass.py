@@ -31,8 +31,8 @@ class Curve:
     def __init__(self, field: Field, a: int, b: int,
                  gx: int, gy: int, order: int, fused: bool = False):
         self.f = field
-        # a = 0 only: on a TPU, a double-scalar ladder over whole-register
-        # batches runs as ops/field_pallas's one kernel
+        # a = 0 only: on a TPU, ladders over whole-register batches run as
+        # one Pallas kernel (double-scalar: ops/field_pallas; single: g1_pallas)
         self.fused = fused and a % field.p == 0
         self.a = a % field.p
         self.b = b % field.p
@@ -111,6 +111,10 @@ class Curve:
 
     def scalar_mul_bits(self, bits: jnp.ndarray, p: WPoint) -> WPoint:
         """[k]P for bit matrix (nbits, ...batch), msb-first, constant-time."""
+        if self.fused:
+            from tpubft.ops import g1_pallas
+            if g1_pallas.usable(p.x):
+                return WPoint(*g1_pallas.scalar_mul_bits(self, bits, p))
         def step(acc, bit):
             acc = self.add(acc, acc)
             acc = self.select(bit.astype(bool), self.add(acc, p), acc)
@@ -144,6 +148,16 @@ class Curve:
             right = WPoint(p.x[..., h:2*h], p.y[..., h:2*h], p.z[..., h:2*h])
             p = self.add(left, right)
         return p
+
+    def msm_bits(self, bits: jnp.ndarray, p: WPoint) -> WPoint:
+        """msm_reduce(scalar_mul_bits(bits, p)) over a flat batch (NL, B),
+        B a power of two. On a TPU, with a = 0, ladder and reduce are one
+        kernel (ops/g1_pallas): bit-identical, every limb."""
+        if self.fused:
+            from tpubft.ops import g1_pallas
+            if g1_pallas.usable(p.x, reduce=True):
+                return WPoint(*g1_pallas.msm_bits(self, bits, p))
+        return self.msm_reduce(self.scalar_mul_bits(bits, p))
 
     def to_affine(self, p: WPoint) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
         """Returns (x_raw, y_raw, is_identity) with canonical non-Montgomery
