@@ -121,7 +121,11 @@ def test_fold_stage_math():
                       # no lane start / queue stamp / group on record:
                       # exec reads as all service, the rest as nothing
                       "order_wait": 0.0, "exec_wait": 0.0,
-                      "exec_run": 10.0, "dur_wait": 0.0}
+                      "exec_run": 10.0, "dur_wait": 0.0,
+                      # nor the loop's end: the run reads as all seal
+                      "exec_app": 0.0, "exec_reply": 0.0,
+                      "exec_seal": 10.0, "dur_queue": 0.0,
+                      "dur_apply": 0.0, "dur_fsync": 0.0}
     # fast path: no prepare quorum — prepare reads 0, commit runs from
     # accept; a primary self-proposal has no admit/handler anchors
     fast = {"accept": t0, "committed": t0 + 4_000_000,
@@ -185,17 +189,30 @@ MS = 1_000_000
 T0 = 1_000_000_000
 
 
+GROUP_EVENTS = (flight.EV_DUR_TAKE, flight.EV_DUR_WRITTEN,
+                flight.EV_DUR_GROUP)
+
+
 def _fold_events(events, rid=7, seq=5):
     """Feed (code, arg, offset_ms) rows for one slot through the live
-    tracker; returns the finalized record."""
+    tracker; returns the finalized record. A group event's `arg` is its
+    watermark."""
     flight.reset()
     tr = flight.slot_tracker()
     for code, arg, at_ms in events:
-        target = arg if code == flight.EV_DUR_GROUP else seq
-        tr.on_event(rid, code, target, 0,
-                    0 if code == flight.EV_DUR_GROUP else arg,
-                    T0 + int(at_ms * MS))
+        group = code in GROUP_EVENTS
+        tr.on_event(rid, code, arg if group else seq, 0,
+                    0 if group else arg, T0 + int(at_ms * MS))
     return tr.recent(rid=rid)[-1]
+
+
+def _assert_parts_sum(st):
+    """The two splits, to the rows' 0.001 ms rounding."""
+    assert st["exec_app"] + st["exec_reply"] + st["exec_seal"] \
+        == pytest.approx(st["exec_run"], abs=0.0015)
+    assert st["dur_queue"] + st["dur_apply"] + st["dur_fsync"] \
+        == pytest.approx(st["dur_wait"], abs=0.0015)
+    assert all(st[k] >= 0 for k in flight.STAGES)
 
 
 def test_order_wait_folds_from_pp_create_on_the_primary_only():
@@ -272,6 +289,135 @@ def test_dur_wait_folds_from_the_group_watermark():
     assert late["dur_wait"] == late["reply"] == 1.0
 
 
+@pytest.mark.parametrize("name,events,parts", [
+    # committed at 5, the lane began at 10, the loop returned at 18
+    # after 6 ms of application calls, applied at 20
+    ("one_slot_run", [(flight.EV_COMMITTED, 0, 5),
+                      (flight.EV_EXEC_START, 1, 10),
+                      (flight.EV_EXEC_HANDLED, 6000, 18),
+                      (flight.EV_EXEC_APPLY, 1, 20)], (6.0, 2.0, 2.0)),
+    # released ahead of its verified commit: the loop was over before
+    # the commit, so the run after it is all seal
+    ("released_ahead", [(flight.EV_EXEC_START, 1, 2),
+                        (flight.EV_EXEC_HANDLED, 1500, 4),
+                        (flight.EV_COMMITTED, 0, 5),
+                        (flight.EV_EXEC_APPLY, 1, 6)], (0.0, 0.0, 1.0)),
+    # the application's sum is capped at the loop it was summed in
+    ("app_over_the_loop", [(flight.EV_COMMITTED, 0, 5),
+                           (flight.EV_EXEC_START, 1, 10),
+                           (flight.EV_EXEC_HANDLED, 9000, 12),
+                           (flight.EV_EXEC_APPLY, 1, 13)], (2.0, 0.0, 1.0)),
+    # a retried run: the first loop's end stands, the retry is seal
+    ("retried_run", [(flight.EV_COMMITTED, 0, 5),
+                     (flight.EV_EXEC_START, 1, 10),
+                     (flight.EV_EXEC_HANDLED, 1000, 12),
+                     (flight.EV_EXEC_START, 1, 20),
+                     (flight.EV_EXEC_HANDLED, 1000, 22),
+                     (flight.EV_EXEC_APPLY, 1, 24)], (1.0, 1.0, 12.0)),
+    # no EV_EXEC_HANDLED on record: the whole run reads as seal
+    ("no_handled", [(flight.EV_COMMITTED, 0, 5),
+                    (flight.EV_EXEC_START, 1, 10),
+                    (flight.EV_EXEC_APPLY, 1, 20)], (0.0, 0.0, 10.0)),
+])
+def test_exec_run_splits_into_app_reply_and_seal(name, events, parts):
+    rec = _fold_events([(flight.EV_PP_ACCEPT, 1, 0)] + events
+                       + [(flight.EV_REPLY, 0, 60)])
+    st = rec["stages_ms"]
+    assert (st["exec_app"], st["exec_reply"], st["exec_seal"]) \
+        == pytest.approx(parts)
+    _assert_parts_sum(st)
+
+
+def test_a_run_of_several_slots_seals_at_its_last():
+    """Three slots in one run: each slot's loop is its own, and the seal
+    of a slot that is not the run's last holds the later slots."""
+    flight.reset()
+    tr = flight.slot_tracker()
+    for seq in (1, 2, 3):
+        tr.on_event(4, flight.EV_COMMITTED, seq, 0, 1, T0)
+    at = 10
+    for seq in (1, 2, 3):
+        tr.on_event(4, flight.EV_EXEC_START, seq, 0, 3, T0 + at * MS)
+        tr.on_event(4, flight.EV_EXEC_HANDLED, seq, 0, 1000,
+                    T0 + (at + 2) * MS)
+        at += 2
+    for seq in (1, 2, 3):
+        tr.on_event(4, flight.EV_EXEC_APPLY, seq, 0, 3, T0 + 20 * MS)
+        tr.on_event(4, flight.EV_REPLY, seq, 0, 0, T0 + 21 * MS)
+    rows = {r["seq"]: r["stages_ms"] for r in tr.recent(rid=4)}
+    assert [rows[s]["exec_seal"] for s in (1, 2, 3)] == [8.0, 6.0, 4.0]
+    assert all(rows[s]["exec_app"] == rows[s]["exec_reply"] == 1.0
+               for s in (1, 2, 3))
+    for st in rows.values():
+        _assert_parts_sum(st)
+
+
+def test_dur_wait_splits_at_the_take_and_the_write():
+    """A group of three runs on replica 7: each slot queues from its own
+    apply to the one take, then shares the group's write and fsync. A
+    sibling replica's group moves nothing here, a slot no group covers
+    reads 0 in every part, and each row carries its group's runs."""
+    flight.reset()
+    tr = flight.slot_tracker()
+    for seq, applied in ((5, 10), (6, 11), (7, 12), (8, 12)):
+        tr.on_event(7, flight.EV_COMMITTED, seq, 0, 1, T0)
+        tr.on_event(7, flight.EV_EXEC_APPLY, seq, 0, 1, T0 + applied * MS)
+    for code, at in ((flight.EV_DUR_TAKE, 13), (flight.EV_DUR_WRITTEN, 14),
+                     (flight.EV_DUR_GROUP, 15)):
+        tr.on_event(8, code, 9, 0, 1, T0 + at * MS)   # replica 8's group
+    tr.on_event(7, flight.EV_DUR_TAKE, 7, 0, flight.DUR_CUT_DEADLINE,
+                T0 + 14 * MS)
+    tr.on_event(7, flight.EV_DUR_WRITTEN, 7, 0, 3, T0 + 17 * MS)
+    tr.on_event(7, flight.EV_DUR_GROUP, 7, 0, 3, T0 + 20 * MS)
+    for seq in (5, 6, 7, 8):
+        tr.on_event(7, flight.EV_REPLY, seq, 0, 0, T0 + 22 * MS)
+    rows = {r["seq"]: r for r in tr.recent(rid=7)}
+    split = {seq: tuple(rows[seq]["stages_ms"][k]
+                        for k in ("dur_queue", "dur_apply", "dur_fsync"))
+             for seq in rows}
+    assert split == {5: (4.0, 3.0, 3.0), 6: (3.0, 3.0, 3.0),
+                     7: (2.0, 3.0, 3.0), 8: (0.0, 0.0, 0.0)}
+    assert [rows[s]["group_runs"] for s in (5, 6, 7, 8)] == [3, 3, 3, 0]
+    assert rows[5]["dur_cut"] == flight.DUR_CUT_DEADLINE
+    for r in rows.values():
+        _assert_parts_sum(r["stages_ms"])
+    # a group that lands after the reply: clamped into it, part by part
+    late = SlotTracker.fold({"applied": T0, "replied": T0 + MS,
+                             "taken": T0 + MS // 2, "written": T0 + 2 * MS,
+                             "durable": T0 + 5 * MS})
+    assert (late["dur_wait"], late["dur_queue"], late["dur_apply"],
+            late["dur_fsync"]) == (1.0, 0.5, 0.5, 0.0)
+    _assert_parts_sum(late)
+    # a group retried after a failed write: the first take stands
+    rec = _fold_events([
+        (flight.EV_COMMITTED, 0, 5), (flight.EV_EXEC_APPLY, 1, 10),
+        (flight.EV_DUR_TAKE, 5, 11), (flight.EV_DUR_TAKE, 5, 16),
+        (flight.EV_DUR_WRITTEN, 5, 17), (flight.EV_DUR_GROUP, 5, 18),
+        (flight.EV_REPLY, 0, 19)])
+    st = rec["stages_ms"]
+    assert (st["dur_queue"], st["dur_apply"], st["dur_fsync"]) \
+        == pytest.approx((1.0, 6.0, 1.0))
+
+
+def test_group_events_scan_only_their_replica_s_live_slots():
+    flight.reset()
+    tr = flight.slot_tracker()
+    for rid in (1, 2):
+        tr.on_event(rid, flight.EV_EXEC_APPLY, 5, 0, 1, T0)
+    tr.on_event(2, flight.EV_DUR_GROUP, 5, 0, 1, T0 + MS)
+    assert "durable" in tr._live[(2, 5)]
+    assert "durable" not in tr._live[(1, 5)]
+    assert set(tr._live_by_rid[1]) == {5}
+    # finalizing and evicting keep the index equal to the live set
+    tr.on_event(2, flight.EV_REPLY, 5, 0, 0, T0 + 2 * MS)
+    assert tr._live_by_rid[2] == {}
+    for seq in range(SlotTracker.MAX_LIVE + 3):
+        tr.on_event(3, flight.EV_PP_ACCEPT, seq, 0, 0, T0)
+    assert sum(len(d) for d in tr._live_by_rid.values()) \
+        == len(tr._live) == SlotTracker.MAX_LIVE
+    tr.reset()
+
+
 def test_recent_holds_4096_slots():
     flight.reset()
     tr = flight.slot_tracker()
@@ -292,18 +438,30 @@ def test_tpuprof_replays_the_new_events_like_the_live_tracker():
     for code, seq, arg in (
             (flight.EV_PP_CREATE, 21, 1500), (flight.EV_PP_ACCEPT, 21, 7),
             (flight.EV_COMMITTED, 21, 0), (flight.EV_EXEC_START, 21, 1),
-            (flight.EV_EXEC_APPLY, 21, 1), (flight.EV_DUR_GROUP, 21, 1),
+            (flight.EV_EXEC_HANDLED, 21, 0),
+            (flight.EV_EXEC_APPLY, 21, 1),
+            (flight.EV_DUR_TAKE, 20, flight.DUR_CUT_FULL),  # not 21's
+            (flight.EV_DUR_TAKE, 21, flight.DUR_CUT_QUIET),
+            (flight.EV_DUR_WRITTEN, 21, 1), (flight.EV_DUR_GROUP, 21, 1),
             (flight.EV_REPLY, 21, 0)):
         flight.record(code, seq=seq, arg=arg)
+        time.sleep(0.0002)            # every part > 0 on both sides
     with flight.span("tpuprof_span_case", 21):
         pass
-    live = flight.slot_tracker().recent(rid=6)[-1]["stages_ms"]
+    row = flight.slot_tracker().recent(rid=6)[-1]
+    live = row["stages_ms"]
     dump = flight.snapshot()
     slot = tpuprof.fold_slots(dump)[(6, 21)]
     replayed = SlotTracker.fold(slot)
     assert {k: round(v, 3) for k, v in replayed.items()} == live
     assert live["order_wait"] == 1.5 and slot["reqs"] == 7
-    assert "durable" in slot
+    assert {"handled", "taken", "written", "durable"} <= set(slot)
+    assert slot["cut"] == row["dur_cut"] == flight.DUR_CUT_QUIET
+    assert slot["group_runs"] == row["group_runs"] == 1
+    assert all(live[k] > 0 for k in ("exec_reply", "exec_seal",
+                                     "dur_queue", "dur_apply",
+                                     "dur_fsync"))
+    _assert_parts_sum(live)
     dump["_path"] = "live"
     assert any("tpuprof_span_case" in line
                for line in tpuprof.span_table([dump]))
@@ -340,6 +498,172 @@ def test_live_cluster_write_accounts_for_order_and_lane():
     names = {n for n in ("exec_run", "dur_group")
              if flight.span_events(n)}
     assert "exec_run" in names
+
+
+def test_live_ledger_write_splits_the_run_and_the_group():
+    """Real writes through an f=1 SKVBC cluster whose ledgers defer to
+    the durability pipeline: every replica has a row with all six parts
+    above 0, and on every row the parts sum to `exec_run` and
+    `dur_wait`."""
+    from tpubft.apps import skvbc
+    from tpubft.kvbc import KeyValueBlockchain
+    from tpubft.storage.memorydb import MemoryDB
+    from tpubft.testing import InProcessCluster
+
+    def handler_factory(_r):
+        return skvbc.SkvbcHandler(
+            KeyValueBlockchain(MemoryDB(), use_device_hashing=False))
+
+    parts = ("exec_app", "exec_reply", "exec_seal", "dur_queue",
+             "dur_apply", "dur_fsync")
+    flight.reset()
+    with InProcessCluster(f=1, handler_factory=handler_factory) as cluster:
+        kv = skvbc.SkvbcClient(cluster.client(0))
+        for i in range(4):
+            assert kv.write([(b"split%d" % i, b"v")],
+                            timeout_ms=15000).success
+        deadline = time.monotonic() + 10
+        while time.monotonic() < deadline:
+            rows = flight.slot_tracker().recent(limit=SlotTracker.KEEP)
+            whole = {r["rid"] for r in rows if r["reqs"]
+                     and all(r["stages_ms"][k] > 0 for k in parts)}
+            if len(whole) == cluster.n:
+                break
+            time.sleep(0.05)
+    assert len(whole) == cluster.n, rows
+    for r in rows:
+        _assert_parts_sum(r["stages_ms"])
+    assert all(r["group_runs"] >= 1 and r["dur_cut"] in flight.DUR_CUT_NAMES
+               for r in rows if r["reqs"])
+
+
+def _xplane_events(trace_dir, prefix="tpubft:"):
+    """{name: [(line key, start ns, duration ns)]} of the host's `prefix`
+    events in the profiler's trace."""
+    import glob
+    from jax.profiler import ProfileData
+    path = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                     recursive=True)[0]
+    out = {}
+    for plane in ProfileData.from_file(path).planes:
+        for i, line in enumerate(plane.lines):
+            for e in line.events:
+                if e.name.startswith(prefix):
+                    out.setdefault(e.name[len(prefix):], []).append(
+                        (f"{plane.name}#{i}", int(e.start_ns),
+                         int(e.duration_ns)))
+    return out
+
+
+def _ring_intervals(start_code, end_code, thread_prefix):
+    """Durations in ns, start event -> the next end event, on the rings
+    of threads named `thread_prefix*`."""
+    out = []
+    for ring in flight.snapshot()["rings"]:
+        if not ring["thread"].startswith(thread_prefix):
+            continue
+        t0 = None
+        for t, code, _seq, _view, _arg in ring["events"]:
+            if code == start_code:
+                t0 = t if t0 is None else t0
+            elif code == end_code and t0 is not None:
+                out.append(t - t0)
+                t0 = None
+    return out
+
+
+def test_the_profiler_s_trace_and_the_rings_name_one_interval(tmp_path):
+    """A real `jax.profiler` trace on the CPU backend holds a worker's
+    `flight.span` and the io thread's `tpubft:dur_apply` /
+    `tpubft:dur_fsync`, each as long as its ring interval, from threads
+    other than the one that started the profiler. (The trace's lines
+    are not asserted one a thread: in a process that has run many
+    threads the host tracer has put two threads' events on one line.)"""
+    import jax
+    from tpubft.durability import DurabilityPipeline, SealedRun
+    from tpubft.storage.interfaces import WriteBatch
+
+    class SlowDB:
+        """write_group takes 20 ms, sync 35 ms."""
+        syncs_on_write = False
+
+        def write_group(self, batches):
+            time.sleep(0.020)
+
+        def sync(self):
+            time.sleep(0.035)
+
+    class Stub:
+        id = 0
+        last_executed = 0
+        aggregator = health = exec_lane = comm = None
+
+        class clients:
+            @staticmethod
+            def on_request_executed(*_a):
+                pass
+
+        class incoming:
+            @staticmethod
+            def push_internal_once(_key):
+                pass
+
+    class Run:
+        def __init__(self, seq):
+            self.first = self.last = seq
+
+    def probe():
+        with flight.span("xplane_probe", 1):
+            time.sleep(0.015)
+
+    flight.reset()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with flight.annotate("main_thread"):
+            pass
+        worker = threading.Thread(target=probe, name="xplane-probe")
+        worker.start()
+        worker.join()
+        pipe = DurabilityPipeline(Stub(), group_max=1, window_us=0)
+        pipe.start()
+        try:
+            for seq in (1, 2):
+                pipe.seal(SealedRun(
+                    run=Run(seq), executed_now=[],
+                    batch=WriteBatch().put(b"k%d" % seq, b"v", b"blk"),
+                    run_no=pipe.pending.stage({}), db=SlowDB()))
+            assert pipe.drain(10)
+        finally:
+            pipe.stop()
+    finally:
+        jax.profiler.stop_trace()
+    trace = _xplane_events(str(tmp_path))
+    assert len(trace["main_thread"]) == 1
+    # the span: its ring half inside its profiler half
+    (_line, _start, span_ns), = trace["xplane_probe"]
+    (_t, _seq, span_us), = flight.span_events("xplane_probe")
+    assert 0 <= span_ns - span_us * 1000 < 2_000_000
+    # the io thread's two intervals, one a group: the annotation inside
+    # its ring interval, and as long to within 2 ms
+    for name, start, end, sleep_ns in (
+            ("dur_apply", flight.EV_DUR_TAKE, flight.EV_DUR_WRITTEN,
+             20_000_000),
+            ("dur_fsync", flight.EV_DUR_WRITTEN, flight.EV_DUR_GROUP,
+             35_000_000)):
+        ann = sorted(d for _k, _s, d in trace[name])
+        ring = sorted(_ring_intervals(start, end, "dur-"))
+        assert len(ann) == len(ring) == 2, (name, ann, ring)
+        for a, r in zip(ann, ring):
+            assert sleep_ns <= a <= r + 50_000
+            assert r - a < 2_000_000, (name, a, r)
+    # each nested in one of the group spans, on that span's line
+    groups = trace["dur_group"]
+    assert len(groups) == 2
+    for name in ("dur_apply", "dur_fsync"):
+        for line, start, dur in trace[name]:
+            assert any(gl == line and gs <= start
+                       and start + dur <= gs + gd
+                       for gl, gs, gd in groups), (name, groups)
 
 
 # ---------------- flight.span ----------------
@@ -398,6 +722,7 @@ def test_span_is_nothing_when_the_recorder_is_off():
     try:
         cm = flight.span("off_span")
         assert cm is flight.span("other")         # one shared no-op
+        assert flight.annotate("off_annotation") is cm
         with cm:
             pass
         flight.record_span("off_sum", 5)
@@ -426,6 +751,13 @@ def test_span_annotates_the_profiler_only_once_jax_is_imported(
         seen.append("body")
     assert seen == [("enter", "tpubft:annotated"), "body",
                     ("exit", "tpubft:annotated")]
+    # the profiler half alone: no ring event of its own
+    flight.reset()
+    with flight.annotate("exec_seal"):
+        seen.append("seal")
+    assert seen[3:] == [("enter", "tpubft:exec_seal"), "seal",
+                        ("exit", "tpubft:exec_seal")]
+    assert not _my_events(flight.EV_SPAN)
 
 
 def test_bls_share_decompression_is_one_span_per_combine():

@@ -19,7 +19,15 @@ reduces and deletes it, and reports:
       before the ordering queue, and so before every slot stage: the
       clients' `client_send` spans (utils/tracing, `time.monotonic`)
       joined by (client, request number) to the primary's `client_req`
-      flight events, which are on the same clock.
+      flight events, which are on the same clock;
+  (e) in a served cell, the split of the lane's run and the durability
+      group on every retained slot row: how many rows, on how many the
+      three parts of `exec_run` or of `dur_wait` do not sum to it (to
+      the rows' 0.001 ms rounding), the parts' medians over requests,
+      and the flight events the split adds per client request.
+
+Each thread's `tpubft:` names are counted (`span_counts_by_thread`),
+beside the number of device module events in the same trace.
 
 It also leaves the window's slot rows and the recorder's snapshot in
 chiprun_out/clock_check/ for `tools/tpuprof.py`.
@@ -102,6 +110,12 @@ def analyse(trace: dict, call_rows: list, patterns: dict,
                        if n.startswith(PREFIX)})
             for t, evs in threads.items()}
     ours = {t: names for t, names in ours.items() if names}
+    counts = {}
+    for t, evs in threads.items():
+        for n, _s, _d in evs:
+            if n.startswith(PREFIX):
+                c = counts.setdefault(t, {})
+                c[n[len(PREFIX):]] = c.get(n[len(PREFIX):], 0) + 1
     dev_spans = [(n, s, d) for evs in threads.values()
                  for n, s, d in evs if n.startswith(DEV)]
     inside = {}
@@ -128,6 +142,8 @@ def analyse(trace: dict, call_rows: list, patterns: dict,
             [t for t in ours if t not in opener]),
         "profiler_started_on": opener,
         "span_kinds_by_thread": ours,
+        "span_counts_by_thread": counts,
+        "device_module_events": len(trace["modules"]),
         "launches": inside,
         "launches_paired": by_kind,
     }
@@ -173,6 +189,38 @@ def request_path(snapshot: dict, slot_rows: list) -> dict:
             "request_ms_p50": statistics.median(whole)}
 
 
+EXEC_PARTS = ("exec_app", "exec_reply", "exec_seal")
+DUR_PARTS = ("dur_queue", "dur_apply", "dur_fsync")
+
+
+def split_check(slot_rows: list, n: int) -> dict:
+    """(e): the rows that carry the split (a program without it: none)."""
+    rows = [r for r in slot_rows if "exec_app" in r["stages_ms"]]
+    if not rows:
+        return {"rows": 0}
+
+    def off(st, parts, whole):
+        return abs(sum(st[k] for k in parts) - st[whole]) > 0.0015
+    out = {"rows": len(rows),
+           "exec_run_mismatches": sum(off(r["stages_ms"], EXEC_PARTS,
+                                          "exec_run") for r in rows),
+           "dur_wait_mismatches": sum(off(r["stages_ms"], DUR_PARTS,
+                                          "dur_wait") for r in rows)}
+    for k in ("exec_run",) + EXEC_PARTS + ("dur_wait",) + DUR_PARTS:
+        vals = [r["stages_ms"][k] for r in rows
+                for _ in range(r.get("reqs", 1))]
+        if vals:
+            out[f"{k}_ms_p50"] = statistics.median(vals)
+    # one exec_handled a slot, a take and a write a group, on every
+    # replica's row; a request is on n replicas' rows
+    reqs = sum(r.get("reqs", 0) for r in rows) / n
+    events = sum(1 + 2 / r["group_runs"] if r.get("group_runs") else 1
+                 for r in rows)
+    if reqs:
+        out["events_added_per_request"] = events / reqs
+    return out
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
@@ -198,6 +246,13 @@ def main(argv=None) -> int:
         snap = flight.snapshot()
         rows = flight.slot_tracker().recent(limit=flight.SlotTracker.KEEP)
         found["request_path"] = request_path(snap, rows)
+        cluster = cell.config.get("cluster")
+        if cluster:
+            found["split"] = split_check(rows, cluster["n"])
+            found["split"]["exec_start_run_lengths"] = sorted({
+                arg for ring in snap["rings"]
+                for _t, code, _seq, _view, arg in ring["events"]
+                if code == flight.EV_EXEC_START})
         os.makedirs(out_dir, exist_ok=True)
         with open(os.path.join(out_dir, stem + ".flight.json"), "w",
                   encoding="utf-8") as fh:

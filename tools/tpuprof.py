@@ -12,7 +12,9 @@ transitions and chaos-campaign red verdicts, or on demand via
     row, not an archaeology session;
   * a STAGE-HISTOGRAM table: adm_wait / dispatch / prepare / commit /
     exec / reply percentiles over every completed slot in the dumps,
-    with the order_wait / exec_wait / exec_run / dur_wait sub-stages;
+    with the order_wait / exec_wait / exec_run / dur_wait sub-stages
+    and the splits of the last two (exec_app / exec_reply / exec_seal,
+    dur_queue / dur_apply / dur_fsync);
   * the KERNEL profile per dump (call counts, batch sizes, compile
     warmup vs warm time, prep / gate-wait totals, breaker states at
     call time);
@@ -62,18 +64,18 @@ def _epoch_of(dump: Dict, t_ns: int) -> float:
 
 def fold_slots(dump: Dict) -> Dict[Tuple[int, int], Dict]:
     """Rebuild slot lifecycles from the dump's raw ring events through
-    the live SlotTracker's own fold (`stamp` / `stamp_durable` /
+    the live SlotTracker's own fold (`stamp` / `stamp_group` /
     `fold` are the shared stage math). Events are replayed in time
-    order across rings: a durability group marks the slots applied
-    BEFORE it. Keyed (rid, seq)."""
+    order across rings: a durability group's take, write and commit
+    mark the slots applied BEFORE them. Keyed (rid, seq)."""
     tracker = flight.SlotTracker
     events = sorted((ev[0], ring.get("rid", -1), ev)
                     for ring in dump.get("rings", [])
                     for ev in ring.get("events", []))
     slots: Dict[Tuple[int, int], Dict] = {}
     for _t, rid, (t_ns, code, seq, view, arg) in events:
-        if code == flight.EV_DUR_GROUP:
-            tracker.stamp_durable(slots.values(), rid, seq, t_ns)
+        if code in tracker._GROUP_FIELD:
+            tracker.stamp_group(slots.values(), rid, code, seq, arg, t_ns)
         elif code in tracker._FIELD:
             slot = slots.setdefault((rid, seq),
                                     {"rid": rid, "seq": seq, "view": view})
@@ -119,7 +121,8 @@ def timeline(dumps: List[Dict], seq_filter: Optional[int] = None,
                 rows[seq], key=lambda r: r[0]):
             ts = [v for k, v in slot.items()
                   if k not in ("rid", "seq", "view", "path", "reqs",
-                               "order_wait_us")]
+                               "order_wait_us", "app_us", "group_runs",
+                               "cut")]
             t0 = ""
             if ts and base_epoch is not None:
                 t0 = f"{_epoch_of(dump, min(ts)) - base_epoch:+.3f}s"

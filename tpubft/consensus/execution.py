@@ -305,8 +305,10 @@ class ExecutionLane:
         try:
             for seq, pp in run:
                 flight.record(flight.EV_EXEC_START, seq=seq, arg=len(run))
-                self._execute_slot(seq, pp, pages_wb, result,
-                                   executed_now)
+                with flight.annotate("exec_slot"):
+                    app_us = self._execute_slot(seq, pp, pages_wb, result,
+                                                executed_now)
+                flight.record(flight.EV_EXEC_HANDLED, seq=seq, arg=app_us)
         except BaseException:
             if acc:
                 blockchain.abort_accumulation()
@@ -337,28 +339,29 @@ class ExecutionLane:
         its fsync alone."""
         r = self._r
         crashpoint("exec.pre_apply", rid=r.id)
-        t0 = time.perf_counter()
         folded = False
         deferred = None                   # (run_no, batch, raw base db)
-        if acc:
-            folded = (pages_wb.ops
-                      and r.res_pages.shares_db(
-                          getattr(blockchain, "_base_db", None)))
-            # deferral requires the WHOLE run to ride one deferred
-            # batch: with reply pages in a SEPARATE store (not folded)
-            # the pages write would land at seal while the ledger batch
-            # waited in memory — a crash in that window persists
-            # "request executed" without its block, and replay would
-            # skip it forever. Fall back to the immediate apply there
-            # (ledger first, pages second, same thread); the seal below
-            # still groups the fsyncs.
-            defer = (getattr(blockchain, "durability_attached", False)
-                     and (folded or not pages_wb.ops))
-            blockchain.end_accumulation(
-                extra=pages_wb if folded else None, defer=defer)
-            if defer:
-                deferred = blockchain.take_deferred()
-        try:
+        # the slot's `exec_seal` on the profiler's clock; its ring half
+        # ends at the EV_EXEC_APPLY events below
+        with flight.annotate("exec_seal"):
+            if acc:
+                folded = (pages_wb.ops
+                          and r.res_pages.shares_db(
+                              getattr(blockchain, "_base_db", None)))
+                # deferral requires the WHOLE run to ride one deferred
+                # batch: with reply pages in a SEPARATE store (not
+                # folded) the pages write would land at seal while the
+                # ledger batch waited in memory — a crash in that window
+                # persists "request executed" without its block, and
+                # replay would skip it forever. Fall back to the
+                # immediate apply there (ledger first, pages second,
+                # same thread); the seal below still groups the fsyncs.
+                defer = (getattr(blockchain, "durability_attached", False)
+                         and (folded or not pages_wb.ops))
+                blockchain.end_accumulation(
+                    extra=pages_wb if folded else None, defer=defer)
+                if defer:
+                    deferred = blockchain.take_deferred()
             if not folded:
                 # without accumulation the handler's effects applied
                 # irreversibly during execution, and with it the ledger
@@ -372,8 +375,8 @@ class ExecutionLane:
                     log.exception("run [%d..%d]: reply-pages batch "
                                   "failed post point-of-no-return",
                                   result.first, result.last)
+        try:
             crashpoint("exec.post_apply", rid=r.id)
-            commit_ms = (time.perf_counter() - t0) * 1e3
             # durable-apply flight events, one per slot (the `exec`
             # stage's end anchor; `reply` runs from here to the
             # dispatcher's integration)
@@ -399,7 +402,7 @@ class ExecutionLane:
                     # strictly worse (duplicate blocks)
                     log.exception("checkpoint snapshot failed at %d",
                                   result.last)
-            r.record_exec_run(run_len, commit_ms)
+            r.record_exec_run(run_len)
         except Exception:  # noqa: BLE001 — the run is durable: a
             # post-commit bookkeeping failure must be SWALLOWED, never
             # reach _loop's requeue path (re-executing a committed run
@@ -442,12 +445,14 @@ class ExecutionLane:
 
     def _execute_slot(self, seq: int, pp, pages_wb: WriteBatch,
                       result: CompletedRun,
-                      executed_now: List[Tuple[int, int, object]]) -> None:
-        """One slot's requests, in order. Only plain / pre-processed
-        client requests reach the lane (barrier batches run inline on
-        the dispatcher)."""
+                      executed_now: List[Tuple[int, int, object]]) -> int:
+        """One slot's requests, in order; returns the µs its application
+        calls took, summed (the slot's `exec_app`). Only plain /
+        pre-processed client requests reach the lane (barrier batches
+        run inline on the dispatcher)."""
         r = self._r
         seen = self._run_seen
+        app_ns = 0
         # batched reply signing (optimistic replies): per-reply scalar
         # signs during execution serialize ~100µs of comb math behind
         # every request — defer them to the io thread, which signs the
@@ -491,7 +496,9 @@ class ExecutionLane:
             if r._slowdown.enabled:
                 from tpubft.testing.slowdown import PHASE_EXECUTE
                 r._slowdown.delay(PHASE_EXECUTE)
+            t0 = time.perf_counter_ns()
             payload = r._execute_request(req, seq)
+            app_ns += time.perf_counter_ns() - t0
             result.n_requests += 1
             reply, wire = r._build_reply(client, req.req_seq_num,
                                          payload, pages_wb,
@@ -507,3 +514,4 @@ class ExecutionLane:
             # agreed-time page writes must stay seq-ordered with the
             # reply pages for checkpoint digest determinism
             r.time_service.on_executed(pp.time)
+        return app_ns // 1000

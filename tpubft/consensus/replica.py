@@ -631,12 +631,10 @@ class Replica(IReceiver):
         self._diag = get_registrar()
         self._h_execute = self._diag.histogram(f"replica{self.id}.execute")
         self._h_verify = self._diag.histogram(f"replica{self.id}.verify")
-        # run-shape histograms: slots per execution run and the coalesced
-        # commit's duration (ms → recorded in µs like the others)
+        # run-shape histogram: slots per execution run (the coalesced
+        # apply's duration is the slot stage `slot.exec_seal`)
         self._h_exec_run_len = self._diag.histogram(
             f"replica{self.id}.exec_run_len")
-        self._h_exec_commit_ms = self._diag.histogram(
-            f"replica{self.id}.exec_commit_ms")
         # slots per fused combine flush (1 = no cross-slot amortization)
         self._h_combine_batch = self._diag.histogram(
             f"replica{self.id}.combine_batch_size", unit="slots")
@@ -2504,6 +2502,7 @@ class Replica(IReceiver):
         replay, and the lane's barrier batches (INTERNAL/RECONFIG
         requests mutate dispatcher-owned subsystems)."""
         flight.record(flight.EV_EXEC_START, seq=nxt, arg=1)
+        app_ns = 0
         for req in info.pre_prepare.client_requests():
             # at-most-once: a request already executed for this client
             # must not re-execute (replay inside a later batch). This
@@ -2517,11 +2516,15 @@ class Replica(IReceiver):
                 continue
             if self._slowdown.enabled:
                 self._slowdown.delay(PHASE_EXECUTE)
+            t0 = time.perf_counter_ns()
             reply = self._execute_request(req, nxt)
+            app_ns += time.perf_counter_ns() - t0
             self.m_executed.inc()
             self._send_reply(req.sender_id, req.req_seq_num, reply)
         if self.cfg.time_service_enabled and info.pre_prepare.time:
             self.time_service.on_executed(info.pre_prepare.time)
+        # the loop's end: `exec_seal` is the persist below
+        flight.record(flight.EV_EXEC_HANDLED, seq=nxt, arg=app_ns // 1000)
         info.executed = True
         info.exec_submitted = False
         if getattr(info, "span", None) is not None:
@@ -2682,14 +2685,12 @@ class Replica(IReceiver):
         self._apply_exec_runs(repump=False)
         return ok and self.exec_lane.idle() and self.durability.idle()
 
-    def record_exec_run(self, run_len: int, commit_ms: float) -> None:
+    def record_exec_run(self, run_len: int) -> None:
         """Lane-thread metrics hook (Counter/Gauge/histograms are
-        thread-safe): one completed run of `run_len` slots whose
-        coalesced durable apply took `commit_ms`."""
+        thread-safe): one completed run of `run_len` slots."""
         self.m_exec_runs.inc()
         self.m_exec_run_slots.inc(run_len)
         self._h_exec_run_len.record(run_len)
-        self._h_exec_commit_ms.record(commit_ms)
 
     def _apply_exec_runs(self, _payload=None, repump: bool = True) -> None:
         """Integrate durably-applied runs (dispatcher thread): advance

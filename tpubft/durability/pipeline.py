@@ -220,8 +220,6 @@ class DurabilityPipeline:
         diag = get_registrar()
         self._h_group_len = diag.histogram(
             f"replica{replica.id}.dur_group_len", unit="runs")
-        self._h_fsync_ms = diag.histogram(
-            f"replica{replica.id}.dur_fsync_ms")
 
     # ------------------------------------------------------------------
     # lifecycle
@@ -344,6 +342,22 @@ class DurabilityPipeline:
         return [self._queue.pop(0)
                 for _ in range(min(self._group_max, len(self._queue)))]
 
+    def _cut_locked(self, now: float, deadline: float) -> int:
+        """Why the group at the queue's head is cut now (a
+        `flight.DUR_CUT_*` code, EV_DUR_TAKE's arg), or 0 to keep its
+        window open."""
+        if len(self._queue) >= self._group_max:
+            return flight.DUR_CUT_FULL
+        if now >= deadline:
+            return flight.DUR_CUT_DEADLINE
+        if self._flush:
+            return flight.DUR_CUT_FLUSH
+        if not self._running:
+            return flight.DUR_CUT_STOP
+        if self._lane_quiet():
+            return flight.DUR_CUT_QUIET
+        return 0
+
     def _lane_quiet(self) -> bool:
         """True when no further seal can be in flight (the lane is
         idle): holding a partial group open would only delay its
@@ -372,10 +386,8 @@ class DurabilityPipeline:
                             and now >= self._retry_at:
                         deadline = (self._queue[0].sealed_mono
                                     + self._window_us / 1e6)
-                        if (len(self._queue) >= self._group_max
-                                or now >= deadline or self._flush
-                                or not self._running
-                                or self._lane_quiet()):
+                        cut = self._cut_locked(now, deadline)
+                        if cut:
                             self._flush = False
                             group = self._take_group_locked()
                             self._busy = True
@@ -392,11 +404,13 @@ class DurabilityPipeline:
                             health.beat("durability")
                     self._cond.wait(wait)
                     watchdog.beat(self._name)
+            wm = max(s.run.last for s in group)
+            flight.record(flight.EV_DUR_TAKE, seq=wm, arg=cut)
             try:
                 # the group's interval on both clocks: apply, fsync,
                 # reply signing and the reply burst
                 with flight.span("dur_group", group[-1].run.last):
-                    self._commit_group(group)
+                    self._commit_group(group, wm)
                 if health is not None:
                     health.beat("durability")
             except Exception:  # noqa: BLE001 — the runs are committed
@@ -458,10 +472,13 @@ class DurabilityPipeline:
             log.exception("group reply signing failed (%d replies "
                           "dropped from the burst)", len(pending))
 
-    def _commit_group(self, group: List[SealedRun]) -> None:
-        """ONE group: concatenated apply per target DB, the
-        `dur.group_fsync` seam, one fsync per distinct DB, watermark
-        publication, then per-run completion."""
+    def _commit_group(self, group: List[SealedRun], wm: int) -> None:
+        """ONE group (`wm` = its highest seq): concatenated apply per
+        target DB, the `dur.group_fsync` seam, one fsync per distinct
+        DB, watermark publication, then per-run completion. The apply
+        and the fsync are the slots' `dur_apply` and `dur_fsync`, on
+        the ring (EV_DUR_WRITTEN, EV_DUR_GROUP) and the profiler's
+        clock alike."""
         r = self._r
         # 1. apply deferred batches, in seal order, one write_group per
         # distinct DB (one concatenated engine record on NativeDB)
@@ -473,35 +490,38 @@ class DurabilityPipeline:
                 per_db[-1][1].append(s)
             else:
                 per_db.append((s.db, [s]))
-        for db, seals in per_db:
-            db.write_group([s.batch for s in seals])
-            for s in seals:
-                self.pending.mark_applied(s.run_no)
-        # 2. the crash seam: group applied (maybe durable, maybe not —
-        # the OS owns the buffers), watermark NOT yet published, no
-        # reply sent. A kill here must replay the suffix exactly once.
-        crashpoint("dur.group_fsync", rid=r.id)
-        # 3. one fsync per distinct store
-        t0 = time.perf_counter()
-        synced = []
-        n_syncs = 0
-        for s in group:
-            for db in (s.db,) + tuple(s.sync_dbs):
-                if db is None or any(db is d for d in synced):
-                    continue
-                # sync_writes-mode stores fsynced the group apply
-                # already — one boundary per group, never two
-                if not getattr(db, "syncs_on_write", False):
-                    db.sync()
-                    n_syncs += 1
-                synced.append(db)
-        fsync_ms = (time.perf_counter() - t0) * 1e3
-        # 4. publish: watermark first (monotone, single-writer), then
-        # the per-run completions the dispatcher integrates
-        wm = max((s.run.last for s in group), default=self.watermark)
-        if wm > self.watermark:
-            self.watermark = wm
-        flight.record(flight.EV_DUR_GROUP, seq=wm, arg=len(group))
+        with flight.annotate("dur_apply"):
+            for db, seals in per_db:
+                db.write_group([s.batch for s in seals])
+                for s in seals:
+                    self.pending.mark_applied(s.run_no)
+        flight.record(flight.EV_DUR_WRITTEN, seq=wm, arg=len(group))
+        with flight.annotate("dur_fsync"):
+            # 2. the crash seam: group applied (maybe durable, maybe
+            # not — the OS owns the buffers), watermark NOT yet
+            # published, no reply sent. A kill here must replay the
+            # suffix exactly once.
+            crashpoint("dur.group_fsync", rid=r.id)
+            # 3. one fsync per distinct store
+            t0 = time.perf_counter()
+            synced = []
+            n_syncs = 0
+            for s in group:
+                for db in (s.db,) + tuple(s.sync_dbs):
+                    if db is None or any(db is d for d in synced):
+                        continue
+                    # sync_writes-mode stores fsynced the group apply
+                    # already — one boundary per group, never two
+                    if not getattr(db, "syncs_on_write", False):
+                        db.sync()
+                        n_syncs += 1
+                    synced.append(db)
+            fsync_ms = (time.perf_counter() - t0) * 1e3
+            # 4. publish: watermark first (monotone, single-writer),
+            # then the per-run completions the dispatcher integrates
+            if wm > self.watermark:
+                self.watermark = wm
+            flight.record(flight.EV_DUR_GROUP, seq=wm, arg=len(group))
         self.m_groups.inc()
         self.m_runs.inc(len(group))
         self.m_fsyncs.inc(n_syncs)
@@ -509,7 +529,6 @@ class DurabilityPipeline:
         self.m_wm.set(self.watermark)
         self.m_wm_lag.set(max(0, self._sealed_head - self.watermark))
         self._h_group_len.record(len(group))
-        self._h_fsync_ms.record(fsync_ms)
         # 5. completion — the group IS durable from here: a bookkeeping
         # failure must be swallowed per run, never reach the _loop retry
         # (requeueing a completed run would re-apply its batch and hand

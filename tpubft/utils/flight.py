@@ -20,7 +20,8 @@ Three consumers fold the rings:
 
   * ``SlotTracker`` — folds slot-stage events into per-seq timings
     (adm_wait / dispatch / prepare / commit / exec / reply, plus the
-    order_wait / exec_wait / exec_run / dur_wait sub-stages), feeding
+    order_wait / exec_wait / exec_run / dur_wait sub-stages, the last
+    two split in three each), feeding
     the diagnostics histograms (``slot.<stage>``) and
     ``status get slots``;
   * ``KernelProfiler`` — per-kernel call count, batch-size stats, wall
@@ -41,7 +42,10 @@ durability group, the BLS host path): one ``EV_SPAN`` ring event on
 exit and, where ``jax`` is already imported, a
 ``jax.profiler.TraceAnnotation("tpubft:<name>")`` for the same interval
 — so the profiler's trace and the rings name one interval on two
-clocks. This module never imports ``jax`` itself.
+clocks. ``annotate(name)`` is the profiler half alone, for an interval
+whose ring half is a slot or group event (the lane's ``exec_slot`` /
+``exec_seal``, the io thread's ``dur_apply`` / ``dur_fsync``). This
+module never imports ``jax`` itself.
 
 Knobs (environment — read once at import, like TPUBFT_THREADCHECK):
 
@@ -148,6 +152,24 @@ EV_EXEC_START = 39      # a slot began executing: a lane run, the restore
 EV_SPAN = 40            # flight.span() closed (view=span-name id,
 #                         arg=µs; the id → name table rides every
 #                         snapshot as `span_names`)
+# the lane's run and the durability group, each split in three
+EV_EXEC_HANDLED = 41    # a slot's request loop returned (lane thread,
+#                         or the restore replay / a barrier batch;
+#                         arg=µs summed over its application calls)
+EV_DUR_TAKE = 42        # the io thread took a group (seq=the group's
+#                         watermark, arg=why it was cut: DUR_CUT_*)
+EV_DUR_WRITTEN = 43     # the group's last write_group returned (io
+#                         thread; seq=the group's watermark, arg=runs)
+
+# EV_DUR_TAKE's arg: why the io thread cut the group when it did
+DUR_CUT_FULL = 1        # durability_group_max runs were sealed
+DUR_CUT_DEADLINE = 2    # durability_window_us ran out
+DUR_CUT_QUIET = 3       # the lane went idle: nothing more to wait for
+DUR_CUT_FLUSH = 4       # a barrier asked for a flush (drain/flush)
+DUR_CUT_STOP = 5        # the pipeline is stopping
+DUR_CUT_NAMES = {DUR_CUT_FULL: "full", DUR_CUT_DEADLINE: "deadline",
+                 DUR_CUT_QUIET: "lane_quiet", DUR_CUT_FLUSH: "flush",
+                 DUR_CUT_STOP: "stop"}
 
 EV_NAMES = {
     EV_ADM_INGEST: "adm_ingest", EV_ADM_DRAIN: "adm_drain",
@@ -169,7 +191,8 @@ EV_NAMES = {
     EV_OFF_LEASE: "lease_issued", EV_OFF_VERIFIED: "lease_verified",
     EV_OFF_REJECTED: "lease_rejected", EV_OFF_EVICT: "helper_evicted",
     EV_PP_CREATE: "pp_create", EV_EXEC_START: "exec_start",
-    EV_SPAN: "span",
+    EV_SPAN: "span", EV_EXEC_HANDLED: "exec_handled",
+    EV_DUR_TAKE: "dur_take", EV_DUR_WRITTEN: "dur_written",
 }
 
 # events the slot tracker folds inline (everything else is ring-only)
@@ -177,7 +200,10 @@ _SLOT_CODES = frozenset((EV_ADM_ADMIT, EV_PP_DISPATCH, EV_PP_ACCEPT,
                          EV_PREPARED, EV_COMMITTED, EV_EXEC_ENQ,
                          EV_EXEC_APPLY, EV_REPLY,
                          EV_CERT_ASYNC_LAG, EV_PP_CREATE,
-                         EV_EXEC_START, EV_DUR_GROUP))
+                         EV_EXEC_START, EV_DUR_GROUP, EV_EXEC_HANDLED,
+                         EV_DUR_TAKE, EV_DUR_WRITTEN))
+# events of a durability GROUP: seq is its watermark, not one slot
+_GROUP_CODES = frozenset((EV_DUR_TAKE, EV_DUR_WRITTEN, EV_DUR_GROUP))
 
 # the six PIPELINE stages partition a slot's lifetime (they sum to the
 # slot total). cert_lag is an OVERLAY, excluded from the total:
@@ -186,15 +212,19 @@ _SLOT_CODES = frozenset((EV_ADM_ADMIT, EV_PP_DISPATCH, EV_PP_ACCEPT,
 # ReplicaConfig.optimistic_replies; fed by EV_CERT_ASYNC_LAG samples,
 # which usually land after the slot finalized on EV_REPLY — so it is
 # tracked as a sample stream, never part of a slot's total).
-# The last four account for a request INSIDE the stages above and are
+# The rest account for a request INSIDE the stages above and are
 # excluded from the total as well: order_wait precedes the slot (the
 # primary's pending_requests queue, 0 on backups); exec_wait + exec_run
 # split `exec` at the lane's EV_EXEC_START (queue wait vs service);
 # dur_wait is the slice of `reply` spent waiting for the group fsync.
+# exec_app + exec_reply + exec_seal split exec_run, and dur_queue +
+# dur_apply + dur_fsync split dur_wait (SlotTracker's docstring).
 PIPELINE_STAGES = ("adm_wait", "dispatch", "prepare", "commit", "exec",
                    "reply")
 STAGES = PIPELINE_STAGES + ("cert_lag", "order_wait", "exec_wait",
-                            "exec_run", "dur_wait")
+                            "exec_run", "dur_wait", "exec_app",
+                            "exec_reply", "exec_seal", "dur_queue",
+                            "dur_apply", "dur_fsync")
 
 RING_SIZE = max(64, int(os.environ.get("TPUBFT_FLIGHT_RING", "4096")
                         or 4096))
@@ -374,6 +404,16 @@ def _span_off(name: str, seq: int = 0) -> _NullSpan:
     return _NULL_SPAN
 
 
+def _annotate(name: str):
+    """`with flight.annotate(name):` — the profiler half alone, for an
+    interval whose ring half is a slot or group event the caller records
+    at its end (the lane's `exec_slot` / `exec_seal`, the io thread's
+    `dur_apply` / `dur_fsync`): one interval on two clocks, and no
+    second ring event."""
+    ann = annotation(name)
+    return _NULL_SPAN if ann is None else ann
+
+
 def record_span(name: str, us: int, seq: int = 0) -> None:
     """One EV_SPAN for time SUMMED by the caller over many small pieces
     (share decompression inside an accumulator's `add` calls) — never a
@@ -422,9 +462,10 @@ def span_events(name: str, since_ns: int = 0
 ENABLED = os.environ.get("TPUBFT_FLIGHT", "1") not in ("", "0")
 # the ONE hot-path entry point: callers use `flight.record(...)` (a
 # module-attribute lookup) so enable/disable swaps take effect; `span`
-# swaps with it
+# and `annotate` swap with it
 record = _record if ENABLED else _record_off
 span = _Span if ENABLED else _span_off
+annotate = _annotate if ENABLED else _span_off
 
 
 def enabled() -> bool:
@@ -434,9 +475,10 @@ def enabled() -> bool:
 def _set_enabled(on: bool) -> None:
     """Test hook (the production compile-out is TPUBFT_FLIGHT=0 at
     process start)."""
-    global record, span
+    global record, span, annotate
     record = _record if on else _record_off
     span = _Span if on else _span_off
+    annotate = _annotate if on else _span_off
 
 
 def configure(dump_dir: Optional[str] = None) -> None:
@@ -480,6 +522,40 @@ class SlotTracker:
                   of `reply`, 0 for a slot no group covers (a barrier
                   batch, the restore replay)
 
+    exec_run and dur_wait are each split in three at events recorded
+    where the work happens; the three always sum to the whole, cut
+    points clamped into it as exec_wait's is (a missing or earlier
+    anchor reads as 0, and the part after it takes the rest):
+
+        exec_app    the slot's application calls (the handler, the
+                  merkle walk, the block's rows staged), summed on the
+                  lane: EV_EXEC_HANDLED's arg, capped at the loop below
+        exec_reply  the rest of the slot's request loop, max(lane start,
+                  commit) -> EV_EXEC_HANDLED: reply building and reply
+                  pages, the dedup checks, the loop itself
+        exec_seal   EV_EXEC_HANDLED -> durable apply: the run's
+                  end_accumulation (its overlay into the pending store)
+                  and the pages write when not folded. For a slot that
+                  is not its run's last, this also holds the run's later
+                  slots. The restore replay and barrier batches record
+                  EV_EXEC_HANDLED too; a row with none on record (an
+                  event lost to a reset, an old dump) reads as all seal
+        dur_queue   durable apply -> the io thread took the group that
+                  covers the slot (EV_DUR_TAKE, whose arg says why the
+                  group was cut then: DUR_CUT_*). It also holds what the
+                  lane does after the apply event: the checkpoint digest
+                  at a boundary and the seal into the pipeline's queue
+        dur_apply   the take -> the group's last write_group returned
+                  (EV_DUR_WRITTEN)
+        dur_fsync   EV_DUR_WRITTEN -> EV_DUR_GROUP: the crash seam, the
+                  fsyncs, the watermark published
+
+    The three group events stamp a replica's applied slots at or under
+    the group's watermark (`stamp_group`, first sighting wins); the
+    row carries the run count of the group that covered it
+    (`group_runs`, EV_DUR_GROUP's arg; 0 where none did) and its cut
+    (`dur_cut`).
+
     A slot finalizes on EV_REPLY (the dispatcher records it for every
     integrated slot, replies or not): its stage durations feed the
     process-wide ``slot.<stage>`` diagnostics histograms and a bounded
@@ -492,6 +568,9 @@ class SlotTracker:
     def __init__(self) -> None:
         self._mu = make_lock("flight.slots")
         self._live: Dict[Tuple[int, int], Dict] = {}
+        # the live slots again, by replica then seq: a group event scans
+        # its own replica's slots, not every replica's
+        self._live_by_rid: Dict[int, Dict[int, Dict]] = {}
         self._done: "deque[Dict]" = deque(maxlen=self.KEEP)
         self._hists: Dict[str, object] = {}
         self._finalized = 0
@@ -527,7 +606,10 @@ class SlotTracker:
               EV_PP_ACCEPT: "accept", EV_PREPARED: "prepared",
               EV_COMMITTED: "committed", EV_EXEC_ENQ: "enqueued",
               EV_EXEC_APPLY: "applied", EV_REPLY: "replied",
-              EV_PP_CREATE: "created", EV_EXEC_START: "started"}
+              EV_PP_CREATE: "created", EV_EXEC_START: "started",
+              EV_EXEC_HANDLED: "handled"}
+    _GROUP_FIELD = {EV_DUR_TAKE: "taken", EV_DUR_WRITTEN: "written",
+                    EV_DUR_GROUP: "durable"}
 
     @classmethod
     def stamp(cls, slot: Dict, code: int, arg: int, t_ns: int) -> None:
@@ -542,16 +624,26 @@ class SlotTracker:
             slot.setdefault("order_wait_us", arg)
         elif code == EV_PP_ACCEPT:
             slot.setdefault("reqs", arg)
+        elif code == EV_EXEC_HANDLED:
+            slot.setdefault("app_us", arg)
 
-    @staticmethod
-    def stamp_durable(slots, rid: int, watermark: int, t_ns: int) -> None:
-        """EV_DUR_GROUP: every applied slot of `rid` at or under the
-        group's watermark became durable at `t_ns` (shared with
-        tools/tpuprof.py; `slots` iterates raw slot records)."""
+    @classmethod
+    def stamp_group(cls, slots, rid: int, code: int, watermark: int,
+                    arg: int, t_ns: int) -> None:
+        """A durability group's event (EV_DUR_TAKE / EV_DUR_WRITTEN /
+        EV_DUR_GROUP): every applied slot of `rid` at or under the
+        group's watermark reached that point at `t_ns`, first sighting
+        winning (shared with tools/tpuprof.py; `slots` iterates raw slot
+        records)."""
+        field = cls._GROUP_FIELD[code]
         for slot in slots:
             if slot["rid"] == rid and slot["seq"] <= watermark \
-                    and "applied" in slot:
-                slot.setdefault("durable", t_ns)
+                    and "applied" in slot and field not in slot:
+                slot[field] = t_ns
+                if code == EV_DUR_GROUP:
+                    slot["group_runs"] = arg
+                elif code == EV_DUR_TAKE:
+                    slot["cut"] = arg
 
     def on_event(self, rid: int, code: int, seq: int, view: int,
                  arg: int, t_ns: int) -> None:
@@ -563,10 +655,13 @@ class SlotTracker:
                 self._cert_lag.append((rid, lag_ms))
             self._hist("cert_lag").record(arg)      # histograms in µs
             return
-        if code == EV_DUR_GROUP:
+        if code in _GROUP_CODES:
             # seq carries the group's watermark, not one slot
             with self._mu:
-                self.stamp_durable(self._live.values(), rid, seq, t_ns)
+                mine = self._live_by_rid.get(rid)
+                if mine:
+                    self.stamp_group(mine.values(), rid, code, seq, arg,
+                                     t_ns)
             return
         key = (rid, seq)
         with self._mu:
@@ -578,13 +673,17 @@ class SlotTracker:
                 if len(self._live) >= self.MAX_LIVE:
                     # bounded: evict the oldest live entry (a wedged or
                     # view-changed-away slot must not pin memory)
-                    self._live.pop(next(iter(self._live)))
+                    old_rid, old_seq = next(iter(self._live))
+                    del self._live[(old_rid, old_seq)]
+                    del self._live_by_rid[old_rid][old_seq]
                 slot = self._live[key] = {"rid": rid, "seq": seq,
                                           "view": view}
+                self._live_by_rid.setdefault(rid, {})[seq] = slot
             self.stamp(slot, code, arg, t_ns)
             if code != EV_REPLY:
                 return
             del self._live[key]
+            del self._live_by_rid[rid][seq]
             self._folded_set.add(key)
             self._folded.append(key)
             if len(self._folded) > self.MAX_LIVE:
@@ -608,7 +707,17 @@ class SlotTracker:
         # reset) reads as all service, one that started before its
         # verified commit (optimistic release) as no wait
         exec_wait = min(ms(committed, slot.get("started")), exec_ms)
+        # exec_run's cut: the loop's end, from the commit, inside the run
+        handled = max(exec_wait,
+                      min(ms(committed, slot.get("handled")), exec_ms))
+        loop = handled - exec_wait
+        app = min(slot.get("app_us", 0) / 1e3, loop)
         reply_ms = ms(applied, slot.get("replied"))
+        dur_wait = min(ms(applied, slot.get("durable")), reply_ms)
+        # dur_wait's cuts: the take and the write, from the apply
+        taken = min(ms(applied, slot.get("taken")), dur_wait)
+        written = max(taken, min(ms(applied, slot.get("written")),
+                                 dur_wait))
         return {
             "adm_wait": ms(slot.get("admit"), slot.get("handler")),
             "dispatch": ms(slot.get("handler"), accept),
@@ -625,7 +734,13 @@ class SlotTracker:
             "order_wait": slot.get("order_wait_us", 0) / 1e3,
             "exec_wait": exec_wait,
             "exec_run": exec_ms - exec_wait,
-            "dur_wait": min(ms(applied, slot.get("durable")), reply_ms),
+            "dur_wait": dur_wait,
+            "exec_app": app,
+            "exec_reply": loop - app,
+            "exec_seal": exec_ms - handled,
+            "dur_queue": taken,
+            "dur_apply": written - taken,
+            "dur_fsync": dur_wait - written,
         }
 
     def _finalize(self, slot: Dict) -> None:
@@ -635,6 +750,8 @@ class SlotTracker:
                "path": slot.get("path", "?"),
                "reqs": slot.get("reqs", 0),
                "primary": "created" in slot,
+               "group_runs": slot.get("group_runs", 0),
+               "dur_cut": slot.get("cut", 0),
                "total_ms": round(sum(stages[s]
                                      for s in PIPELINE_STAGES), 3),
                "stages_ms": {k: round(v, 3) for k, v in stages.items()}}
@@ -685,6 +802,7 @@ class SlotTracker:
     def reset(self) -> None:
         with self._mu:
             self._live.clear()
+            self._live_by_rid.clear()
             self._done.clear()
             self._finalized = 0
             self._finalized_by_rid.clear()
