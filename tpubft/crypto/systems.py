@@ -891,3 +891,10 @@ def resolve_threshold_scheme(scheme: str, n: int,
 # ops/bls12_381._msm_launch picks its kernel). Read at exit beside the
 # kernel profiler's `bls_msm` calls; nothing here is read back.
 FUSED_MSM_LAUNCHES = METRICS.register_counter("bls_msm_fused_launches")
+
+# ed25519 signatures whose host half `ops/ed25519.prepare_batch` ran as
+# one native call (`native/ed25519prep.cpp`), n a call, process-wide.
+# Read at exit beside the kernel profiler's `ed25519` items; nothing
+# here is read back.
+ED25519_PREP_NATIVE_ITEMS = METRICS.register_counter(
+    "ed25519_prep_native_items")

@@ -21,9 +21,9 @@ verify and benched BELOW one CPU thread):
     tpubft/ops/f25519.py (non-uniform-radix int32 limbs, batch on lanes).
 
 Split of labor (host vs device):
-  host   — parse 64B sig + 32B pk, SHA-512 → h mod L (vectorized numpy
-           except the hash itself), canonicality prechecks (s < L, y < p),
-           scalar→window recoding.
+  host   — parse 64B sig + 32B pk, SHA-512 → h mod L, canonicality
+           prechecks (s < L, y < p), scalar→window recoding and limbs:
+           one native call a batch (native/ed25519prep.cpp).
   device — A decompression (sqrt in Fp), Q = [s]B + [h](-A), affine
            canonicalization, compare with R's encoding. No data-dependent
            control flow.
@@ -34,7 +34,6 @@ checked as encode([s]B + [h](-A)) == R_bytes with canonical encodings.
 from __future__ import annotations
 
 import functools
-import hashlib
 from typing import List, NamedTuple, Sequence, Tuple
 
 import jax
@@ -270,7 +269,7 @@ def verify_kernel(s_win: jnp.ndarray, h_win: jnp.ndarray,
     return jnp.logical_and(a_valid, compress_eq(q, r_y, r_sign))
 
 
-# ---------------- host-side preparation (vectorized) ----------------
+# ---------------- host-side preparation (native) ----------------
 
 class PreparedBatch(NamedTuple):
     s_win: np.ndarray
@@ -282,69 +281,80 @@ class PreparedBatch(NamedTuple):
     host_valid: np.ndarray     # False where host-side canonicality failed
 
 
-def _lex_lt(rows_le: np.ndarray, bound: int) -> np.ndarray:
-    """Vectorized rows (B, 32) little-endian < bound (256-bit)."""
-    b_be = np.frombuffer(bound.to_bytes(32, "big"), np.uint8)
-    r_be = rows_le[:, ::-1]
-    diff = r_be != b_be[None, :]
-    has = diff.any(axis=1)
-    first = diff.argmax(axis=1)
-    rows_first = r_be[np.arange(len(r_be)), first]
-    return np.where(has, rows_first < b_be[first], False)
+# The binding rule (as storage/native.py's): `ed25519prep_run` is pure
+# compute, about 1 us an item, so it goes through a `PyDLL` handle and
+# keeps the interpreter lock. Releasing it would make the caller queue
+# behind whichever thread took the lock meanwhile (the flood's signer,
+# a replica's dispatcher) to get it back, and that queue is what the
+# host prep cost before it was native. No call holds the lock past one
+# switch interval: a batch goes in chunks of at most PREP_CHUNK items.
+PREP_CHUNK = 4096
+_ZERO_SIG = bytes(64)
+_ZERO_PK = bytes(32)
 
 
-def _windows_le(rows_le: np.ndarray) -> np.ndarray:
-    """(B, 32) little-endian byte rows -> (WINDOWS, B) 4-bit windows in
-    little-endian window order."""
-    bits = np.unpackbits(rows_le, axis=1, bitorder="little")   # (B, 256)
-    nib = bits.reshape(bits.shape[0], WINDOWS, 4).astype(np.int32)
-    vals = nib @ np.array([1, 2, 4, 8], np.int32)
-    return np.ascontiguousarray(vals.T)
+@functools.lru_cache(maxsize=1)
+def _prep_native():
+    import ctypes
+
+    from tpubft.native.build import load
+    keep = ctypes.PyDLL(load("ed25519prep")._name)
+    ptr = ctypes.c_void_p
+    keep.ed25519prep_run.restype = ctypes.c_int
+    keep.ed25519prep_run.argtypes = (
+        [ctypes.c_int64] * 3 + [ctypes.c_char_p, ptr, ctypes.c_char_p,
+                                ctypes.c_char_p, ctypes.c_char_p, ptr,
+                                ctypes.c_int32]
+        + [ptr] * 7)
+    keep.ed25519prep_sha512.restype = None
+    keep.ed25519prep_sha512.argtypes = [ctypes.c_char_p, ctypes.c_uint64,
+                                        ctypes.c_char_p]
+    keep.ed25519prep_sc_reduce.restype = None
+    keep.ed25519prep_sc_reduce.argtypes = [ctypes.c_char_p, ctypes.c_char_p]
+    return keep
+
+
+_LIMB_OFFSETS = np.array(F.W[:NL + 1], np.int32)
 
 
 def prepare_batch(items: Sequence[Tuple[bytes, bytes, bytes]]
                   ) -> PreparedBatch:
     """items: (message, signature64, public_key32) triples → device arrays.
 
-    Performs the host half of verification: SHA-512 challenge, s < L
-    check, canonical y < p checks. Everything but the hash loop is
-    vectorized numpy."""
+    The host half of verification, in native code
+    (`native/ed25519prep.cpp`): the SHA-512 challenge mod L, the s < L
+    and canonical y < p checks, nibble windows and limbs; a row that is
+    not shaped or fails a check is all zeros and `host_valid` False."""
     n = len(items)
-    sig_raw = np.zeros((n, 64), np.uint8)
-    pk_raw = np.zeros((n, 32), np.uint8)
-    shaped = np.zeros(n, bool)
-    h_raw = np.zeros((n, 32), np.uint8)
-    for i, (msg, sig, pk) in enumerate(items):
-        if len(sig) != 64 or len(pk) != 32:
-            continue
-        shaped[i] = True
-        sig_raw[i] = np.frombuffer(sig, np.uint8)
-        pk_raw[i] = np.frombuffer(pk, np.uint8)
-        h = int.from_bytes(
-            hashlib.sha512(sig[:32] + pk + msg).digest(), "little") % L
-        h_raw[i] = np.frombuffer(h.to_bytes(32, "little"), np.uint8)
-    r_bytes = sig_raw[:, :32].copy()
-    s_bytes = sig_raw[:, 32:].copy()
-    a_sign = (pk_raw[:, 31] >> 7).astype(np.int32)
-    r_sign = (r_bytes[:, 31] >> 7).astype(np.int32)
-    a_masked = pk_raw.copy()
-    a_masked[:, 31] &= 0x7F
-    r_masked = r_bytes.copy()
-    r_masked[:, 31] &= 0x7F
-    host_valid = (shaped
-                  & _lex_lt(s_bytes, L)          # malleability: s < L
-                  & _lex_lt(a_masked, P)         # canonical encodings
-                  & _lex_lt(r_masked, P))
-    # zero out invalid rows so the kernel runs on benign values
-    keep = host_valid[:, None]
-    return PreparedBatch(
-        s_win=_windows_le(np.where(keep, s_bytes, 0)),
-        h_win=_windows_le(np.where(keep, h_raw, 0)),
-        a_y=F.bytes_le_to_limbs(np.where(keep, a_masked, 0)),
-        a_sign=np.where(host_valid, a_sign, 0),
-        r_y=F.bytes_le_to_limbs(np.where(keep, r_masked, 0)),
-        r_sign=np.where(host_valid, r_sign, 0),
-        host_valid=host_valid)
+    msgs = [m for m, _, _ in items]
+    sigs = [s for _, s, _ in items]
+    pks = [k for _, _, k in items]
+    shaped = bytes([len(s) == 64 and len(k) == 32
+                    for s, k in zip(sigs, pks)])
+    if shaped.count(1) != n:      # the blobs keep 64 and 32 bytes an item
+        sigs = [s if len(s) == 64 else _ZERO_SIG for s in sigs]
+        pks = [k if len(k) == 32 else _ZERO_PK for k in pks]
+    offs = np.cumsum([0, *map(len, msgs)], dtype=np.uint64)
+    msg_blob, sig_blob, pk_blob = (b"".join(msgs), b"".join(sigs),
+                                   b"".join(pks))
+    out = PreparedBatch(
+        s_win=np.empty((WINDOWS, n), np.int32),
+        h_win=np.empty((WINDOWS, n), np.int32),
+        a_y=np.empty((NL, n), np.int32),
+        a_sign=np.empty(n, np.int32),
+        r_y=np.empty((NL, n), np.int32),
+        r_sign=np.empty(n, np.int32),
+        host_valid=np.empty(n, bool))
+    run = _prep_native().ed25519prep_run
+    bufs = [a.ctypes.data for a in out]
+    for i0 in range(0, n, PREP_CHUNK):
+        if run(i0, min(i0 + PREP_CHUNK, n), n, msg_blob, offs.ctypes.data,
+               sig_blob, pk_blob, shaped, _LIMB_OFFSETS.ctypes.data, NL,
+               *bufs):
+            raise ValueError(f"limb offsets {F.W[:NL + 1]} out of range")
+    from tpubft.crypto.systems import ED25519_PREP_NATIVE_ITEMS
+    ED25519_PREP_NATIVE_ITEMS.inc(n)
+    return out
 
 
 # batch is padded to one of these sizes so jit caches a few programs

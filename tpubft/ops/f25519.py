@@ -72,18 +72,6 @@ def limbs_to_int(limbs) -> int:
     return v % P
 
 
-def bytes_le_to_limbs(arr_u8: np.ndarray) -> np.ndarray:
-    """Host, vectorized: (B, 32) little-endian byte rows -> (NL, B) limbs.
-    Values must be < 2^255 (callers mask the sign bit first)."""
-    bits = np.unpackbits(arr_u8, axis=1, bitorder="little")       # (B, 256)
-    out = np.zeros((NL, bits.shape[0]), np.int32)
-    for i in range(NL):
-        seg = bits[:, W[i]:W[i + 1]].astype(np.int32)
-        out[i] = seg @ (1 << np.arange(seg.shape[1], dtype=np.int64)).astype(
-            np.int32)
-    return out
-
-
 # ----------------------------------------------------------------------
 # device ops — all (NL, ...batch) int32, fully data-parallel
 # ----------------------------------------------------------------------
